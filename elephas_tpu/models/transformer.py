@@ -876,16 +876,14 @@ def _moe_block_routed_ep(h, moe, config: "TransformerConfig", mesh: Mesh,
             aux = jax.lax.pmean(aux, data_axis)
         return out, aux
 
-    from ..utils.compat import shard_map as _shard_map
-
     batch_spec = P(data_axis, None, None)
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(batch_spec, P(None, None), P(model_axis, None, None),
                   P(model_axis, None), P(model_axis, None, None),
                   P(model_axis, None)),
         out_specs=(batch_spec, P()),
-        check=False)(h, moe["gate"], moe["w1"], moe["b1"], moe["w2"],
+        check_vma=False)(h, moe["gate"], moe["w1"], moe["b1"], moe["w2"],
                      moe["b2"])
     return out, aux
 

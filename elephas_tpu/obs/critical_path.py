@@ -32,7 +32,7 @@ from .metrics import percentile
 __all__ = ["STAGES", "aggregate", "build_tree", "decompose"]
 
 #: recognized critical-path stages, in pipeline order. Spans with
-#: other ``stage`` values still bill (the taxonomy is open), but these
+#: other ``stage`` values still bill (the stage set is open), but these
 #: are the ones the serving planes emit and the docs catalog.
 STAGES = (
     "edge_queue",       # router-side: dispatch attempts, proxy wait

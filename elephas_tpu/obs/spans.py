@@ -26,7 +26,7 @@ The whole plane sits behind :func:`set_span_plane_enabled` — the
 ``trace_plane`` bench row A/Bs tokens/s with it on vs off and holds
 the overhead under 2%.
 
-``obs/critical_path.py`` consumes these trees; the stage taxonomy
+``obs/critical_path.py`` consumes these trees; the stage catalog
 (``prefill``, ``kv_wire``, ``spill_promote``, ...) lives there.
 """
 import threading

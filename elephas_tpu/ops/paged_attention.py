@@ -45,7 +45,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_attention import NEG_INF, _CompilerParams, _use_interpret
+from .pallas_attention import NEG_INF, _use_interpret
 
 __all__ = ["paged_decode_attention", "pallas_supported"]
 
@@ -191,7 +191,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_use_interpret(interpret),
     )(jnp.asarray(tables, jnp.int32),

@@ -32,10 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 spells the TPU compiler-params struct TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
 
 __all__ = ["flash_attention", "flash_attention_sharded"]
@@ -184,7 +180,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, window=None,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_use_interpret(interpret),
     )(_as_offset(q_offset), _as_offset(k_offset), qr, kr, vr)
@@ -353,7 +349,7 @@ def _bwd_calls(q, k, v, g, lse, delta, causal, block_q, block_k, interpret,
         out_specs=[q_spec],
         out_shape=[jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(_as_offset(q_offset), _as_offset(k_offset),
@@ -385,7 +381,7 @@ def _bwd_calls(q, k, v, g, lse, delta, causal, block_q, block_k, interpret,
                    jax.ShapeDtypeStruct((b * kvh, sk_p, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(_as_offset(q_offset), _as_offset(k_offset),
@@ -504,12 +500,10 @@ def flash_attention_sharded(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     from jax.sharding import PartitionSpec as _P
 
-    from ..utils.compat import shard_map as _shard_map
-
     spec = _P(batch_axis, head_axis, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         _partial(flash_attention, causal=causal, block_q=block_q,
                  block_k=block_k, interpret=interpret, window=window),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check=False)
+        check_vma=False)
     return fn(q, k, v)

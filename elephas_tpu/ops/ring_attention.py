@@ -466,8 +466,6 @@ def ring_attention_sharded(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         window=window)
     else:
         raise ValueError(f"impl must be 'einsum' or 'flash', got {impl!r}")
-    from ..utils.compat import shard_map as _shard_map
-
-    fn = _shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec, check=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)

@@ -31,13 +31,8 @@ def initialize_multihost(coordinator_address: Optional[str] = None,
     """
     # NOTE: the guard must not touch the XLA backend — jax.process_count()
     # would initialize it, after which jax.distributed.initialize() fails.
-    try:
-        from jax._src import distributed as _jax_distributed
-
-        if _jax_distributed.global_state.client is not None:
-            return  # already initialized
-    except (ImportError, AttributeError):
-        pass  # private API moved: fall through and let initialize() decide
+    if jax.distributed.is_initialized():
+        return
     coordinator_address = coordinator_address or os.environ.get(
         "JAX_COORDINATOR_ADDRESS")
     if coordinator_address is None and num_processes is None:
@@ -96,13 +91,10 @@ def maybe_initialize_from_env():
     if not (os.environ.get("JAX_COORDINATOR_ADDRESS")
             or os.environ.get("JAX_NUM_PROCESSES")):
         return
-    try:
-        from jax._src import xla_bridge
+    from jax._src import xla_bridge
 
-        if xla_bridge.backends_are_initialized():
-            return
-    except (ImportError, AttributeError):
-        pass
+    if xla_bridge.backends_are_initialized():
+        return
     try:
         initialize_multihost()
     except Exception:

@@ -106,11 +106,9 @@ def make_pipeline_fn(stage_fn: Callable, mesh: Mesh, axis: str = "pipe",
         # micro is (M, B, ...): with a dp axis the per-microbatch batch
         # dim shards over it, so each dp row pipelines its own shard
         x_spec = P(None, batch_axis) if batch_axis is not None else P()
-        from ..utils.compat import shard_map as _shard_map
-
-        y = _shard_map(per_device, mesh=mesh,
-                       in_specs=(in_spec, x_spec), out_specs=x_spec,
-                       check=False)(stacked_params, micro)
+        y = jax.shard_map(per_device, mesh=mesh,
+                          in_specs=(in_spec, x_spec), out_specs=x_spec,
+                          check_vma=False)(stacked_params, micro)
         return y.reshape(x.shape[0:1] + y.shape[2:])
 
     return pipelined

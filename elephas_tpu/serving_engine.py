@@ -243,8 +243,8 @@ class DecodeEngine:
     :param steps_per_sync: decode steps fused into each :meth:`step`
         dispatch (plain mode): one jitted ``lax.scan`` advances every
         slot by this many tokens per host round trip. Where dispatch
-        latency dominates (remote/tunneled chips), throughput scales
-        almost linearly with it; the cost is scheduling granularity —
+        latency dominates, throughput scales almost linearly with it;
+        the cost is scheduling granularity —
         admission/retirement happen every ``steps_per_sync`` tokens, and
         a slot that hits eos/budget mid-chunk wastes the remainder.
         Per-slot output is still exactly its solo greedy decode.

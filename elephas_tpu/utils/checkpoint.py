@@ -29,16 +29,13 @@ except Exception:  # pragma: no cover - orbax is in the base image
 def _spans_processes() -> bool:
     """True in an initialized multi-process (DCN) run. Never initializes
     the backend as a side effect."""
-    try:
-        from jax._src import xla_bridge
+    from jax._src import xla_bridge
 
-        if not xla_bridge.backends_are_initialized():
-            return False
-        import jax
-
-        return jax.process_count() > 1
-    except Exception:  # private API moved / import failure
+    if not xla_bridge.backends_are_initialized():
         return False
+    import jax
+
+    return jax.process_count() > 1
 
 
 def _is_coordinator() -> bool:
@@ -48,16 +45,13 @@ def _is_coordinator() -> bool:
     would otherwise *initialize* the backend as a side effect (pinning
     the platform before the caller could configure it). Before backend
     init there is no multi-process run to coordinate with."""
-    try:
-        from jax._src import xla_bridge
+    from jax._src import xla_bridge
 
-        if not xla_bridge.backends_are_initialized():
-            return True
-        import jax
-
-        return jax.process_index() == 0
-    except Exception:  # private API moved / import failure
+    if not xla_bridge.backends_are_initialized():
         return True
+    import jax
+
+    return jax.process_index() == 0
 
 
 class CheckpointManager:

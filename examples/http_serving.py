@@ -9,8 +9,12 @@ settings and cancellation on the wire.
 Run: JAX_PLATFORMS=cpu python examples/http_serving.py
 """
 import json
+import os
+import sys
 import threading
 import urllib.request
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp

@@ -14,7 +14,7 @@ set -u
 cd "$(dirname "$0")"
 declare -a NAMES=(core ops models transformer serving engine distributed)
 declare -a PATHS=(
-  "tests/ml tests/mllib tests/utils tests/parameter tests/test_ps_sharding.py tests/test_ps_replication.py tests/test_matrix_model.py tests/test_model_serialization.py tests/test_tpu_callbacks.py tests/test_trainer_cache.py tests/test_ci_shards.py"
+  "tests/ml tests/mllib tests/utils tests/parameter tests/test_ps_sharding.py tests/test_ps_replication.py tests/test_matrix_model.py tests/test_model_serialization.py tests/test_tpu_callbacks.py tests/test_trainer_cache.py tests/test_ci_shards.py tests/test_chip_smoke.py"
   "tests/ops"
   "tests/models --ignore=tests/models/test_transformer.py --ignore=tests/models/test_speculative.py --ignore=tests/models/test_distill.py"
   "tests/models/test_transformer.py"

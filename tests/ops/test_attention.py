@@ -8,7 +8,6 @@ from jax.sharding import Mesh, PartitionSpec
 
 from elephas_tpu.ops import (attention, blockwise_attention, ring_attention,
                              ring_attention_sharded)
-from elephas_tpu.utils.compat import shard_map as compat_shard_map
 
 
 def _qkv(b=2, h=4, s=32, d=16, seed=0):
@@ -203,11 +202,11 @@ def test_zigzag_ring_flash_matches_full(ring_size):
     ref = attention(q, k, v, causal=True)
     spec = PartitionSpec(None, None, "seq", None)
     for zigzag in (True, None):  # explicit and auto both take the path
-        fn = compat_shard_map(
+        fn = jax.shard_map(
             partial(ring_flash_attention, axis_name="seq", causal=True,
                     zigzag=zigzag),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check=False)
+            check_vma=False)
         got = fn(q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-4)
@@ -224,11 +223,11 @@ def test_zigzag_ring_flash_gradients_match_plain():
     cot = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
 
     def loss(zigzag):
-        fn = compat_shard_map(
+        fn = jax.shard_map(
             partial(ring_flash_attention, axis_name="seq", causal=True,
                     zigzag=zigzag),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check=False)
+            check_vma=False)
         return lambda q, k, v: jnp.sum(fn(q, k, v) * cot)
 
     ref_grads = jax.grad(loss(False), argnums=(0, 1, 2))(q, k, v)
@@ -253,11 +252,11 @@ def test_zigzag_ring_flash_gqa():
     expected = attention(q, k_full, v_full, causal=True)
     mesh = Mesh(np.array(jax.devices()[:4]), ("seq",))
     spec = PartitionSpec(None, None, "seq", None)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         partial(ring_flash_attention, axis_name="seq", causal=True,
                 zigzag=True),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check=False)
+        check_vma=False)
     np.testing.assert_allclose(np.asarray(fn(q, k, v)),
                                np.asarray(expected), atol=2e-5, rtol=2e-5)
 
