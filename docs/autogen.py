@@ -148,7 +148,7 @@ PAGES = [
     ("Text utilities", "elephas_tpu.utils.text", ["ByteTokenizer"]),
     ("Serving", "elephas_tpu.serving", ["TextGenerator"]),
     ("Step timing", "elephas_tpu.utils.tracing",
-     ["StepTimer", "profiler_trace", "annotate"]),
+     ["StepTimer", "profiler_trace"]),
     ("Observability metrics API", "elephas_tpu.obs.metrics",
      ["MetricsRegistry", "Counter", "Gauge", "Histogram",
       "default_registry", "percentile"]),

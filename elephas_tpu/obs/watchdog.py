@@ -264,11 +264,10 @@ class EngineWatchdog:
             # the profiler's OWN clock (perf_counter by default) — its
             # stamps are not comparable to this watchdog's monotonic
             now = prof._clock()
-            stack = prof._stack
-            if stack:
-                phase, started, _ = stack[-1]
-                out["phase"] = phase
-                out["phase_age_s"] = round(max(0.0, now - started), 6)
+            open_phase = prof.open_phase()
+            if open_phase is not None:
+                out["phase"] = open_phase[0]
+                out["phase_age_s"] = round(open_phase[1], 6)
             start = prof._iter_start
             if start is not None:
                 out["iteration_age_s"] = round(max(0.0, now - start), 6)

@@ -193,7 +193,7 @@ GAMMA_ADJUST_EVERY = 4
 #: interleaved prefill: iterations between prefill-budget recomputes.
 #: The budget reads the profiler's utilization(), which walks the
 #: ring-buffer under a lock — cheap, but not every-iteration cheap
-#: against a sub-millisecond decode step (<2% overhead budget)
+#: against a sub-millisecond decode step
 PREFILL_BUDGET_EVERY = 16
 
 #: interleaved prefill: most chunks one iteration may feed. The budget
@@ -326,14 +326,17 @@ class DecodeEngine:
         fresh registries. The HTTP server merges this registry with the
         process default registry on its ``GET /metrics`` route.
     :param profiler: the engine-loop continuous profiler
-        (:class:`~elephas_tpu.obs.LoopProfiler`): per-iteration phase
-        accounting (swap/admit/prefill/decode/emit + idle) published
-        as ``serving_loop_utilization{phase}`` gauges, with jit
-        compiles tracked separately. ``None`` (the default) creates
-        one on this engine's registry — measured overhead is <2%
-        tokens/s (the ``slo_plane`` bench row), cheap enough to be
-        always-on. Pass ``False`` to disable (the bench A/B baseline)
-        or an instance to share one across wrappers.
+        (:class:`~elephas_tpu.obs.LoopProfiler`): the loop's sections
+        (swap/admit/prefill/decode dispatch/decode wait/emit + idle)
+        as ``serving_loop_phase_seconds_total{phase}`` counters,
+        ``serving_loop_utilization{phase}`` gauges, the slow-iteration
+        record and, under a ``jax.profiler`` session, ``elephas.loop.*``
+        spans on the device trace's clock; jit compiles and garbage
+        collections tracked separately. ``None`` (the default) creates
+        one on this engine's registry — it is meant to be always-on
+        (its measured cost: ``PERF.md``). Pass ``False`` to disable
+        (the bench A/B baseline) or an instance to share one across
+        wrappers.
     :param kernel: paged decode-attention inner loop: ``"gather"``
         (default — materialize each row's blocks, full-row softmax) or
         ``"pallas"`` (fused block-gather flash kernel,
@@ -1377,24 +1380,30 @@ class DecodeEngine:
         chunked = self.prefill_chunk is not None
         if entry is None:
             if chunked:
-                logits, row = self._extend_chunked(
-                    params, fresh_fn(), prompt, 0, extend_fn,
-                    extend_owned_fn, owned=True)
+                with self._psec("elephas.loop.prefill.row_init"):
+                    row = fresh_fn()
+                with self._psec("elephas.loop.prefill.chunks"):
+                    logits, row = self._extend_chunked(
+                        params, row, prompt, 0, extend_fn,
+                        extend_owned_fn, owned=True)
                 return logits[0], row
-            logits, row = prefill_fn(params, jnp.asarray(prompt[None]))
+            with self._psec("elephas.loop.prefill.chunks"):
+                logits, row = prefill_fn(params,
+                                         jnp.asarray(prompt[None]))
             return logits[0], row
         ptoks, plogits = entry[0], entry[1]
         row = entry[cache_idx]
         if prompt.size == ptoks.size:
             return plogits, row
-        if chunked:
-            logits, row = self._extend_chunked(
-                params, row, prompt[ptoks.size:], int(ptoks.size),
-                extend_fn, extend_owned_fn, owned=False)
-            return logits[0], row
-        suffix = jnp.asarray(prompt[None, ptoks.size:])
-        logits, row = extend_fn(params, row, suffix,
-                                jnp.int32(ptoks.size))
+        with self._psec("elephas.loop.prefill.chunks"):
+            if chunked:
+                logits, row = self._extend_chunked(
+                    params, row, prompt[ptoks.size:], int(ptoks.size),
+                    extend_fn, extend_owned_fn, owned=False)
+            else:
+                suffix = jnp.asarray(prompt[None, ptoks.size:])
+                logits, row = extend_fn(params, row, suffix,
+                                        jnp.int32(ptoks.size))
         return logits[0], row
 
     # ------------------------------------------------- automatic KV cache
@@ -1812,14 +1821,15 @@ class DecodeEngine:
         donating extend variants apply. Returns (last-position logits
         ``(vocab,)``, full row)."""
         suffix = prompt[pos0:]
-        if self.prefill_chunk is not None:
-            logits, row = self._extend_chunked(
-                self.params, row, suffix, pos0, self._extend_fn,
-                self._extend_owned_fn, owned=True)
-            return logits[0], row
-        logits, row = self._extend_owned_fn(
-            self.params, row, jnp.asarray(suffix[None]),
-            jnp.int32(pos0))
+        with self._psec("elephas.loop.prefill.chunks"):
+            if self.prefill_chunk is not None:
+                logits, row = self._extend_chunked(
+                    self.params, row, suffix, pos0, self._extend_fn,
+                    self._extend_owned_fn, owned=True)
+            else:
+                logits, row = self._extend_owned_fn(
+                    self.params, row, jnp.asarray(suffix[None]),
+                    jnp.int32(pos0))
         return logits[0], row
 
     def _host_cache_prefill(self, rid: Optional[int],
@@ -2781,7 +2791,7 @@ class DecodeEngine:
                                      and self._staged_params is None
                                      and self._staged_draft is None):
             return self._admit_impl()
-        with self.profiler.section("admit"):
+        with self.profiler.section("elephas.loop.admit"):
             self._admit_impl()
 
     def _admit_impl(self):
@@ -2794,7 +2804,7 @@ class DecodeEngine:
             # on the next step — exactly the contract stage_params has
             self.apply_staged_params()
         else:
-            with self._psec("swap"):
+            with self._psec("elephas.loop.swap"):
                 self.apply_staged_params()
         self._shed_expired_queued()
         self._enforce_active_deadlines()
@@ -2809,7 +2819,19 @@ class DecodeEngine:
                 if not self._maybe_preempt_for(self._queue.peek()):
                     return
                 continue
-            slot = slots[0]
+            with self._psec("elephas.loop.admit.request"):
+                if not self._admit_request(slots[0]):
+                    return
+
+    def _admit_request(self, slot: int) -> bool:
+        """One admission into the free ``slot``: claim what serves the
+        scheduled candidate (cached blocks, fresh blocks), pop it,
+        prefill and install it. Returns False when admission has to
+        wait (no blocks and no one to preempt), True when the loop
+        in :meth:`_admit_impl` may go on."""
+        cand = self._queue.peek()
+        self._pmeta(rid=cand.rid, prompt_tokens=int(cand.prompt.size))
+        with self._psec("elephas.loop.admit.claim"):
             if self.paged is not None:
                 # allocate BEFORE popping: when the pool is momentarily
                 # empty the scheduled candidate simply waits (no
@@ -2877,8 +2899,8 @@ class DecodeEngine:
                     # or free, and the loop re-evaluates availability);
                     # otherwise the candidate keeps its turn and waits
                     if not self._maybe_preempt_for(cand):
-                        return
-                    continue
+                        return False
+                    return True
                 # claim the hit chain FIRST (refcount++, unpark): the
                 # remainder allocation below may evict LRU entries and
                 # must never reclaim the blocks this request reuses
@@ -2913,128 +2935,129 @@ class DecodeEngine:
                 self._tables[slot, :] = 0      # unused entries -> scratch
                 self._tables[slot, :needed] = (
                     [e.payload for e in hits] + blocks)
-            item = self._queue.pop()
-            rid, prompt, max_new = item.rid, item.prompt, item.max_new
-            temp, topk, topp = item.temperature, item.top_k, item.top_p
-            self._queued_tokens -= int(prompt.size)
-            resume = self._resume.pop(rid, None)
-            # queue wait ends HERE — prefill compute/compile time below
-            # belongs to total latency, not to time-spent-queued
-            self._admit_t[rid] = time.monotonic()
-            t_sub = self._submit_t.get(rid)
-            self.recorder.record(
-                rid, "admitted", slot=slot, tenant=item.tenant,
-                # the weight version this request will decode under —
-                # the flight-recorder half of "which weights served
-                # this request" (a mid-decode swap shows up as
-                # weights.swapped events between its step events)
-                weights_version=self.weights_version,
-                queue_wait_s=(None if t_sub is None
-                              else round(self._admit_t[rid] - t_sub, 6)),
-                # the sampling seed, when pinned — the repro handle: a
-                # trace reader can replay THIS request's exact output
-                **({"seed": self._seed[rid]}
-                   if rid in self._seed else {}))
-            if t_sub is not None and rid in self._trace_ctx:
-                # queue time as a retroactive stage span: monotonic
-                # wait projected back from the current wall clock
-                wait_s = self._admit_t[rid] - t_sub
-                add_span("serving.admission_wait", time.time() - wait_s,
-                         wait_s, stage="admission_wait",
-                         ctx=self._trace_ctx[rid])
-            # per-request context restore: this loop runs on the engine
-            # thread, but prefill (and any span/fault/event it emits)
-            # belongs to the request whose context was captured at
-            # submit — None for requests submitted without one
-            pre = self._prefilled_kv.pop(rid, None)
-            with use_context(self._trace_ctx.get(rid)):
-                if (pre is not None and len(pre) > 2
-                        and pre[2] is not None
-                        and int(pre[2]) != int(self.weights_version)):
-                    # the shipped KV's weight-version stamp went stale
-                    # between the caller's gate and THIS install (a
-                    # hot-swap staged in the window): decoding over it
-                    # would be silently wrong output. Fall back to a
-                    # local prefill — correct, never a failed request,
-                    # one admission's worth of extra compute.
-                    self.recorder.record(
-                        rid, "kv_install_stale",
-                        frame_version=int(pre[2]),
-                        engine_version=int(self.weights_version),
-                        fallback="local_prefill")
-                    emit_event("serving.kv_install_stale",
-                               frame_version=int(pre[2]),
-                               engine_version=int(self.weights_version))
-                    pre = None
-                if pre is not None:
-                    # disaggregated admission: the shipped KV blocks
-                    # install straight into the slot (between decode
-                    # steps — this loop IS the atomic point); no
-                    # prefill compute, no prefix lookup
-                    # shipped frames deliberately do NOT seed the
-                    # decode-side cache: a pure-disagg decode tier
-                    # never walks it for prefilled requests (dead
-                    # entries would only inflate eviction churn), and
-                    # a Q8 frame's dequantized KV is content-addressed
-                    # by TOKENS — letting a later LOCAL admission hit
-                    # lossy blocks would break its cache-off parity
-                    with self._psec("prefill"), \
-                            start_span("serving.kv_install",
-                                       stage="prefill"):
-                        t0 = self._install_prefilled(slot, prompt, pre)
-                    self.recorder.record(
-                        rid, "kv_install",
-                        prompt_tokens=int(prompt.size),
-                        duration_s=round(
-                            time.monotonic() - self._admit_t[rid], 6))
-                else:
-                    if self._interleave_ok(slot, prompt):
-                        # defer the chunk loop: the slot is reserved
-                        # (blocks allocated, hit chain claimed) but its
-                        # prompt feeds between the coming decode steps
-                        # — _interleave_prefills() finishes the
-                        # admission when the last chunk lands
-                        self._begin_interleaved_prefill(
-                            rid, slot, item, prompt, resume, temp,
-                            topk, topp)
-                        continue
-                    with self._psec("prefill"), \
-                            start_span("serving.prefill",
-                                       stage="prefill"):
-                        t0 = self._admit_prefill(rid, slot, prompt,
-                                                 temp, topk, topp)
-            self._rid[slot] = rid
-            # a RESUMED request keeps the tokens it emitted before its
-            # preemption — the new first token (sampled from the full
-            # resubmitted sequence's final-position logits) is exactly
-            # the next token the never-preempted decode would emit
-            self._outputs[rid] = ([] if resume is None
-                                  else resume["outputs"])
-            self._slot_prompt[slot] = prompt
-            self._slot_prior[slot] = len(self._outputs[rid])
-            self._slot_tenant[slot] = item.tenant
-            self._slot_priority[slot] = item.priority
-            self._slot_wv[slot] = self.weights_version
-            self._pos[slot] = prompt.size - 1
-            self._last[slot] = t0
-            self._budget[slot] = max_new
-            self._temp[slot] = temp
-            self._topk[slot] = topk
-            self._topp[slot] = topp
-            self._slot_seed[slot] = self._seed.get(rid, -1)
-            if self.qos is not None:
-                self._m_tenant_admitted.labels(
-                    tenant=self.qos.label(item.tenant)).inc()
-            if resume is not None:
+        item = self._queue.pop()
+        rid, prompt, max_new = item.rid, item.prompt, item.max_new
+        temp, topk, topp = item.temperature, item.top_k, item.top_p
+        self._queued_tokens -= int(prompt.size)
+        resume = self._resume.pop(rid, None)
+        # queue wait ends HERE — prefill compute/compile time below
+        # belongs to total latency, not to time-spent-queued
+        self._admit_t[rid] = time.monotonic()
+        t_sub = self._submit_t.get(rid)
+        self.recorder.record(
+            rid, "admitted", slot=slot, tenant=item.tenant,
+            # the weight version this request will decode under —
+            # the flight-recorder half of "which weights served
+            # this request" (a mid-decode swap shows up as
+            # weights.swapped events between its step events)
+            weights_version=self.weights_version,
+            queue_wait_s=(None if t_sub is None
+                          else round(self._admit_t[rid] - t_sub, 6)),
+            # the sampling seed, when pinned — the repro handle: a
+            # trace reader can replay THIS request's exact output
+            **({"seed": self._seed[rid]}
+               if rid in self._seed else {}))
+        if t_sub is not None and rid in self._trace_ctx:
+            # queue time as a retroactive stage span: monotonic
+            # wait projected back from the current wall clock
+            wait_s = self._admit_t[rid] - t_sub
+            add_span("serving.admission_wait", time.time() - wait_s,
+                     wait_s, stage="admission_wait",
+                     ctx=self._trace_ctx[rid])
+        # per-request context restore: this loop runs on the engine
+        # thread, but prefill (and any span/fault/event it emits)
+        # belongs to the request whose context was captured at
+        # submit — None for requests submitted without one
+        pre = self._prefilled_kv.pop(rid, None)
+        with use_context(self._trace_ctx.get(rid)):
+            if (pre is not None and len(pre) > 2
+                    and pre[2] is not None
+                    and int(pre[2]) != int(self.weights_version)):
+                # the shipped KV's weight-version stamp went stale
+                # between the caller's gate and THIS install (a
+                # hot-swap staged in the window): decoding over it
+                # would be silently wrong output. Fall back to a
+                # local prefill — correct, never a failed request,
+                # one admission's worth of extra compute.
                 self.recorder.record(
-                    rid, "resumed", tokens_so_far=len(self._outputs[rid]),
-                    remaining_tokens=int(max_new),
-                    preemptions=resume["preempts"])
-            if self._record(slot, t0):
-                # surfaced by the next step(); append — a preempted-
-                # and-resumed request may still owe its PREVIOUS
-                # admission's un-surfaced first token
-                self._fresh.setdefault(rid, []).append(t0)
+                    rid, "kv_install_stale",
+                    frame_version=int(pre[2]),
+                    engine_version=int(self.weights_version),
+                    fallback="local_prefill")
+                emit_event("serving.kv_install_stale",
+                           frame_version=int(pre[2]),
+                           engine_version=int(self.weights_version))
+                pre = None
+            if pre is not None:
+                # disaggregated admission: the shipped KV blocks
+                # install straight into the slot (between decode
+                # steps — this loop IS the atomic point); no
+                # prefill compute, no prefix lookup
+                # shipped frames deliberately do NOT seed the
+                # decode-side cache: a pure-disagg decode tier
+                # never walks it for prefilled requests (dead
+                # entries would only inflate eviction churn), and
+                # a Q8 frame's dequantized KV is content-addressed
+                # by TOKENS — letting a later LOCAL admission hit
+                # lossy blocks would break its cache-off parity
+                with self._psec("elephas.loop.prefill"), \
+                        start_span("serving.kv_install",
+                                   stage="prefill"):
+                    t0 = self._install_prefilled(slot, prompt, pre)
+                self.recorder.record(
+                    rid, "kv_install",
+                    prompt_tokens=int(prompt.size),
+                    duration_s=round(
+                        time.monotonic() - self._admit_t[rid], 6))
+            else:
+                if self._interleave_ok(slot, prompt):
+                    # defer the chunk loop: the slot is reserved
+                    # (blocks allocated, hit chain claimed) but its
+                    # prompt feeds between the coming decode steps
+                    # — _interleave_prefills() finishes the
+                    # admission when the last chunk lands
+                    self._begin_interleaved_prefill(
+                        rid, slot, item, prompt, resume, temp,
+                        topk, topp)
+                    return True
+                with self._psec("elephas.loop.prefill"), \
+                        start_span("serving.prefill",
+                                   stage="prefill"):
+                    t0 = self._admit_prefill(rid, slot, prompt,
+                                             temp, topk, topp)
+        self._rid[slot] = rid
+        # a RESUMED request keeps the tokens it emitted before its
+        # preemption — the new first token (sampled from the full
+        # resubmitted sequence's final-position logits) is exactly
+        # the next token the never-preempted decode would emit
+        self._outputs[rid] = ([] if resume is None
+                              else resume["outputs"])
+        self._slot_prompt[slot] = prompt
+        self._slot_prior[slot] = len(self._outputs[rid])
+        self._slot_tenant[slot] = item.tenant
+        self._slot_priority[slot] = item.priority
+        self._slot_wv[slot] = self.weights_version
+        self._pos[slot] = prompt.size - 1
+        self._last[slot] = t0
+        self._budget[slot] = max_new
+        self._temp[slot] = temp
+        self._topk[slot] = topk
+        self._topp[slot] = topp
+        self._slot_seed[slot] = self._seed.get(rid, -1)
+        if self.qos is not None:
+            self._m_tenant_admitted.labels(
+                tenant=self.qos.label(item.tenant)).inc()
+        if resume is not None:
+            self.recorder.record(
+                rid, "resumed", tokens_so_far=len(self._outputs[rid]),
+                remaining_tokens=int(max_new),
+                preemptions=resume["preempts"])
+        if self._record(slot, t0):
+            # surfaced by the next step(); append — a preempted-
+            # and-resumed request may still owe its PREVIOUS
+            # admission's un-surfaced first token
+            self._fresh.setdefault(rid, []).append(t0)
+        return True
 
     # --------------------------------------------------------- preemption
     @property
@@ -3201,7 +3224,9 @@ class DecodeEngine:
                     rid, slot, prompt, temp, topk, topp)
             logits, row, reused, reg_used = self._host_cache_prefill(
                 rid, prompt)
-            self.cache = self._install_fn(self.cache, row, slot)
+            self._pmeta(prefix_tokens=max(reused, reg_used))
+            with self._psec("elephas.loop.prefill.install"):
+                self.cache = self._install_fn(self.cache, row, slot)
             if self.draft_config is not None:
                 # the cache served (some of) the TARGET's prefill; the
                 # draft's KV is never cached and recomputes in full
@@ -3224,19 +3249,22 @@ class DecodeEngine:
         if entry is not None:
             self._m_prefix_hits.inc()
             self._m_prefix_tokens.inc(int(entry[0].size))
+        self._pmeta(
+            prefix_tokens=0 if entry is None else int(entry[0].size))
         logits, row_cache = self._prefill_with_prefixes(
             prompt, self._extend_fn, self._extend_owned_fn,
             self._prefill_fn, self.params, entry, 2,
             self._fresh_row_fn)
-        if self.paged is not None:
-            from .models.paged_decode import install_row_paged
+        with self._psec("elephas.loop.prefill.install"):
+            if self.paged is not None:
+                from .models.paged_decode import install_row_paged
 
-            nprefill = -(-prompt.size // self.paged[1])
-            self.pool = install_row_paged(
-                self.pool, row_cache, self._tables[slot], nprefill)
-        else:
-            self.cache = self._install_fn(self.cache, row_cache,
-                                          slot)
+                nprefill = -(-prompt.size // self.paged[1])
+                self.pool = install_row_paged(
+                    self.pool, row_cache, self._tables[slot], nprefill)
+            else:
+                self.cache = self._install_fn(self.cache, row_cache,
+                                              slot)
         if self.draft_config is not None:
             self._install_draft_row(slot, prompt, entry=entry)
         t0 = self._sample_first(logits, temp, topk, topp,
@@ -3291,10 +3319,12 @@ class DecodeEngine:
             self.recorder.record(rid, "kv_cache_hit", blocks=j,
                                  tokens_reused=reused,
                                  promoted=len(promos))
-            row = gather_blocks_to_row(
-                self.pool,
-                [int(b) for b in self._tables[slot, :j]],
-                self.max_len)
+            self._pmeta(prefix_tokens=reused)
+            with self._psec("elephas.loop.prefill.row_init"):
+                row = gather_blocks_to_row(
+                    self.pool,
+                    [int(b) for b in self._tables[slot, :j]],
+                    self.max_len)
             logits, row = self._extend_remainder(row, prompt, reused)
         else:
             # classic path, registered row included (longest match
@@ -3311,6 +3341,7 @@ class DecodeEngine:
                 # layer's reuse (counted just above), not a cache miss
                 self._m_kv_misses.inc()
                 cache.record_walk(0, True)
+            self._pmeta(prefix_tokens=reused)
             logits, row = self._prefill_with_prefixes(
                 prompt, self._extend_fn, self._extend_owned_fn,
                 self._prefill_fn, self.params, entry, 2,
@@ -3318,10 +3349,11 @@ class DecodeEngine:
         # install ONLY the remainder blocks: positions [j*bs, ...) —
         # the shared head blocks already hold their positions and other
         # slots may be reading them this very step
-        self.pool = install_row_paged(self.pool, row,
-                                      self._tables[slot], nprefill,
-                                      start=j)
-        self._insert_full_blocks(slot, prompt, skip=j, rid=rid)
+        with self._psec("elephas.loop.prefill.install"):
+            self.pool = install_row_paged(self.pool, row,
+                                          self._tables[slot], nprefill,
+                                          start=j)
+            self._insert_full_blocks(slot, prompt, skip=j, rid=rid)
         if self.draft_config is not None:
             # speculative paged admission: the chain hit (or miss) above
             # served the TARGET cache only — the draft recomputes its
@@ -3423,6 +3455,7 @@ class DecodeEngine:
         if row is None:
             row = (self._fresh_row_fn() if entry is None else entry[2])
             owned = entry is None
+        self._pmeta(prefix_tokens=int(reused))
         self._pending_prefill[slot] = dict(
             rid=rid, item=item, resume=resume, prompt=prompt,
             temp=temp, topk=topk, topp=topp, row=row,
@@ -3439,8 +3472,8 @@ class DecodeEngine:
         """Recompute the chunks-per-iteration budget from the
         profiler's decode-phase share of wall time, every
         :data:`PREFILL_BUDGET_EVERY` iterations (``utilization()``
-        walks the ring under a lock — reading it per step would spend
-        the profiler's <2% overhead budget on the scheduler). Decode
+        walks the ring under a lock — reading it per step would put
+        the profiler's whole per-step cost on the scheduler). Decode
         saturating the loop → 1 chunk/step (in-flight inter-token
         latency wins); decode mostly waiting → up to
         :data:`MAX_INTERLEAVE_CHUNKS` (drain the prompt, TTFT wins).
@@ -3452,7 +3485,8 @@ class DecodeEngine:
         if self.profiler is None:
             self._prefill_budget = 1
             return
-        decode = self.profiler.utilization().get("decode", 0.0)
+        util = self.profiler.utilization()
+        decode = util["decode"] + util["decode_dispatch"]
         self._prefill_budget = max(
             1, int(round((1.0 - decode) * MAX_INTERLEAVE_CHUNKS)))
 
@@ -3708,18 +3742,19 @@ class DecodeEngine:
         the same rule the step fns use, so a resumed request's
         admission token re-samples exactly what the original decode
         emitted at that position."""
-        if temp > 0:
-            if seed is not None:
-                sub = jax.random.fold_in(jax.random.PRNGKey(int(seed)),
-                                         int(fold))
-            else:
-                self._key, sub = jax.random.split(self._key)
-            filt = _filter_logits_rows(
-                logits[None] / temp,
-                jnp.asarray([topk], jnp.int32),
-                jnp.asarray([topp], jnp.float32))[0]
-            return int(jax.random.categorical(sub, filt))
-        return int(jnp.argmax(logits))
+        with self._psec("elephas.loop.prefill.first_token"):
+            if temp > 0:
+                if seed is not None:
+                    sub = jax.random.fold_in(
+                        jax.random.PRNGKey(int(seed)), int(fold))
+                else:
+                    self._key, sub = jax.random.split(self._key)
+                filt = _filter_logits_rows(
+                    logits[None] / temp,
+                    jnp.asarray([topk], jnp.int32),
+                    jnp.asarray([topp], jnp.float32))[0]
+                return int(jax.random.categorical(sub, filt))
+            return int(jnp.argmax(logits))
 
     def _install_prefilled(self, slot: int, prompt: np.ndarray,
                            pre: Tuple) -> int:
@@ -3739,12 +3774,13 @@ class DecodeEngine:
                                       self.max_len)
         row = jax.tree_util.tree_map(
             lambda a: jnp.asarray(a, self.config.dtype), row_np)
-        if self.paged is not None:
-            nprefill = -(-prompt.size // self.paged[1])
-            self.pool = install_row_paged(self.pool, row,
-                                          self._tables[slot], nprefill)
-        else:
-            self.cache = self._install_fn(self.cache, row, slot)
+        with self._psec("elephas.loop.prefill.install"):
+            if self.paged is not None:
+                nprefill = -(-prompt.size // self.paged[1])
+                self.pool = install_row_paged(
+                    self.pool, row, self._tables[slot], nprefill)
+            else:
+                self.cache = self._install_fn(self.cache, row, slot)
         if self.draft_config is not None:
             # disaggregated speculative decode: the shipped frame holds
             # TARGET KV only — prefill the draft locally BEFORE the
@@ -4162,11 +4198,20 @@ class DecodeEngine:
                 + len(self._fresh)
                 + (1 if staged else 0))
 
-    def _psec(self, phase: str):
-        """The profiler section for ``phase`` (a shared no-op context
-        when profiling is off — the hot path pays one attribute read)."""
+    def _psec(self, name: str):
+        """The profiler section for the span ``name`` (a shared no-op
+        context when profiling is off — the hot path pays one attribute
+        read)."""
         prof = self.profiler
-        return _NULL_SECTION if prof is None else prof.section(phase)
+        return _NULL_SECTION if prof is None else prof.section(name)
+
+    def _pmeta(self, **metadata) -> None:
+        """Arguments for the trace span of the admission in progress
+        (``elephas.loop.admit.request``); spans of one request share
+        its ``rid``."""
+        if self.profiler is not None:
+            self.profiler.annotate("elephas.loop.admit.request",
+                                   **metadata)
 
     def _steer_gamma(self, accepted: int, proposed: int) -> None:
         """One control-loop tick of the adaptive speculative depth.
@@ -4222,8 +4267,9 @@ class DecodeEngine:
             self.profiler.tick()
         # slow steps (a prefill-compile-heavy one) also land on the
         # slow-span ring by name
-        with span_if_counted("serving.step", self._m_steps,
-                             histogram=self._m_step_latency):
+        with self._psec("elephas.loop.step"), \
+                span_if_counted("serving.step", self._m_steps,
+                                histogram=self._m_step_latency):
             return self._step_impl()
 
     def _step_impl(self) -> Dict[int, List[int]]:
@@ -4235,7 +4281,7 @@ class DecodeEngine:
             # feed this iteration's chunk budget BEFORE reading _fresh:
             # an admission completing here surfaces its first token in
             # this very step, matching run-to-completion semantics
-            with self._psec("prefill"):
+            with self._psec("elephas.loop.prefill"):
                 self._interleave_prefills()
         emitted = {rid: list(toks) for rid, toks in self._fresh.items()}
         self._fresh = {}
@@ -4254,7 +4300,7 @@ class DecodeEngine:
             # engines); verify slack was budgeted at the ceiling, so any
             # depth <= it writes safely
             g_now = self._gamma_now
-            with self._psec("decode"):
+            with self._psec("elephas.loop.decode.dispatch"):
                 if self.paged is not None:
                     (emit, acc, nxt, self.pool, self.draft_cache,
                      self._key) = self._spec_step_paged_for(g_now)(
@@ -4268,6 +4314,7 @@ class DecodeEngine:
                         self.params, self.draft_params, self.cache,
                         self.draft_cache, jnp.asarray(self._last),
                         jnp.asarray(pos), self._key)
+            with self._psec("elephas.loop.decode.wait"):
                 emit, acc, nxt = (np.asarray(emit), np.asarray(acc),
                                   np.asarray(nxt))
             n_active = int(active.sum())
@@ -4277,7 +4324,7 @@ class DecodeEngine:
             self._m_spec_rounds.inc(n_active)
             if self.adaptive_gamma:
                 self._steer_gamma(n_accepted, g_now * n_active)
-            with self._psec("emit"):
+            with self._psec("elephas.loop.emit"):
                 for slot in np.nonzero(active)[0]:
                     rid = self._rid[slot]
                     # per-request acceptance for the flight recorder's
@@ -4295,7 +4342,7 @@ class DecodeEngine:
             self._admit()
             return emitted
         if self.steps_per_sync > 1:
-            with self._psec("decode"):
+            with self._psec("elephas.loop.decode.dispatch"):
                 if self.paged is not None:
                     toks, self.pool, self._key = \
                         self._multi_step_paged_fn(
@@ -4312,8 +4359,9 @@ class DecodeEngine:
                         jnp.asarray(pos), jnp.asarray(self._temp),
                         jnp.asarray(self._topk), jnp.asarray(self._topp),
                         jnp.asarray(self._slot_seed), self._key)
+            with self._psec("elephas.loop.decode.wait"):
                 toks = np.asarray(toks)                   # (B, K)
-            with self._psec("emit"):
+            with self._psec("elephas.loop.emit"):
                 for slot in np.nonzero(active)[0]:
                     rid = self._rid[slot]
                     for tok in toks[slot]:
@@ -4325,7 +4373,7 @@ class DecodeEngine:
                             emitted.setdefault(rid, []).append(int(tok))
             self._admit()
             return emitted
-        with self._psec("decode"):
+        with self._psec("elephas.loop.decode.dispatch"):
             if self.paged is not None:
                 toks, self.pool, self._key = self._step_paged_fn(
                     self.params, self.pool, jnp.asarray(self._tables),
@@ -4339,8 +4387,9 @@ class DecodeEngine:
                     jnp.asarray(pos), jnp.asarray(self._temp),
                     jnp.asarray(self._topk), jnp.asarray(self._topp),
                     jnp.asarray(self._slot_seed), self._key)
+        with self._psec("elephas.loop.decode.wait"):
             toks = np.asarray(toks)
-        with self._psec("emit"):
+        with self._psec("elephas.loop.emit"):
             for slot in np.nonzero(active)[0]:
                 rid = self._rid[slot]
                 self._pos[slot] += 1

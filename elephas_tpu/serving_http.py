@@ -71,6 +71,7 @@ The reference has no serving server at all (SURVEY.md §2: inference is
 Spark ``mapPartitions``); this is the online half of the framework's
 beyond-parity serving stack.
 """
+import contextlib
 import json
 import re
 import threading
@@ -786,6 +787,39 @@ class ServingServer:
             self._drain_done = None
             done.set()
 
+    def _deliver_locked(self, emitted: Dict) -> bool:
+        """What the engine loop owes the handlers after a step, under
+        the serving lock: route the emitted tokens into their streams,
+        harvest finished requests, wake the blocked handlers, enforce a
+        drain. Returns whether the engine has nothing left to step."""
+        for rid, toks in emitted.items():
+            if rid in self._streams:
+                self._streams[rid].extend(toks)
+        if emitted:
+            self._cond.notify_all()
+        finished = []
+        for rid in list(self._tracked):
+            out = self._result_info(rid)
+            if out is not None:
+                self._results[rid] = out
+                finished.append(rid)
+        if finished:
+            self._tracked.difference_update(finished)
+            while len(self._results) > self.max_stored_results:
+                # abandoned submits: evict oldest unfetched — but never
+                # a result a blocked /v1/generate handler or live
+                # stream is about to claim
+                victim = next(
+                    (r for r in self._results
+                     if r not in self._waiters
+                     and r not in self._streams), None)
+                if victim is None:
+                    break
+                self._results.pop(victim)
+            self._cond.notify_all()
+        self._check_drain_locked()
+        return not self.engine.pending
+
     def _engine_loop(self):
         """The single driver of the device program: steps whenever work
         is pending, harvests finished requests, wakes blocked waiters.
@@ -793,75 +827,65 @@ class ServingServer:
         turns 500, new submits are rejected), every in-flight request is
         failed, and all blocked handlers are woken — a dead engine must
         answer errors, not hang its clients."""
+        # the loop's sections come from the engine's own profiler (none
+        # on a DisaggEngine or a profiler=False engine): its phases and,
+        # under a jax.profiler session, its elephas.server.* trace spans
+        prof = getattr(self.engine, "profiler", None)
+        null = contextlib.nullcontext()
+        section = (lambda name: null) if prof is None else prof.section
         try:
             first_pass_done = False
             while not self._stop.is_set():
-                with self._cond:
+                with section("elephas.server.lock_wait"):
+                    self._cond.acquire()
+                try:
                     emitted = {}
                     if self.engine.pending:
                         emitted = self.engine.step()
-                    for rid, toks in emitted.items():
-                        if rid in self._streams:
-                            self._streams[rid].extend(toks)
-                    if emitted:
-                        self._cond.notify_all()
-                    finished = []
-                    for rid in list(self._tracked):
-                        out = self._result_info(rid)
-                        if out is not None:
-                            self._results[rid] = out
-                            finished.append(rid)
-                    if finished:
-                        self._tracked.difference_update(finished)
-                        while len(self._results) > self.max_stored_results:
-                            # abandoned submits: evict oldest unfetched —
-                            # but never a result a blocked /v1/generate
-                            # handler or live stream is about to claim
-                            victim = next(
-                                (r for r in self._results
-                                 if r not in self._waiters
-                                 and r not in self._streams), None)
-                            if victim is None:
-                                break
-                            self._results.pop(victim)
-                        self._cond.notify_all()
-                    self._check_drain_locked()
-                    idle = not self.engine.pending
-                if self.slo is not None:
-                    # outside the serving lock (the tracker reads the
-                    # registry under per-metric locks): one clock
-                    # check per iteration, a real evaluation only when
-                    # the tracker's interval elapsed. Best-effort: a
-                    # broken objective must never read as engine death
-                    try:
-                        self.slo.maybe_evaluate()
-                    except Exception:  # noqa: BLE001
-                        pass
-                if self.watchdog is not None:
-                    # one beat per iteration, idle included — the LOOP
-                    # heartbeat is the liveness signal (the profiler's
-                    # iteration stamp goes stale on a healthy idle
-                    # engine; it supplies stall ATTRIBUTION, not
-                    # detection)
-                    self.watchdog.beat()
+                    elif prof is not None:
+                        # a pass with nothing to step is an iteration
+                        # too: an idle loop must not read as one long
+                        # (slow) iteration when work arrives
+                        prof.tick()
+                    with section("elephas.server.deliver"):
+                        idle = self._deliver_locked(emitted)
+                finally:
+                    self._cond.release()
+                with section("elephas.server.housekeeping"):
+                    if self.slo is not None:
+                        # outside the serving lock (the tracker reads
+                        # the registry under per-metric locks): one
+                        # clock check per iteration, a real evaluation
+                        # only when the tracker's interval elapsed.
+                        # Best-effort: a broken objective must never
+                        # read as engine death
+                        try:
+                            self.slo.maybe_evaluate()
+                        except Exception:  # noqa: BLE001
+                            pass
+                    if self.watchdog is not None:
+                        # one beat per iteration, idle included — the
+                        # LOOP heartbeat is the liveness signal (the
+                        # profiler supplies stall ATTRIBUTION, not
+                        # detection)
+                        self.watchdog.beat()
                 if not first_pass_done:
                     # ready only after a FULL first iteration — a loop
                     # whose very first step will crash must never show
                     # a 200 /ready window before it does
                     first_pass_done = True
                     self._ready = True
-                if idle:
-                    time.sleep(_IDLE_SLEEP)
-                else:
-                    # fairness yield: this loop holds the serving lock
-                    # for the whole of every step, re-acquiring it
-                    # microseconds after release — without an explicit
-                    # scheduler yield, handler threads (submit, cancel,
-                    # /stats) can starve on the lock for SECONDS while
-                    # the batch is busy (observed: a 2s submit under a
-                    # 50ms-step fault plan). sleep(0) parks this thread
-                    # just long enough for a waiting acquirer to win.
-                    time.sleep(0)
+                with section("elephas.server.yield"):
+                    # busy: a fairness yield. This loop holds the
+                    # serving lock for the whole of every step,
+                    # re-acquiring it microseconds after release —
+                    # without an explicit scheduler yield, handler
+                    # threads (submit, cancel, /stats) can starve on
+                    # the lock for SECONDS while the batch is busy
+                    # (observed: a 2s submit under a 50ms-step fault
+                    # plan). sleep(0) parks this thread just long
+                    # enough for a waiting acquirer to win.
+                    time.sleep(_IDLE_SLEEP if idle else 0)
         except Exception as exc:  # noqa: BLE001 — record ANY engine death
             with self._cond:
                 self._failure = f"{type(exc).__name__}: {exc}"

@@ -11,4 +11,4 @@ from .dataset_utils import (encode_label, from_labeled_points, lp_to_dataset,
 from .checkpoint import CheckpointManager
 from .faults import (FaultEvent, FaultPlan, InjectedFault, active_plan,
                      clear_plan, fault_site, install_plan)
-from .tracing import StepTimer, annotate, profiler_trace
+from .tracing import StepTimer, profiler_trace

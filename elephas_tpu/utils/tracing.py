@@ -93,9 +93,3 @@ def profiler_trace(logdir: Optional[str] = None):
     with jax.profiler.trace(logdir):
         yield
 
-
-def annotate(name: str):
-    """Named trace span (shows up in profiler timelines)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

@@ -27,6 +27,7 @@ from elephas_tpu.obs import (EventLog, LoopProfiler, MetricsRegistry,
                              recent_events)
 from elephas_tpu.obs.context import new_root, use_context
 from elephas_tpu.obs.events import FlightRecorder
+from elephas_tpu.obs.profiler import PHASES, SPANS
 from elephas_tpu.serving_engine import DecodeEngine
 
 
@@ -185,6 +186,256 @@ def test_loop_profiler_exclusive_nesting_and_off_switch(tiny):
     assert eng.profiler is None
     assert eng.registry.get("serving_loop_utilization") is None
     assert "loop" not in eng.stats
+
+
+def _fake_profiler(window_s=100.0):
+    reg = MetricsRegistry()
+    clk = [0.0]
+    prof = LoopProfiler(reg, window_s=window_s, track_jit=False,
+                        clock=lambda: clk[0])
+    return reg, clk, prof
+
+
+def _phase_seconds(reg, name="serving_loop_phase_seconds_total"):
+    return {key[0]: child.value
+            for key, child in reg.get(name).series().items()}
+
+
+def test_phase_seconds_counters_are_the_sections_exclusive_times():
+    reg, clk, prof = _fake_profiler()
+    assert set(_phase_seconds(reg)) == set(PHASES)      # all from the start
+    assert all(v == 0 for v in _phase_seconds(reg).values())
+    prof.tick()
+    before = _phase_seconds(reg)
+    with prof.section("elephas.server.lock_wait"):
+        clk[0] += 0.25
+    prof.tick()                                # iteration 1: 0.25 s
+    with prof.section("elephas.loop.step"):    # parent only
+        clk[0] += 0.01                         # its own: no phase
+        with prof.section("elephas.loop.admit"):
+            clk[0] += 0.02
+            with prof.section("elephas.loop.admit.request"):
+                clk[0] += 0.03                 # parent only: to admit
+                with prof.section("elephas.loop.admit.claim"):
+                    clk[0] += 0.04
+                with prof.section("elephas.loop.prefill"):
+                    clk[0] += 0.05
+                    with prof.section("elephas.loop.prefill.chunks"):
+                        clk[0] += 0.06         # parent only: to prefill
+        with prof.section("elephas.loop.decode.dispatch"):
+            clk[0] += 0.07
+        with prof.section("elephas.loop.decode.wait"):
+            clk[0] += 0.08
+        with prof.section("elephas.loop.emit"):
+            clk[0] += 0.09
+    with prof.section("elephas.server.deliver"):
+        clk[0] += 0.10
+    with prof.section("elephas.server.housekeeping"):
+        clk[0] += 0.11
+    with prof.section("elephas.server.yield"):
+        clk[0] += 0.12
+    clk[0] += 0.13                             # no section: idle
+    prof.tick()                                # iteration 2
+    delta = {ph: v - before[ph]
+             for ph, v in _phase_seconds(reg).items()}
+    want = {"lock_wait": 0.25, "admit": 0.02 + 0.03 + 0.04,
+            "prefill": 0.05 + 0.06, "decode_dispatch": 0.07,
+            "decode": 0.08, "emit": 0.09, "deliver": 0.10 + 0.11,
+            "yield": 0.12, "idle": 0.01 + 0.13}
+    for ph in PHASES:
+        assert delta[ph] == pytest.approx(want.get(ph, 0.0)), ph
+    # the phases and idle sum to the wall
+    assert sum(delta.values()) == pytest.approx(clk[0])
+    assert reg.get("serving_loop_iterations_total").value == 2
+    # the gauges read the same accumulation
+    util = prof.utilization()
+    assert util["decode"] == pytest.approx(0.08 / clk[0])
+    assert util["decode_dispatch"] == pytest.approx(0.07 / clk[0])
+    assert sum(util.values()) == pytest.approx(1.0)
+    # a bare phase name still is a section (span elephas.loop.<phase>)
+    with prof.section("decode"):
+        clk[0] += 1.0
+    prof.tick()
+    assert _phase_seconds(reg)["decode"] == pytest.approx(1.08)
+
+
+def test_slow_iteration_lands_once_under_its_dominant_phase():
+    reg, clk, prof = _fake_profiler()
+    clear_events()
+    prof.tick()
+    with prof.section("elephas.loop.decode.wait"):
+        clk[0] += 0.9                          # 0.9 s: not slow
+    prof.tick()
+    with prof.section("elephas.loop.emit"):
+        clk[0] += 0.3
+    with prof.section("elephas.server.lock_wait"):
+        clk[0] += 0.8
+    clk[0] += 0.1
+    prof.tick()                                # 1.2 s, most in lock_wait
+    with prof.section("elephas.loop.emit"):
+        clk[0] += 0.2
+    prof.tick()
+    slow = _phase_seconds(reg, "serving_loop_slow_iterations_total")
+    slow_s = _phase_seconds(reg,
+                            "serving_loop_slow_iteration_seconds_total")
+    assert set(slow) == set(slow_s) == set(PHASES)
+    assert slow.pop("lock_wait") == 1
+    assert slow_s.pop("lock_wait") == pytest.approx(1.2)
+    assert not any(slow.values()) and not any(slow_s.values())
+    events = [e for e in recent_events()
+              if e["event"] == "engine.slow_iteration"]
+    assert len(events) == 1
+    assert events[0]["phase"] == "lock_wait"
+    assert events[0]["wall_s"] == pytest.approx(1.2)
+    assert events[0]["phases"] == {"emit": pytest.approx(0.3),
+                                   "lock_wait": pytest.approx(0.8),
+                                   "idle": pytest.approx(0.1)}
+    # an iteration nothing claimed is slow under idle
+    clk[0] += 5.0
+    prof.tick()
+    assert _phase_seconds(
+        reg, "serving_loop_slow_iteration_seconds_total")[
+            "idle"] == pytest.approx(5.0)
+
+
+def test_compiles_and_collections_leave_the_section_they_interrupt():
+    reg, clk, prof = _fake_profiler()
+    prof.tick()
+    with prof.section("elephas.loop.prefill"):
+        clk[0] += 1.0
+        with prof.section("elephas.loop.prefill.chunks"):
+            clk[0] += 2.0                      # a compile's wall time
+            prof.record_compile(2.0)
+            clk[0] += 0.5                      # a collection's
+            prof.record_gc(0.5)
+            clk[0] += 0.25
+    prof.tick()
+    got = _phase_seconds(reg)
+    assert got["jit"] == pytest.approx(2.0)
+    assert got["gc"] == pytest.approx(0.5)
+    assert got["prefill"] == pytest.approx(1.25)
+    assert got["idle"] == pytest.approx(0.0)
+    # the collector's own hook: a real collection inside a section, on
+    # the thread the profiler is bound to, goes to the gc phase
+    import gc
+
+    reg2 = MetricsRegistry()
+    real = LoopProfiler(reg2, track_jit=False)
+    real.tick()
+    junk = [[i] for i in range(20000)]
+    for item in junk:
+        item.append(junk)                      # cycles to find
+    with real.section("elephas.loop.emit"):
+        del junk, item
+        gc.collect()
+    real.tick()
+    got = _phase_seconds(reg2)
+    assert got["gc"] > 0
+    assert got["emit"] >= 0
+    # a collection may strike anywhere, also while tick() folds the
+    # closed iteration: with the collector on a hair trigger the loop
+    # must go on (the hook claims into the new iteration's split)
+    threshold = gc.get_threshold()
+    gc.set_threshold(1)
+    try:
+        for _ in range(500):
+            real.tick()
+            with real.section("elephas.loop.emit"):
+                junk = [[] for _ in range(8)]
+    finally:
+        gc.set_threshold(*threshold)
+    assert reg2.get("serving_loop_iterations_total").value >= 500
+
+
+def _host_lines(trace_dir):
+    """``[[(name, start, end, stats)]]``: the ``elephas.`` events of
+    each host thread line of the newest trace under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(trace_dir, "plugins", "profile",
+                                      "*", "*.xplane.pb")),
+               key=os.path.getmtime)
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            events = [(ev.name, int(ev.start_ns),
+                       int(ev.start_ns) + int(ev.duration_ns),
+                       dict(ev.stats))
+                      for ev in line.events
+                      if ev.name.startswith("elephas.")]
+            if events:
+                lines.append(events)
+    return lines
+
+
+def test_loop_spans_land_in_the_profilers_trace(tiny, tmp_path):
+    """The sections are host spans of ``jax.profiler``'s own trace: one
+    thread line holds the engine loop, the step's parts inside
+    ``elephas.loop.step`` and the server's outside it, and an
+    admission carries its request id."""
+    from elephas_tpu import ServingServer
+
+    c, params = tiny
+    eng = DecodeEngine(params, c, max_slots=2, paged=(16, 8),
+                       prefill_chunk=8)
+    eng.warmup(prompt_lengths=[12])
+    srv = ServingServer(eng).start()
+    url = f"http://127.0.0.1:{srv.port}/v1/generate"
+    try:
+        _post(url, {"prompt": list(range(1, 13)), "max_new_tokens": 2})
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            out = _post(url, {"prompt": list(range(2, 14)),
+                              "max_new_tokens": 5})
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        srv.stop()
+    assert out["status"] == "done" and len(out["tokens"]) == 5
+    lines = [ln for ln in _host_lines(str(tmp_path))
+             if any(name == "elephas.loop.step" for name, *_ in ln)]
+    assert len(lines) == 1                     # one engine-loop thread
+    events = lines[0]
+    assert {name for name, *_ in events} <= set(SPANS)
+    steps = [(s, e) for name, s, e, _ in events
+             if name == "elephas.loop.step"]
+    assert len(steps) >= 4
+
+    def inside_a_step(start, end):
+        return any(s <= start and end <= e for s, e in steps)
+
+    by_name = {}
+    for name, start, end, stats in events:
+        by_name.setdefault(name, []).append((start, end, stats))
+    for name in ("elephas.loop.decode.dispatch", "elephas.loop.decode.wait",
+                 "elephas.loop.emit", "elephas.loop.admit",
+                 "elephas.loop.admit.request", "elephas.loop.admit.claim",
+                 "elephas.loop.prefill", "elephas.loop.prefill.row_init",
+                 "elephas.loop.prefill.chunks",
+                 "elephas.loop.prefill.install",
+                 "elephas.loop.prefill.first_token"):
+        assert by_name.get(name), name
+        assert all(inside_a_step(s, e) for s, e, _ in by_name[name]), name
+    for name in ("elephas.server.lock_wait", "elephas.server.deliver",
+                 "elephas.server.housekeeping", "elephas.server.yield"):
+        assert by_name.get(name), name
+        assert not any(inside_a_step(s, e) for s, e, _ in by_name[name])
+    (r_start, r_end, stats), = by_name["elephas.loop.admit.request"]
+    assert stats == {"rid": 1, "prompt_tokens": 12, "prefix_tokens": 0}
+    (p_start, p_end, _), = by_name["elephas.loop.prefill"]
+    assert r_start <= p_start and p_end <= r_end
+    for name in ("row_init", "chunks", "install", "first_token"):
+        (s, e, _), = by_name[f"elephas.loop.prefill.{name}"]
+        assert p_start <= s and e <= p_end, name
+    # no session: a section writes nothing and the loop goes on
+    assert eng.registry.get("serving_loop_iterations_total").value > 0
 
 
 # ----------------------------------------------------- SLO / burn rates
