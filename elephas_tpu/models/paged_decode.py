@@ -11,11 +11,16 @@ eos retirements return their blocks immediately, so a pool far smaller
 than ``max_slots × max_len`` serves the same traffic (admission simply
 queues when the pool is momentarily empty).
 
-The trade: each step gathers the slot's blocks into attention order
-(one extra O(cache) HBM pass versus reading a contiguous strip), so
-paged mode is a CAPACITY lever, not a speed lever — exactly like the
-int8 KV cache (BASELINE.md decode row). Use it when concurrency ×
-max_len exceeds HBM, not to make a fitting workload faster.
+The decode step (:func:`decode_step_paged`) attends over a FLAT list
+of the blocks its rows hold: per layer one leading-axis gather of
+``held_blocks`` pool blocks, contracted in the pool's own ``(block,
+head, pos, D)`` layout and normalised per row across its blocks. Its
+cost follows the SUM of what the rows hold (bucketed to one of a few
+static widths, picked on the device), not ``batch × table width``: a
+row of 40 positions costs 3 blocks, whatever ``max_len`` is. The
+gathered copy is still one extra pass over the held K/V versus
+streaming the blocks in place (``kernel="pallas"``), and a contiguous
+strip read in place is still the cheapest of all at full occupancy.
 
 Math mirrors :func:`~elephas_tpu.models.transformer.decode_block`
 (S=1) exactly — same norms, RoPE convention, GQA grouping,
@@ -46,7 +51,7 @@ layers.
 """
 import math
 from functools import partial
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +63,7 @@ from .transformer import (NEG_INF, TransformerConfig, _alibi_slope_list,
                           _sinusoidal_table, head_logits)
 
 __all__ = ["init_paged_pool", "decode_step_paged", "decode_block_paged",
+           "held_block_count",
            "install_row_paged", "gather_blocks_to_row",
            "validate_paged_config", "export_kv_blocks",
            "import_kv_blocks", "export_pool_blocks",
@@ -298,36 +304,126 @@ def _install_blocks_jit(pool, blocks, block_ids):
             for name, lc in pool.items()}
 
 
+def _held_range(pos, block_size: int, max_blocks: int, window, xp):
+    """Table entries a row at ``pos`` attends over, as (first, count):
+    every block up to the one holding ``pos``, from the first the
+    ``window`` touches. ``xp`` is ``numpy`` on the host and
+    ``jax.numpy`` in the traced step, so both count by one formula."""
+    last = xp.minimum(pos // block_size, max_blocks - 1)
+    if window is None:
+        return xp.zeros_like(last), last + 1
+    first = xp.minimum(xp.maximum(pos - window + 1, 0) // block_size,
+                       last)
+    return first, last - first + 1
+
+
+def held_block_count(pos, block_size: int, max_blocks: int,
+                     window=None) -> int:
+    """Blocks the rows at host positions ``pos`` hold in all: the least
+    ``held_blocks`` :func:`decode_step_paged` may be given for them. An
+    inactive row (``pos`` 0) holds its one scratch block."""
+    return int(_held_range(np.asarray(pos), block_size, max_blocks,
+                           window, np)[1].sum())
+
+
+def _held_slots(tables, pos, bs: int, window, width: int, alibi: bool):
+    """Lay the rows' held blocks out as one flat list of ``width``
+    slots, row after row, from ``tables`` and ``pos`` alone. Returns the
+    number of live slots and the slot-major arrays ``row`` ``(W,)`` (the
+    slot's owner), ``owns`` ``(W, B)`` (its one-hot, all false on
+    padding slots), ``flat_blk`` ``(W,)`` (the pool block; padding reads
+    scratch block 0), ``mask`` ``(W, bs)`` (positions the owner attends
+    to; all false on padding) and, under ``alibi``, ``dist`` ``(W, bs)``
+    (query position minus key position). Live slots come first, so the
+    first ``w`` slots are the whole list for any ``w`` that covers
+    them."""
+    b, mb = tables.shape
+    first, count = _held_range(pos, bs, mb, window, jnp)
+    ends = jnp.cumsum(count)
+    slot = jnp.arange(width)
+    row = jnp.minimum(jnp.searchsorted(ends, slot, side="right",
+                                       method="compare_all"), b - 1)
+    live = slot < ends[-1]
+    j = jnp.where(live, first[row] + slot - (ends - count)[row], 0)
+    flat_blk = jnp.where(live, tables[row, j], 0)
+    kpos = j[:, None] * bs + jnp.arange(bs)[None, :]
+    rpos = pos[row][:, None]
+    mask = live[:, None] & (kpos <= rpos)
+    if window is not None:
+        mask = mask & (kpos > rpos - window)
+    owns = (row[:, None] == jnp.arange(b)[None, :]) & live[:, None]
+    dist = (rpos - kpos).astype(jnp.float32) if alibi else None
+    return ends[-1], (row, owns, flat_blk, mask, dist)
+
+
+def _held_attention(q, pk, pv, slots, slopes, scale):
+    """Attention of each row's query ``q`` ``(B, KV, G, D)`` over the
+    flat list of held blocks ``slots`` (:func:`_held_slots`): one
+    leading-axis gather each from the pools ``pk``/``pv``, contracted in
+    their own ``(block, head, pos, D)`` layout. Scores and the softmax's
+    sums are float32; the softmax is normalised per row across its
+    slots through the one-hot ``owns`` (a masked max, a masked sum and
+    one matmul) — a scatter-based segment sum would serialise on the
+    TPU. Returns ``(B, KV, G, D)``."""
+    row, owns, flat_blk, mask, dist = slots
+    kb, vb = pk[flat_blk], pv[flat_blk]            # (W, KV, bs, D)
+    s = jnp.einsum("wngd,wnpd->wngp", q[row], kb,
+                   preferred_element_type=jnp.float32) * scale
+    if dist is not None:
+        s = s - slopes.reshape(1, *q.shape[1:3], 1) * dist[:, None, None]
+    s = jnp.where(mask[:, None, None, :], s, NEG_INF)
+    own = owns[:, :, None, None]                   # (W, B, 1, 1)
+    top = jnp.max(jnp.where(own, s.max(-1)[:, None], NEG_INF), axis=0)
+    p = jnp.exp(s - top[row][..., None])           # masked -> exactly 0
+    denom = jnp.sum(jnp.where(own, p.sum(-1)[:, None], 0.0), axis=0)
+    o = jnp.einsum("wngp,wnpd->wngd", p.astype(vb.dtype), vb,
+                   preferred_element_type=jnp.float32)
+    # (a plain bf16 x bf16 -> f32 matmul is not implemented on the CPU
+    # backend, so the per-row sum leaves the matmul in the data dtype)
+    o = jnp.einsum("wb,wngd->bngd", owns.astype(vb.dtype),
+                   o.astype(vb.dtype))
+    return (o.astype(jnp.float32) / denom[..., None]).astype(vb.dtype)
+
+
 def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
                       tokens: jnp.ndarray, pos,
                       config: TransformerConfig,
                       kernel: str = "gather",
-                      interpret=None) -> Tuple[jnp.ndarray, Dict]:
+                      interpret=None,
+                      held_blocks: Union[None, int, Sequence[int]] = None
+                      ) -> Tuple[jnp.ndarray, Dict]:
     """One autoregressive step over the block pool: token ids ``(B,)``
     at per-row positions ``pos`` ``(B,)``; ``tables`` is ``(B,
     max_blocks)`` of block ids. Returns (logits ``(B, vocab)``, updated
     pool). The paged mirror of
     :func:`~elephas_tpu.models.transformer.decode_step`.
 
-    ``kernel`` selects the attention inner loop: ``"gather"`` (default)
-    materializes each row's blocks into attention order and runs a
-    full-row masked softmax; ``"pallas"`` runs
+    ``kernel`` selects the attention inner loop. ``"gather"`` (default,
+    plain XLA) attends over the flat list of blocks the rows hold: a
+    row at ``pos`` holds ``pos // block_size + 1`` blocks (under
+    ``attention_window`` only those the window touches), the list is
+    laid out row after row from ``tables`` and ``pos`` alone, padded to
+    a static width with masked reads of scratch block 0, gathered once
+    per layer and normalised per row. ``held_blocks`` gives the width:
+    an int must be at least the sum of the rows' blocks (the caller's
+    to guarantee — a traced program cannot check it); a sequence of
+    widths compiles a branch for each and the step picks, on the
+    device, the narrowest that covers what its rows hold (the widest
+    has to cover any input it will meet); ``None`` means ``B ×
+    max_blocks``, enough for any input. ``"pallas"`` runs
     :func:`~elephas_tpu.ops.paged_attention.paged_decode_attention`,
-    which fuses the block gather into a flash-style online-softmax
-    kernel (no gathered copy — the decode hot-path saving). The two
-    agree to float rounding (the online softmax associates the
-    reduction differently), pinned by the variant-matrix parity tests.
-    ``interpret`` is threaded to the Pallas kernel (tests force the
-    interpreter off-TPU; production callers leave it ``None``)."""
+    which streams each table block from the pool into a flash-style
+    online-softmax kernel (no gathered copy; it walks the whole table
+    and ignores ``held_blocks``). The two agree to float rounding,
+    pinned by the variant-matrix parity tests. ``interpret`` is
+    threaded to the Pallas kernel (tests force the interpreter off-TPU;
+    production callers leave it ``None``)."""
     if kernel not in ("gather", "pallas"):
         raise ValueError(f"unknown paged decode kernel {kernel!r}; "
                          "expected 'gather' or 'pallas'")
     c = config
     b = tokens.shape[0]
-    first = next(iter(pool.values()))["k"]
-    bs = first.shape[2]
-    mb = tables.shape[1]
-    length = mb * bs                               # gathered view length
+    bs = next(iter(pool.values()))["k"].shape[2]
     pos = jnp.asarray(pos)
     blk = jnp.take_along_axis(tables, (pos // bs)[:, None],
                               axis=1)[:, 0]        # (B,) owning block
@@ -340,15 +436,31 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
         x = x + _sinusoidal_table(pos, c.d_model)
     x = x.astype(c.dtype)[:, None]                 # (B, 1, D)
 
-    kpos = jnp.arange(length)
-    mask = kpos[None, :] <= pos[:, None]           # (B, L)
-    if c.attention_window is not None:
-        mask = mask & (kpos[None, :] > (pos[:, None]
-                                        - c.attention_window))
     scale = 1.0 / math.sqrt(c.head_dim)
     rp = pos[:, None, None]                        # (B, 1, 1) rope angles
     groups = c.num_heads // c.kv_heads
     hidx = jnp.arange(c.kv_heads)
+    if kernel == "gather":
+        if held_blocks is None:
+            held_blocks = b * tables.shape[1]
+        widths = (sorted(set(held_blocks)) if isinstance(
+            held_blocks, (tuple, list)) else [int(held_blocks)])
+        alibi = c.positional == "alibi"
+        held, slots = _held_slots(tables, pos, bs, c.attention_window,
+                                  widths[-1], alibi)
+        slopes = _alibi_slopes(c.num_heads) if alibi else None
+
+        def attend_over(width):
+            # the first `width` slots are the whole list when they
+            # cover what the rows hold
+            cut = jax.tree_util.tree_map(lambda a: a[:width], slots)
+            return lambda q, pk, pv: _held_attention(q, pk, pv, cut,
+                                                     slopes, scale)
+
+        branches = [attend_over(w) for w in widths]
+        # the narrowest width that covers the rows, picked on the
+        # device from what the step is given anyway
+        pick = jnp.searchsorted(jnp.asarray(widths), held, side="left")
     new_pool: Dict = {}
     for i in range(c.num_layers):
         layer = params[f"layer_{i}"]
@@ -382,28 +494,10 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
                               if c.positional == "alibi" else None),
                 interpret=interpret)[:, :, None, :]
         else:
-            # gather each row's blocks into attention order: (B, MB, H,
-            # bs, D) -> (B, H, MB*bs, D). The one extra O(cache) pass
-            # paged mode pays; positions beyond the row's allocation
-            # land on stale/scratch data and are masked
-            ck = jnp.swapaxes(pk[tables], 1, 2).reshape(
-                b, c.kv_heads, length, c.head_dim)
-            cv = jnp.swapaxes(pv[tables], 1, 2).reshape(
-                b, c.kv_heads, length, c.head_dim)
-
-            qg = q.reshape(b, c.kv_heads, groups, 1, c.head_dim)
-            scores = jnp.einsum("bngsk,bntk->bngst", qg, ck) * scale
-            if c.positional == "alibi":
-                dist = (pos[:, None] - kpos[None, :]).astype(jnp.float32)
-                ab = (-_alibi_slopes(c.num_heads)[None, :, None, None]
-                      * dist[:, None, None]).reshape(b, c.kv_heads,
-                                                     groups, 1, length)
-                scores = scores + ab
-            scores = jnp.where(mask[:, None, None, None, :], scores,
-                               NEG_INF)
-            weights = jax.nn.softmax(scores, axis=-1)
-            o = jnp.einsum("bngst,bntk->bngsk", weights, cv)
-            o = o.reshape(b, c.num_heads, 1, c.head_dim)
+            qg = q.reshape(b, c.kv_heads, groups, c.head_dim)
+            # (a single width is no branch: lax.switch calls it)
+            o = jax.lax.switch(pick, branches, qg, pk, pv).reshape(
+                b, c.num_heads, 1, c.head_dim)
         x = x + jnp.einsum("bhsk,hkd->bsd", o,
                            layer["attn"]["wo"].astype(c.dtype))
         x = _mlp_apply(layer, x, c)

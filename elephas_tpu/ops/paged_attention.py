@@ -2,10 +2,10 @@
 
 The serving engine's paged decode step
 (:func:`~elephas_tpu.models.paged_decode.decode_step_paged`) reads the
-KV cache by materializing a gathered view: ``pool[tables]`` copies
-every live block into attention order — one extra O(cache) HBM pass
-per layer per step — and then runs a plain masked softmax over it.
-This module fuses the gather INTO the attention loop: the kernel's
+KV cache by materializing a gathered copy of the blocks its rows hold
+— one extra pass over the held K/V per layer per step — and then runs
+a masked softmax over it. This module fuses the gather INTO the
+attention loop, over each row's whole table: the kernel's
 ``BlockSpec`` index map reads the block table (scalar-prefetched into
 SMEM) and DMAs each block of k/v straight from its pool slot into
 VMEM, accumulating flash-style online softmax across the row's blocks.

@@ -259,9 +259,11 @@ class DecodeEngine:
         a shared block pool with per-slot block tables (vLLM's paged
         memory model): cache memory scales with tokens in flight
         instead of ``max_slots × max_len``, requests queue while the
-        pool is momentarily empty, and blocks return on retirement —
-        a CAPACITY lever for oversubscribed serving (each step pays one
-        extra gather pass over the cache; see
+        pool is momentarily empty, and blocks return on retirement.
+        A decode step gathers the blocks the rows hold, so its cost
+        follows the tokens in flight too (the step program picks, on
+        the device, from a ladder of widths derived from ``max_slots``
+        and the table width; see
         :mod:`~elephas_tpu.models.paged_decode`). Composes with prefix
         caching, chunked prefill, multi-step, and speculative mode
         (each slot's allocation budgets ``gamma`` extra positions of
@@ -338,8 +340,10 @@ class DecodeEngine:
         (the bench A/B baseline) or an instance to share one across
         wrappers.
     :param kernel: paged decode-attention inner loop: ``"gather"``
-        (default — materialize each row's blocks, full-row softmax) or
-        ``"pallas"`` (fused block-gather flash kernel,
+        (default, plain XLA — one gather per layer of the flat list of
+        blocks the rows hold, softmax normalised per row across its
+        blocks) or ``"pallas"`` (flash kernel streaming each row's
+        whole table from the pool,
         :mod:`~elephas_tpu.ops.paged_attention`; TPU only — off-TPU the
         engine falls back to gather with a ``serving.kernel_fallback``
         event, and ``stats["kernel"]`` reports what actually runs).
@@ -473,8 +477,8 @@ class DecodeEngine:
             # per-slot table width: enough blocks to cover max_len
             self._mb = -(-self.max_len // block_size)
         # paged decode-attention kernel selection: "gather" (default)
-        # materializes each row's blocks; "pallas" fuses the gather into
-        # a flash-style online-softmax kernel
+        # gathers the blocks the rows hold; "pallas" streams each row's
+        # table into a flash-style online-softmax kernel
         # (:mod:`~elephas_tpu.ops.paged_attention`). The compiled kernel
         # needs a TPU: elsewhere the engine FALLS BACK to gather (a
         # ``serving.kernel_fallback`` event; ``stats["kernel"]`` reports
@@ -793,6 +797,31 @@ class DecodeEngine:
         # acceptance observability; survives preemption — keyed by rid)
         self._accept: Dict[int, List[int]] = {}
         if self.paged is not None:
+            # widths of the decode step's flat block list, derived from
+            # the shapes: doubling up to max_slots x table width (enough
+            # for any rows), at most 6, none narrower than the batch
+            # (every row holds at least its scratch block). The Pallas
+            # kernel walks whole tables: one width
+            top = self.max_slots * self._mb
+            self._held_ladder = tuple(sorted(
+                {max(top >> k, self.max_slots) for k in range(
+                    1 if self.kernel == "pallas" else 6)}))
+            self._m_blocks_held = reg.counter(
+                "serving_decode_blocks_held_total",
+                "KV blocks the batch's rows held, summed over decode "
+                "dispatches (a fused dispatch counts its first step)"
+                ).labels()
+            self._m_blocks_read = reg.counter(
+                "serving_decode_blocks_read_total",
+                "KV blocks the decode program gathered per layer (the "
+                "ladder width it picked), summed over decode dispatches; "
+                "held / read is the share a row really holds").labels()
+            fam = reg.counter(
+                "serving_decode_steps_total",
+                "decode dispatches by the width of their flat block "
+                "list", labels=("width",))
+            self._m_steps_by_width = {
+                w: fam.labels(width=str(w)) for w in self._held_ladder}
             reg.gauge("serving_paged_blocks_free",
                       "allocatable KV blocks currently free"
                       ).set_function(
@@ -951,13 +980,17 @@ class DecodeEngine:
             from .models.paged_decode import decode_step_paged
 
             kern, kern_interp = self.kernel, self._kernel_interpret
+            # one program holds a branch per ladder width and picks
+            # among them on the device, from the positions it is given
+            ladder = self._held_ladder
 
             def _one_step_paged(params, pool, tables, last, pos, temps,
                                 topk, topp, seeds, key):
                 logits, pool = decode_step_paged(params, pool, tables,
                                                  last, pos, cfg,
                                                  kernel=kern,
-                                                 interpret=kern_interp)
+                                                 interpret=kern_interp,
+                                                 held_blocks=ladder)
                 tok, key = _sample_tok(logits, temps, topk, topp, seeds,
                                        pos, key)
                 return tok, pool, key
@@ -4344,6 +4377,7 @@ class DecodeEngine:
         if self.steps_per_sync > 1:
             with self._psec("elephas.loop.decode.dispatch"):
                 if self.paged is not None:
+                    self._count_held(pos)
                     toks, self.pool, self._key = \
                         self._multi_step_paged_fn(
                             self.params, self.pool,
@@ -4375,6 +4409,7 @@ class DecodeEngine:
             return emitted
         with self._psec("elephas.loop.decode.dispatch"):
             if self.paged is not None:
+                self._count_held(pos)
                 toks, self.pool, self._key = self._step_paged_fn(
                     self.params, self.pool, jnp.asarray(self._tables),
                     jnp.asarray(self._last), jnp.asarray(pos),
@@ -4398,6 +4433,23 @@ class DecodeEngine:
                     emitted.setdefault(rid, []).append(int(toks[slot]))
         self._admit()
         return emitted
+
+    def _held_width(self, pos: np.ndarray) -> Tuple[int, int]:
+        """(blocks the rows at ``pos`` hold, the ladder width the step
+        program picks for them): the host's copy of the device's
+        arithmetic, for the counters."""
+        from .models.paged_decode import held_block_count
+
+        held = held_block_count(pos, self.paged[1], self._mb,
+                                self.config.attention_window)
+        assert held <= self._held_ladder[-1], (held, self._held_ladder)
+        return held, next(w for w in self._held_ladder if w >= held)
+
+    def _count_held(self, pos: np.ndarray):
+        held, width = self._held_width(pos)
+        self._m_blocks_held.inc(held)
+        self._m_blocks_read.inc(width)
+        self._m_steps_by_width[width].inc()
 
     def run(self, requests: Sequence[Sequence[int]],
             max_new_tokens: int) -> List[List[int]]:
