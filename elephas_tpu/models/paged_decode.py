@@ -45,9 +45,32 @@ prefix-cache blocks stay read-only under it for the same reason they
 do under plain decode: every verify write lands at a position at or
 past the prompt length, past every shared full block.
 
+**What a block holds** is asked of the model
+(``TransformerConfig.cache_leaves``): ``k`` and ``v`` per KV head for
+``attention_kind="mha"``; for ``"mla"`` ONE leaf ``latent`` of shape
+``(num_blocks, 1, block_size, latent_width)`` -- the normalised latent
+and the rotated rope key every head shares, 576 values at DeepSeek-V2's
+sizes (1,152 bytes a position and layer in bf16), zero-padded to 640 so
+that the pool keeps a row-major layout on the TPU
+(:func:`~elephas_tpu.models.mla.latent_width`). Install,
+gather, export and import map over those leaves; nothing here names
+``k`` or ``v`` outside the ``mha`` attention itself. The ``mla`` decode
+step is the ABSORBED form (:mod:`~elephas_tpu.models.mla`): the key half
+of ``W_kvb`` is folded into the query, the 128 heads score against the
+held latents directly, and the value half is applied to the weighted
+sum of latents -- K and V are never expanded. Its flat list is laid out
+in tiles of :data:`LATENT_TILE_BLOCKS` blocks, each tile owned by one
+row, so that the per-tile partial sums (heads x kv_lora_rank values)
+stay small against the latents read.
+
+Expert layers (``mlp_kinds``, either expert variant) run in the paged
+step like any MLP; with ``with_stats`` the step also returns the
+routing counts of its swiglu expert layers.
+
 Not supported in paged mode (constructor raises): ``kv_cache_quant``
-(compose the int8 cache with the contiguous engine instead) and MoE
-layers.
+(compose the int8 cache with the contiguous engine instead). The
+speculative verify block (:func:`decode_block_paged`) refuses
+``attention_kind="mla"``.
 """
 import math
 from functools import partial
@@ -57,17 +80,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import mla as _mla
 from .transformer import (NEG_INF, TransformerConfig, _alibi_slope_list,
                           _alibi_slopes,
-                          _apply_rope, _mlp_apply, _norm,
+                          _apply_rope, _mlp_sublayer, _norm,
                           _sinusoidal_table, head_logits)
 
 __all__ = ["init_paged_pool", "decode_step_paged", "decode_block_paged",
-           "held_block_count",
+           "held_block_count", "held_tile", "held_ladder",
+           "LATENT_TILE_BLOCKS",
            "install_row_paged", "gather_blocks_to_row",
-           "validate_paged_config", "export_kv_blocks",
+           "validate_paged_config", "require_per_head_cache",
+           "export_kv_blocks",
            "import_kv_blocks", "export_pool_blocks",
            "install_pool_blocks"]
+
+
+#: blocks in one tile of the ``mla`` step's flat list; a row's held
+#: blocks are padded to whole tiles (at most ``LATENT_TILE_BLOCKS - 1``
+#: masked blocks a row)
+LATENT_TILE_BLOCKS = 8
 
 
 def validate_paged_config(config: TransformerConfig):
@@ -75,21 +107,52 @@ def validate_paged_config(config: TransformerConfig):
         raise ValueError("paged KV mode does not compose with "
                          "kv_cache_quant; use the contiguous engine for "
                          "the int8 cache")
-    if config.num_experts > 1:
-        raise ValueError("paged KV mode does not support MoE layers")
+
+
+def require_per_head_cache(config: TransformerConfig, what: str):
+    """Refuse ``what`` for a latent cache: the KV tiers, the
+    disaggregated wire and the speculative verify block move or score
+    per-head k/v and have no ``mla`` form yet."""
+    if config.attention_kind == "mla":
+        raise ValueError(f"{what} has no latent-cache form yet: it is "
+                         "not available with attention_kind='mla'")
+
+
+def held_tile(config: TransformerConfig) -> int:
+    """Blocks a row's share of the decode step's flat list is padded to
+    a multiple of: 1 for per-head k/v, :data:`LATENT_TILE_BLOCKS` for
+    the latent pool."""
+    return LATENT_TILE_BLOCKS if config.attention_kind == "mla" else 1
+
+
+def held_ladder(config: TransformerConfig, rows: int, max_blocks: int,
+                rungs: int = 6) -> Tuple[int, ...]:
+    """Widths for :func:`decode_step_paged`'s ``held_blocks``, derived
+    from the shapes: ``rows`` x table width (in whole tiles: enough for
+    any rows) halved ``rungs - 1`` times, none narrower than a tile a
+    row (every row holds at least its scratch block)."""
+    tile = held_tile(config)
+    top = rows * (-(-max_blocks // tile) * tile)
+    return tuple(sorted({max(top >> k, rows * tile) for k in range(rungs)}))
 
 
 def init_paged_pool(config: TransformerConfig, num_blocks: int,
                     block_size: int) -> Dict:
-    """Shared block pool: per layer ``k``/``v`` of shape
-    ``(num_blocks, kv_heads, block_size, head_dim)``. Block 0 is the
+    """Shared block pool: per layer one array per cache leaf
+    (``config.cache_leaves()``) of shape ``(num_blocks, heads,
+    block_size, width)`` -- ``k``/``v`` with ``(kv_heads, head_dim)``, or
+    the one ``latent`` leaf of ``attention_kind="mla"``. Block 0 is the
     reserved scratch sink (allocators must hand out ids >= 1)."""
     validate_paged_config(config)
     c = config
-    shape = (num_blocks, c.kv_heads, block_size, c.head_dim)
-    return {f"layer_{i}": {"k": jnp.zeros(shape, c.dtype),
-                           "v": jnp.zeros(shape, c.dtype)}
-            for i in range(c.num_layers)}
+    return {f"layer_{i}": {
+        leaf: jnp.zeros((num_blocks, heads, block_size, width), c.dtype)
+        for leaf, (heads, width) in c.cache_leaves().items()}
+        for i in range(c.num_layers)}
+
+
+def _block_size(pool: Dict) -> int:
+    return jax.tree_util.tree_leaves(pool)[0].shape[2]
 
 
 def install_row_paged(pool: Dict, row_cache: Dict, block_ids,
@@ -107,31 +170,24 @@ def install_row_paged(pool: Dict, row_cache: Dict, block_ids,
 
 
 def _install(pool, row_cache, block_ids, nblocks: int, start: int = 0):
-    out = {}
     n_write = nblocks - start
-    for name, lc in pool.items():
-        bs = lc["k"].shape[2]
+    bs = _block_size(pool)
+    ids = block_ids[start:nblocks]
 
-        def to_blocks(row):                      # (H, L, D) -> blocks
-            h, length, d = row.shape
-            take = min(nblocks * bs, length)
-            chunk = row[:, start * bs:take]
-            if take < nblocks * bs:
-                # max_len need not divide block_size: the final block's
-                # tail holds zero padding that no position ever reads
-                # (every valid position is < max_len)
-                chunk = jnp.pad(chunk,
-                                ((0, 0), (0, nblocks * bs - take),
-                                 (0, 0)))
-            return chunk.reshape(h, n_write, bs, d)
+    def to_blocks(big, row):                     # (1, H, L, D) -> blocks
+        h, length, d = row.shape[1:]
+        take = min(nblocks * bs, length)
+        chunk = row[0, :, start * bs:take]
+        if take < nblocks * bs:
+            # max_len need not divide block_size: the final block's
+            # tail holds zero padding that no position ever reads
+            # (every valid position is < max_len)
+            chunk = jnp.pad(chunk, ((0, 0), (0, nblocks * bs - take),
+                                    (0, 0)))
+        return big.at[ids].set(jnp.swapaxes(
+            chunk.reshape(h, n_write, bs, d), 0, 1))
 
-        chunk_k = to_blocks(row_cache[name]["k"][0])
-        chunk_v = to_blocks(row_cache[name]["v"][0])
-        ids = block_ids[start:nblocks]
-        out[name] = {
-            "k": lc["k"].at[ids].set(jnp.swapaxes(chunk_k, 0, 1)),
-            "v": lc["v"].at[ids].set(jnp.swapaxes(chunk_v, 0, 1))}
-    return out
+    return jax.tree_util.tree_map(to_blocks, pool, row_cache)
 
 
 _install_jit = jax.jit(_install, static_argnums=(3, 4),
@@ -153,20 +209,17 @@ def gather_blocks_to_row(pool: Dict, block_ids, max_len: int) -> Dict:
 
 @partial(jax.jit, static_argnums=(2,))
 def _gather_jit(pool, block_ids, max_len: int):
-    out = {}
     n = block_ids.shape[0]
-    for name, lc in pool.items():
-        bs = lc["k"].shape[2]
+    bs = _block_size(pool)
 
-        def to_row(p):                          # blocks -> (1, H, L, D)
-            sel = p[block_ids]                  # (n, H, bs, D)
-            h, d = sel.shape[1], sel.shape[3]
-            flat = jnp.swapaxes(sel, 0, 1).reshape(h, n * bs, d)
-            return jnp.pad(flat, ((0, 0), (0, max_len - n * bs),
-                                  (0, 0)))[None]
+    def to_row(p):                              # blocks -> (1, H, L, D)
+        sel = p[block_ids]                      # (n, H, bs, D)
+        h, d = sel.shape[1], sel.shape[3]
+        flat = jnp.swapaxes(sel, 0, 1).reshape(h, n * bs, d)
+        return jnp.pad(flat, ((0, 0), (0, max_len - n * bs),
+                              (0, 0)))[None]
 
-        out[name] = {"k": to_row(lc["k"]), "v": to_row(lc["v"])}
-    return out
+    return jax.tree_util.tree_map(to_row, pool)
 
 
 # --------------------------------------------------------------------------
@@ -190,12 +243,13 @@ def _layer_names(row_cache: Dict) -> List[str]:
 def export_kv_blocks(row_cache: Dict, length: int,
                      block_size: int) -> List[np.ndarray]:
     """Extract a batch-1 row cache's first ``length`` positions as
-    block-unit host arrays: a flat ``[k_0, v_0, k_1, v_1, ...]`` list
-    (layer index order) of shape ``(nblocks, kv_heads, block_size,
-    head_dim)`` each, ``nblocks = ceil(length / block_size)``. The final
-    block's tail is zero padding (no position past ``length`` is ever
-    read after install — the same contract as
-    :func:`install_row_paged`'s padding)."""
+    block-unit host arrays: a flat list, layer after layer and within a
+    layer leaf after leaf in sorted leaf order (``[k_0, v_0, k_1, v_1,
+    ...]``; ``[latent_0, latent_1, ...]`` for ``mla``), of shape
+    ``(nblocks, heads, block_size, width)`` each, ``nblocks =
+    ceil(length / block_size)``. The final block's tail is zero padding
+    (no position past ``length`` is ever read after install — the same
+    contract as :func:`install_row_paged`'s padding)."""
     length = int(length)
     bs = int(block_size)
     if length < 1 or bs < 1:
@@ -204,7 +258,7 @@ def export_kv_blocks(row_cache: Dict, length: int,
     out: List[np.ndarray] = []
     for name in _layer_names(row_cache):
         lc = row_cache[name]
-        for part in ("k", "v"):
+        for part in sorted(lc):
             row = np.asarray(lc[part])[0]          # (H, L, D)
             h, cached, d = row.shape
             if cached < length:
@@ -218,24 +272,27 @@ def export_kv_blocks(row_cache: Dict, length: int,
 
 
 def import_kv_blocks(arrays: Sequence[np.ndarray], length: int,
-                     max_len: int) -> Dict:
+                     max_len: int,
+                     leaves: Sequence[str] = ("k", "v")) -> Dict:
     """Reassemble :func:`export_kv_blocks` output into a contiguous
-    batch-1 row cache dict (``{"layer_i": {"k", "v"}}``, each ``(1,
-    kv_heads, max_len, head_dim)``) padded with zeros past ``length`` —
-    ready for the decode engine's slot install (contiguous
-    ``_install_fn`` or :func:`install_row_paged`)."""
-    if not arrays or len(arrays) % 2:
-        raise ValueError("KV block export must hold (k, v) pairs per "
+    batch-1 row cache dict (``{"layer_i": {leaf: (1, heads, max_len,
+    width)}}``) padded with zeros past ``length`` — ready for the decode
+    engine's slot install (contiguous ``_install_fn`` or
+    :func:`install_row_paged`). ``leaves`` names the cache's leaves
+    (``sorted(config.cache_leaves())``; the default is the ``mha``
+    cache's)."""
+    leaves = sorted(leaves)
+    if not arrays or len(arrays) % len(leaves):
+        raise ValueError(f"KV block export must hold {tuple(leaves)} per "
                          f"layer, got {len(arrays)} arrays")
     length, max_len = int(length), int(max_len)
     if length > max_len:
         raise ValueError(f"length {length} exceeds max_len {max_len}")
     row: Dict = {}
-    for i, (k_blocks, v_blocks) in enumerate(zip(arrays[0::2],
-                                                 arrays[1::2])):
+    for i in range(len(arrays) // len(leaves)):
         parts = {}
-        for part, blocks in (("k", k_blocks), ("v", v_blocks)):
-            blocks = np.asarray(blocks)
+        for j, part in enumerate(leaves):
+            blocks = np.asarray(arrays[i * len(leaves) + j])
             if blocks.ndim != 4:
                 raise ValueError("KV block tensors must be (nblocks, "
                                  f"heads, block_size, head_dim), got "
@@ -253,24 +310,24 @@ def import_kv_blocks(arrays: Sequence[np.ndarray], length: int,
 
 
 def export_pool_blocks(pool: Dict, block_ids: Sequence[int]) -> List[Dict]:
-    """Read pool blocks out to host payload dicts: one ``{layer: (k,
-    v)}`` dict per id (each array ``(kv_heads, block_size, head_dim)``
-    — the block cache's host payload format). One device->host gather
-    per layer tensor regardless of block count. The KV spill tier's
-    demotion read (:mod:`elephas_tpu.kvtier`) and the session store's
-    persistence read."""
+    """Read pool blocks out to host payload dicts: one ``{layer: tuple of
+    the layer's leaves in sorted leaf order}`` dict per id (``(k, v)``
+    for the ``mha`` pool, ``(latent,)`` for ``mla``; each array
+    ``(heads, block_size, width)`` — the block cache's host payload
+    format). One device->host gather per layer tensor regardless of
+    block count. The KV spill tier's demotion read
+    (:mod:`elephas_tpu.kvtier`) and the session store's persistence
+    read."""
     ids = [int(b) for b in block_ids]
     if not ids:
         return []
     idx = jnp.asarray(ids)
-    per_layer = {name: (np.asarray(lc["k"][idx]), np.asarray(lc["v"][idx]))
+    per_layer = {name: tuple(np.asarray(lc[leaf][idx])
+                             for leaf in sorted(lc))
                  for name, lc in pool.items()}
-    out: List[Dict] = []
-    for i in range(len(ids)):
-        out.append({name: (np.ascontiguousarray(ks[i]),
-                           np.ascontiguousarray(vs[i]))
-                    for name, (ks, vs) in per_layer.items()})
-    return out
+    return [{name: tuple(np.ascontiguousarray(a[i]) for a in parts)
+             for name, parts in per_layer.items()}
+            for i in range(len(ids))]
 
 
 def install_pool_blocks(pool: Dict, payloads: Sequence[Dict],
@@ -285,23 +342,19 @@ def install_pool_blocks(pool: Dict, payloads: Sequence[Dict],
                          f"{len(block_ids)} block ids")
     if not payloads:
         return pool
-    stacked = {}
-    for name, lc in pool.items():
-        dt = lc["k"].dtype
-        stacked[name] = {
-            "k": jnp.asarray(np.stack([np.asarray(p[name][0], np.float32)
-                                       for p in payloads]), dt),
-            "v": jnp.asarray(np.stack([np.asarray(p[name][1], np.float32)
-                                       for p in payloads]), dt)}
+    stacked = {name: {
+        leaf: jnp.asarray(np.stack([np.asarray(p[name][j], np.float32)
+                                    for p in payloads]), lc[leaf].dtype)
+        for j, leaf in enumerate(sorted(lc))}
+        for name, lc in pool.items()}
     return _install_blocks_jit(pool, stacked,
                                jnp.asarray([int(b) for b in block_ids]))
 
 
 @partial(jax.jit, donate_argnums=(0,))
 def _install_blocks_jit(pool, blocks, block_ids):
-    return {name: {"k": lc["k"].at[block_ids].set(blocks[name]["k"]),
-                   "v": lc["v"].at[block_ids].set(blocks[name]["v"])}
-            for name, lc in pool.items()}
+    return jax.tree_util.tree_map(
+        lambda big, new: big.at[block_ids].set(new), pool, blocks)
 
 
 def _held_range(pos, block_size: int, max_blocks: int, window, xp):
@@ -318,12 +371,25 @@ def _held_range(pos, block_size: int, max_blocks: int, window, xp):
 
 
 def held_block_count(pos, block_size: int, max_blocks: int,
-                     window=None) -> int:
+                     window=None, tile: int = 1) -> int:
     """Blocks the rows at host positions ``pos`` hold in all: the least
     ``held_blocks`` :func:`decode_step_paged` may be given for them. An
-    inactive row (``pos`` 0) holds its one scratch block."""
-    return int(_held_range(np.asarray(pos), block_size, max_blocks,
-                           window, np)[1].sum())
+    inactive row (``pos`` 0) holds its one scratch block. With ``tile``
+    (:func:`held_tile`) every row's count is rounded up to whole tiles,
+    which is what the latent pool's step lays out."""
+    count = _held_range(np.asarray(pos), block_size, max_blocks,
+                        window, np)[1]
+    return int((-(-count // tile) * tile).sum())
+
+
+def _ladder(held_blocks, whole: int) -> List[int]:
+    """``decode_step_paged``'s ``held_blocks`` as ascending widths;
+    ``whole`` (every table entry of every row) when it is None."""
+    if held_blocks is None:
+        return [whole]
+    if isinstance(held_blocks, (tuple, list)):
+        return sorted(set(held_blocks))
+    return [int(held_blocks)]
 
 
 def _held_slots(tables, pos, bs: int, window, width: int, alibi: bool):
@@ -385,13 +451,64 @@ def _held_attention(q, pk, pv, slots, slopes, scale):
     return (o.astype(jnp.float32) / denom[..., None]).astype(vb.dtype)
 
 
+def _held_tiles(tables, pos, bs: int, tile: int, width: int):
+    """The latent pool's flat list: ``width`` tiles of ``tile`` blocks,
+    each owned by ONE row (a row at ``pos`` holds ``ceil((pos // bs + 1)
+    / tile)`` of them, row after row, live tiles first). Returns the
+    number of live tiles and the tile-major arrays ``row`` ``(T,)``,
+    ``owns`` ``(T, B)`` (all false on padding tiles), ``blk`` ``(T,
+    tile)`` (pool blocks; entries past what the row holds read scratch
+    block 0) and ``mask`` ``(T, tile * bs)`` (positions the owner
+    attends to)."""
+    b, mb = tables.shape
+    _, count = _held_range(pos, bs, mb, None, jnp)
+    tiles = -(-count // tile)
+    ends = jnp.cumsum(tiles)
+    slot = jnp.arange(width)
+    row = jnp.minimum(jnp.searchsorted(ends, slot, side="right",
+                                       method="compare_all"), b - 1)
+    live = slot < ends[-1]
+    j = ((slot - (ends - tiles)[row]) * tile)[:, None] + jnp.arange(tile)
+    held = live[:, None] & (j < count[row][:, None])
+    blk = jnp.where(held, tables[row[:, None], jnp.minimum(j, mb - 1)], 0)
+    kpos = (j[:, :, None] * bs + jnp.arange(bs)).reshape(width, tile * bs)
+    mask = live[:, None] & (kpos <= pos[row][:, None])
+    owns = (row[:, None] == jnp.arange(b)[None, :]) & live[:, None]
+    return ends[-1], (row, owns, blk, mask)
+
+
+def _latent_attention(q, pool, tiles, scale, rank: int):
+    """The absorbed attention of each row's folded query ``q`` ``(B, H,
+    latent_width)`` over the held tiles (:func:`_held_tiles`) of the
+    latent pool ``(blocks, 1, bs, latent_width)``: one gather of the
+    tiles' blocks, scores of all heads against every gathered latent,
+    a softmax normalised per row across its tiles through ``owns``, and
+    the weighted sum of the latents' first ``rank`` values. Scores and
+    sums are float32. Returns ``(B, H, rank)``."""
+    row, owns, blk, mask = tiles
+    t = blk.shape[0]
+    lat = pool[blk][:, :, 0].reshape(t, -1, pool.shape[-1])   # (T, P, W)
+    s = jnp.einsum("thw,tpw->thp", q[row], lat,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(mask[:, None, :], s, NEG_INF)
+    own = owns[:, :, None]                         # (T, B, 1)
+    top = jnp.max(jnp.where(own, s.max(-1)[:, None], NEG_INF), axis=0)
+    p = jnp.exp(s - top[row][..., None])           # masked -> exactly 0
+    denom = jnp.sum(jnp.where(own, p.sum(-1)[:, None], 0.0), axis=0)
+    u = jnp.einsum("thp,tpr->thr", p.astype(lat.dtype), lat[..., :rank],
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("tb,thr->bhr", owns.astype(lat.dtype),
+                   u.astype(lat.dtype))
+    return (u.astype(jnp.float32) / denom[..., None]).astype(lat.dtype)
+
+
 def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
                       tokens: jnp.ndarray, pos,
                       config: TransformerConfig,
                       kernel: str = "gather",
                       interpret=None,
-                      held_blocks: Union[None, int, Sequence[int]] = None
-                      ) -> Tuple[jnp.ndarray, Dict]:
+                      held_blocks: Union[None, int, Sequence[int]] = None,
+                      with_stats: bool = False):
     """One autoregressive step over the block pool: token ids ``(B,)``
     at per-row positions ``pos`` ``(B,)``; ``tables`` is ``(B,
     max_blocks)`` of block ids. Returns (logits ``(B, vocab)``, updated
@@ -417,13 +534,31 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
     and ignores ``held_blocks``). The two agree to float rounding,
     pinned by the variant-matrix parity tests. ``interpret`` is
     threaded to the Pallas kernel (tests force the interpreter off-TPU;
-    production callers leave it ``None``)."""
+    production callers leave it ``None``).
+
+    ``attention_kind="mla"`` runs the absorbed step over the latent pool
+    (module docstring) with the same ladder; its widths count blocks and
+    must be multiples of :func:`held_tile`, and the rows' need is
+    :func:`held_block_count` with that ``tile``. ``kernel`` must be
+    ``"gather"`` there.
+
+    With ``with_stats`` a third value is returned, None for a model
+    without swiglu expert layers, else ``{"counts", "picks"}``: the
+    int32 routing counts summed over those layers
+    (:data:`~elephas_tpu.models.grouped_experts.STATS`: picks made by
+    live rows -- those at ``pos`` > 0, which an engine's idle slots are
+    not --, picks on held experts, held experts touched, expert layers
+    run) and every such layer's picks ``(layers, B, top_k)``."""
     if kernel not in ("gather", "pallas"):
         raise ValueError(f"unknown paged decode kernel {kernel!r}; "
                          "expected 'gather' or 'pallas'")
     c = config
     b = tokens.shape[0]
-    bs = next(iter(pool.values()))["k"].shape[2]
+    bs = _block_size(pool)
+    mla = c.attention_kind == "mla"
+    if mla and kernel != "gather":
+        raise ValueError("attention_kind='mla' has no Pallas paged "
+                         "kernel; use kernel='gather'")
     pos = jnp.asarray(pos)
     blk = jnp.take_along_axis(tables, (pos // bs)[:, None],
                               axis=1)[:, 0]        # (B,) owning block
@@ -440,11 +575,28 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
     rp = pos[:, None, None]                        # (B, 1, 1) rope angles
     groups = c.num_heads // c.kv_heads
     hidx = jnp.arange(c.kv_heads)
-    if kernel == "gather":
-        if held_blocks is None:
-            held_blocks = b * tables.shape[1]
-        widths = (sorted(set(held_blocks)) if isinstance(
-            held_blocks, (tuple, list)) else [int(held_blocks)])
+    if mla:
+        tile = LATENT_TILE_BLOCKS
+        widths = _ladder(held_blocks,
+                         b * (-(-tables.shape[1] // tile) * tile))
+        if any(w % tile for w in widths):
+            raise ValueError(f"held_blocks {widths} must be multiples of "
+                             f"the latent tile, {tile} blocks")
+        held, tiles = _held_tiles(tables, pos, bs, tile,
+                                  widths[-1] // tile)
+        sigma = _mla.softmax_scale(c)
+
+        def attend_over(width):
+            cut = jax.tree_util.tree_map(lambda a: a[:width // tile],
+                                         tiles)
+            return lambda q, lat: _latent_attention(q, lat, cut, sigma,
+                                                    c.kv_lora_rank)
+
+        branches = [attend_over(w) for w in widths]
+        pick = jnp.searchsorted(jnp.asarray(widths), held * tile,
+                                side="left")
+    elif kernel == "gather":
+        widths = _ladder(held_blocks, b * tables.shape[1])
         alibi = c.positional == "alibi"
         held, slots = _held_slots(tables, pos, bs, c.attention_window,
                                   widths[-1], alibi)
@@ -462,9 +614,27 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
         # device from what the step is given anyway
         pick = jnp.searchsorted(jnp.asarray(widths), held, side="left")
     new_pool: Dict = {}
+    live = pos > 0
+    stats = []
     for i in range(c.num_layers):
         layer = params[f"layer_{i}"]
         h = _norm(x, layer["ln1"], c).astype(c.dtype)
+        if mla:
+            attn = layer["attn"]
+            q_nope, q_rope = _mla.project_query(attn, h, pos[:, None], c)
+            new = _mla.project_latent(attn, h, pos[:, None], c)
+            # this position's latent into each row's owning block
+            lat = pool[f"layer_{i}"]["latent"].at[blk, 0, off].set(
+                new[:, 0])
+            new_pool[f"layer_{i}"] = {"latent": lat}
+            q_lat = _mla.absorb_query(attn, q_nope, q_rope, c)
+            with jax.named_scope("elephas.mla.attend"):
+                u = jax.lax.switch(pick, branches, q_lat[:, 0], lat)
+            x = x + _mla.unabsorb_output(attn, u[:, None], c)
+            x, st = _mlp_sublayer(layer, x, c, i, live=live[:, None])
+            if st is not None:
+                stats.append(st)
+            continue
         q = jnp.einsum("bsd,dhk->bhsk", h,
                        layer["attn"]["wq"].astype(c.dtype))
         k_new = jnp.einsum("bsd,dhk->bhsk", h,
@@ -500,9 +670,16 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
                 b, c.num_heads, 1, c.head_dim)
         x = x + jnp.einsum("bhsk,hkd->bsd", o,
                            layer["attn"]["wo"].astype(c.dtype))
-        x = _mlp_apply(layer, x, c)
+        x, st = _mlp_sublayer(layer, x, c, i, live=live[:, None])
+        if st is not None:
+            stats.append(st)
     logits = head_logits(params["embed"], params["final_ln"], x[:, 0],
-                         head=params.get("head"), norm=c.norm)
+                         head=params.get("head"), norm=c.norm,
+                         rms_norm_eps=c.rms_norm_eps)
+    if with_stats:
+        return logits, new_pool, None if not stats else {
+            "counts": sum(st["counts"] for st in stats),
+            "picks": jnp.stack([st["picks"][:, 0] for st in stats])}
     return logits, new_pool
 
 
@@ -527,9 +704,10 @@ def decode_block_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
     (stale) tail positions are masked until later rounds overwrite them
     and can never corrupt another row's blocks."""
     c = config
+    require_per_head_cache(c, "decode_block_paged (the speculative "
+                              "verify pass)")
     b, s = tokens.shape
-    first = next(iter(pool.values()))["k"]
-    bs = first.shape[2]
+    bs = _block_size(pool)
     mb = tables.shape[1]
     length = mb * bs                               # gathered view length
     pos0 = jnp.asarray(pos0)
@@ -599,7 +777,8 @@ def decode_block_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
         o = o.reshape(b, c.num_heads, s, c.head_dim)
         x = x + jnp.einsum("bhsk,hkd->bsd", o,
                            layer["attn"]["wo"].astype(c.dtype))
-        x = _mlp_apply(layer, x, c)
+        x, _ = _mlp_sublayer(layer, x, c, i)
     logits = head_logits(params["embed"], params["final_ln"], x,
-                         head=params.get("head"), norm=c.norm)
+                         head=params.get("head"), norm=c.norm,
+                         rms_norm_eps=c.rms_norm_eps)
     return logits, new_pool

@@ -26,6 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from . import grouped_experts as _gx
+from . import mla as _mla
+from .mla import YarnScaling  # noqa: F401  (part of the config's surface)
 from ..ops.attention import NEG_INF, attention
 from ..ops.pallas_attention import flash_attention, flash_attention_sharded
 from ..ops.ring_attention import ring_attention_sharded
@@ -153,6 +156,48 @@ class TransformerConfig:
     #: cache shrink by that factor while attention quality stays close to
     #: full MHA (GQA, Ainslie et al. 2023)
     num_kv_heads: Optional[int] = None
+    #: RMSNorm's epsilon (``norm='rmsnorm'``; LayerNorm keeps 1e-5)
+    rms_norm_eps: float = 1e-5
+    #: attention kind of every layer: ``mha`` (multi-head / grouped-query
+    #: with per-head k/v in the cache) or ``mla`` (multi-head latent
+    #: attention, :mod:`~elephas_tpu.models.mla`: low-rank query and
+    #: key/value projections, and a cache of ONE latent vector of
+    #: ``kv_lora_rank + qk_rope_head_dim`` values a position). ``mla``
+    #: takes its sizes from the five fields below, rotates only the rope
+    #: dims (``rope_theta``, ``rope_scaling``) and ignores ``positional``
+    #: (set it to ``rope``), ``num_kv_heads`` and ``attention_window``
+    attention_kind: str = "mha"
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    #: YaRN scaling of the ``mla`` rope dims (``YarnScaling``); None is
+    #: plain RoPE
+    rope_scaling: Optional[_mla.YarnScaling] = None
+    #: MLP kind of each layer, ``"dense"`` or ``"experts"``, one entry a
+    #: layer (leading dense layers, then expert layers). None: every
+    #: layer has experts when ``num_experts`` > 1, else is dense
+    mlp_kinds: Optional[Tuple[str, ...]] = None
+    #: expert kind: ``gelu`` (ungated, biased, dense or capacity-routed
+    #: dispatch per ``moe_dispatch``, optional gelu shared expert) or
+    #: ``swiglu`` (:mod:`~elephas_tpu.models.grouped_experts`: gated, no
+    #: bias, width ``expert_d_ff``, grouped matmuls over sorted tokens,
+    #: no token ever dropped, shared SwiGLU of width ``shared_d_ff``)
+    expert_variant: str = "gelu"
+    expert_d_ff: Optional[int] = None
+    shared_d_ff: int = 0
+    #: ``swiglu`` experts route ``group_limited_greedy``: the
+    #: ``expert_top_k`` best among the ``moe_topk_groups`` best of
+    #: ``moe_n_groups`` groups, scaled by ``routed_scaling_factor``, not
+    #: renormalised (the defaults, one group, are plain top-k)
+    moe_n_groups: int = 1
+    moe_topk_groups: int = 1
+    routed_scaling_factor: float = 1.0
+    #: the experts this rank holds, ``(first, count)`` out of the
+    #: router's ``num_experts`` (``swiglu`` experts only; None: all). The
+    #: layer routes over every expert and computes its own experts' part
+    held_experts: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         if self.attention_impl not in ("auto", "flash", "xla"):
@@ -194,10 +239,89 @@ class TransformerConfig:
             raise ValueError(
                 f"num_kv_heads ({self.num_kv_heads}) must divide "
                 f"num_heads ({self.num_heads})")
+        if self.attention_kind not in ("mha", "mla"):
+            raise ValueError("attention_kind must be 'mha' or 'mla', got "
+                             f"{self.attention_kind!r}")
+        if self.attention_kind == "mla":
+            sizes = (self.q_lora_rank, self.kv_lora_rank,
+                     self.qk_nope_head_dim, self.qk_rope_head_dim,
+                     self.v_head_dim)
+            if any(v is None or v < 1 for v in sizes):
+                raise ValueError(
+                    "attention_kind='mla' needs q_lora_rank, kv_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+            if self.qk_rope_head_dim % 2:
+                raise ValueError("qk_rope_head_dim must be even")
+            if self.kv_cache_quant or self.attention_window is not None:
+                raise ValueError("attention_kind='mla' composes with "
+                                 "neither kv_cache_quant nor "
+                                 "attention_window")
+        if self.mlp_kinds is not None and (
+                len(self.mlp_kinds) != self.num_layers
+                or any(k not in ("dense", "experts")
+                       for k in self.mlp_kinds)
+                or ("experts" in self.mlp_kinds
+                    and self.num_experts < 2)):
+            raise ValueError(
+                "mlp_kinds needs one of 'dense' / 'experts' per layer "
+                f"(and num_experts > 1 for 'experts'), got "
+                f"{self.mlp_kinds!r}")
+        if self.expert_variant not in ("gelu", "swiglu"):
+            raise ValueError("expert_variant must be 'gelu' or 'swiglu', "
+                             f"got {self.expert_variant!r}")
+        swiglu = self.expert_variant == "swiglu"
+        if swiglu and self.moe_shared_expert:
+            raise ValueError("moe_shared_expert is the gelu experts' "
+                             "shared expert; swiglu experts take "
+                             "shared_d_ff")
+        if not swiglu and (self.held_experts is not None
+                           or self.shared_d_ff
+                           or (self.moe_n_groups, self.moe_topk_groups,
+                               self.routed_scaling_factor) != (1, 1, 1.0)):
+            raise ValueError("held_experts, shared_d_ff, moe_n_groups, "
+                             "moe_topk_groups and routed_scaling_factor "
+                             "belong to expert_variant='swiglu'")
+        if swiglu and self.num_experts > 1:
+            if not self.expert_d_ff or self.expert_d_ff < 1:
+                raise ValueError("swiglu experts need expert_d_ff")
+            if (self.moe_n_groups < 1
+                    or self.num_experts % self.moe_n_groups
+                    or not 1 <= self.moe_topk_groups <= self.moe_n_groups
+                    or self.moe_topk_groups * self.num_experts
+                    // self.moe_n_groups < self.expert_top_k):
+                raise ValueError(
+                    "group-limited routing needs moe_n_groups dividing "
+                    "num_experts and moe_topk_groups groups that hold at "
+                    "least expert_top_k experts")
+            if self.held_experts is not None:
+                first, count = self.held_experts
+                if not (0 <= first and count >= 1
+                        and first + count <= self.num_experts):
+                    raise ValueError(
+                        f"held_experts {self.held_experts!r} is no range "
+                        f"of the {self.num_experts} experts")
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.num_heads
+
+    def has_experts(self, layer: int) -> bool:
+        """Whether layer ``layer``'s MLP is an expert layer."""
+        if self.mlp_kinds is not None:
+            return self.mlp_kinds[layer] == "experts"
+        return self.num_experts > 1
+
+    def cache_leaves(self) -> Dict[str, Tuple[int, int]]:
+        """What one layer caches per position, ``{leaf: (heads,
+        width)}``: the one place that says how the decode cache is laid
+        out. Every cache leaf is ``(batch | blocks, heads, positions,
+        width)``; :func:`init_kv_cache` and the paged pool are built
+        from this and everything that moves cache blocks maps over the
+        leaves."""
+        if self.attention_kind == "mla":
+            return {"latent": (1, _mla.latent_width(self))}
+        return {"k": (self.kv_heads, self.head_dim),
+                "v": (self.kv_heads, self.head_dim)}
 
     @property
     def kv_heads(self) -> int:
@@ -232,19 +356,26 @@ def init_params(config: TransformerConfig, key) -> Dict:
                                (c.d_model, c.vocab_size), c.d_model)
     for i in range(c.num_layers):
         lk = jax.random.split(keys[2 + i], 7)
-        layer = {
-            "ln1": {"gamma": jnp.ones((c.d_model,), c.param_dtype),
-                    "beta": jnp.zeros((c.d_model,), c.param_dtype)},
-            "attn": {
+        if c.attention_kind == "mla":
+            attn = _mla.init_attn(c, jax.random.split(lk[0], 5), dense)
+        else:
+            attn = {
                 "wq": dense(lk[0], (c.d_model, c.num_heads, c.head_dim), c.d_model),
                 "wk": dense(lk[1], (c.d_model, c.kv_heads, c.head_dim), c.d_model),
                 "wv": dense(lk[2], (c.d_model, c.kv_heads, c.head_dim), c.d_model),
                 "wo": dense(lk[3], (c.num_heads, c.head_dim, c.d_model), c.d_model),
-            },
+            }
+        layer = {
+            "ln1": {"gamma": jnp.ones((c.d_model,), c.param_dtype),
+                    "beta": jnp.zeros((c.d_model,), c.param_dtype)},
+            "attn": attn,
             "ln2": {"gamma": jnp.ones((c.d_model,), c.param_dtype),
                     "beta": jnp.zeros((c.d_model,), c.param_dtype)},
         }
-        if c.num_experts > 1:
+        if c.has_experts(i) and c.expert_variant == "swiglu":
+            layer["moe"] = _gx.init_experts(
+                c, jax.random.split(lk[4], 7), dense)
+        elif c.has_experts(i):
             layer["moe"] = {
                 "gate": dense(lk[6], (c.d_model, c.num_experts), c.d_model),
                 "w1": dense(lk[4], (c.num_experts, c.d_model, c.d_ff),
@@ -314,15 +445,20 @@ def param_specs(config: TransformerConfig, model_axis: str = "model",
     for i in range(config.num_layers):
         layer_specs = {
             "ln1": {"gamma": P(None), "beta": P(None)},
-            "attn": {
-                "wq": P(None, h_ax, None),
-                "wk": kv_spec,
-                "wv": kv_spec,
-                "wo": P(h_ax, None, None),
-            },
+            "attn": (_mla.attn_specs(P, h_ax)
+                     if config.attention_kind == "mla" else {
+                         "wq": P(None, h_ax, None),
+                         "wk": kv_spec,
+                         "wv": kv_spec,
+                         "wo": P(h_ax, None, None),
+                     }),
             "ln2": {"gamma": P(None), "beta": P(None)},
         }
-        if config.num_experts > 1:
+        if config.has_experts(i) and config.expert_variant == "swiglu":
+            held = _gx.held_range(config)[1]
+            layer_specs["moe"] = _gx.expert_specs(
+                config, P, model_axis if div(held) else None, ff_ax)
+        elif config.has_experts(i):
             # expert parallelism: the expert dimension shards over the
             # model axis, so each device holds and computes E/tp experts;
             # the gate is replicated and XLA all-reduces the weighted
@@ -465,7 +601,7 @@ def _rms_norm(x, gamma, eps=1e-5):
 def _norm(x, sub: Dict, c) -> jnp.ndarray:
     """Config-selected normalization (rmsnorm ignores beta)."""
     if getattr(c, "norm", "layernorm") == "rmsnorm":
-        return _rms_norm(x, sub["gamma"])
+        return _rms_norm(x, sub["gamma"], getattr(c, "rms_norm_eps", 1e-5))
     return _layer_norm(x, sub["gamma"], sub["beta"])
 
 
@@ -569,12 +705,13 @@ def embed_apply(embed: Dict, tokens: jnp.ndarray,
 
 def head_logits(embed: Dict, final_ln: Dict, x: jnp.ndarray,
                 head: Optional[jnp.ndarray] = None,
-                norm: str = "layernorm") -> jnp.ndarray:
+                norm: str = "layernorm",
+                rms_norm_eps: float = 1e-5) -> jnp.ndarray:
     """Final norm + LM head (tied to the embedding unless an untied
     ``head`` matrix is given); f32 logits for a stable softmax. Shared
     by the monolithic forward and the pipelined LM exit."""
     x = x.astype(jnp.float32)
-    x = (_rms_norm(x, final_ln["gamma"]) if norm == "rmsnorm"
+    x = (_rms_norm(x, final_ln["gamma"], rms_norm_eps) if norm == "rmsnorm"
          else _layer_norm(x, final_ln["gamma"], final_ln["beta"]))
     if head is not None:
         return x @ head.astype(jnp.float32)
@@ -613,7 +750,8 @@ def chunked_next_token_losses(x: jnp.ndarray, embed: Dict, final_ln: Dict,
                               tokens: jnp.ndarray, chunk: int,
                               head: Optional[jnp.ndarray] = None,
                               norm: str = "layernorm",
-                              weights: Optional[jnp.ndarray] = None
+                              weights: Optional[jnp.ndarray] = None,
+                              rms_norm_eps: float = 1e-5
                               ) -> Tuple[jnp.ndarray, jnp.ndarray,
                                          jnp.ndarray]:
     """Streamed LM loss pieces from the final hidden states: returns
@@ -626,7 +764,7 @@ def chunked_next_token_losses(x: jnp.ndarray, embed: Dict, final_ln: Dict,
     ``(B, T, chunk)``.
     """
     h = x.astype(jnp.float32)
-    h = (_rms_norm(h, final_ln["gamma"]) if norm == "rmsnorm"
+    h = (_rms_norm(h, final_ln["gamma"], rms_norm_eps) if norm == "rmsnorm"
          else _layer_norm(h, final_ln["gamma"], final_ln["beta"]))[:, :-1]
     targets = tokens[:, 1:]                                  # (B, T')
     emb = (head.T if head is not None
@@ -926,7 +1064,8 @@ def forward_with_aux(params: Dict, tokens: jnp.ndarray,
                                     dropout_key=dropout_key,
                                     segment_ids=segment_ids)
     return head_logits(params["embed"], params["final_ln"], x,
-                       head=params.get("head"), norm=config.norm), aux_total
+                       head=params.get("head"), norm=config.norm,
+                       rms_norm_eps=config.rms_norm_eps), aux_total
 
 
 def _hidden_with_aux(params: Dict, tokens: jnp.ndarray,
@@ -1007,16 +1146,32 @@ def _hidden_with_aux(params: Dict, tokens: jnp.ndarray,
     moe_ep = (moe_dispatch == "routed" and ep > 1 and seq_axis is None
               and _mesh_divides(mesh, model_axis, c.num_experts))
 
-    def layer_apply(layer, x, layer_key):
+    mla = c.attention_kind == "mla"
+    if mla and (mesh is not None and seq_axis is not None
+                or segment_ids is not None):
+        raise ValueError("attention_kind='mla' runs the plain causal "
+                         "path: no sequence axis, no segment_ids")
+
+    def layer_apply(layer, x, layer_key, experts):
         if layer_key is not None:
             attn_key, mlp_key = jax.random.split(layer_key)
         else:
             attn_key = mlp_key = None
-        x = _attn_apply(layer, x, c, attn_fn, dropout_key=attn_key)
-        if c.num_experts > 1:
+        if mla:
+            h = _norm(x, layer["ln1"], c).astype(c.dtype)
+            x = x + _dropout(_mla.attn_full(layer["attn"], h, c),
+                             c.dropout_rate, attn_key)
+        else:
+            x = _attn_apply(layer, x, c, attn_fn, dropout_key=attn_key)
+        if experts:
             h = _norm(x, layer["ln2"], c)
             h = h.astype(c.dtype)
-            if moe_ep:
+            if c.expert_variant == "swiglu":
+                # (the shared expert is part of the layer; no auxiliary
+                # loss: the router's balance is not trained here yet)
+                out, _ = _gx.experts_apply(h, layer["moe"], c)
+                aux = jnp.zeros((), jnp.float32)
+            elif moe_ep:
                 out, aux = _moe_block_routed_ep(h, layer["moe"], c, mesh,
                                                 batch_axis, model_axis)
             else:
@@ -1033,12 +1188,14 @@ def _hidden_with_aux(params: Dict, tokens: jnp.ndarray,
         # of keeping them live: activation memory stays O(1) in depth
         policy = (jax.checkpoint_policies.dots_saveable
                   if c.remat_policy == "dots" else None)
-        layer_apply = jax.checkpoint(layer_apply, policy=policy)
+        layer_apply = jax.checkpoint(layer_apply, policy=policy,
+                                     static_argnums=(3,))
 
     for i in range(c.num_layers):
         layer_key = (jax.random.fold_in(dropout_key, i)
                      if dropout_key is not None else None)
-        x, aux = layer_apply(params[f"layer_{i}"], x, layer_key)
+        x, aux = layer_apply(params[f"layer_{i}"], x, layer_key,
+                             c.has_experts(i))
         aux_total = aux_total + aux
 
     return x, aux_total
@@ -1068,7 +1225,8 @@ def lm_loss(params: Dict, tokens: jnp.ndarray, config: TransformerConfig,
                                   segment_ids=segment_ids)
         loss, lse, mean_logits = chunked_next_token_losses(
             x, params["embed"], params["final_ln"], tokens, int(chunk),
-            head=params.get("head"), norm=config.norm, weights=weights)
+            head=params.get("head"), norm=config.norm, weights=weights,
+            rms_norm_eps=config.rms_norm_eps)
         if config.label_smoothing:
             # mean_v logp_v = mean_v logits_v - lse
             eps = config.label_smoothing
@@ -1447,9 +1605,29 @@ def init_kv_cache(config: TransformerConfig, batch: int,
                                "v": jnp.zeros(shape, jnp.int8),
                                "v_scale": jnp.zeros(sshape, jnp.float32)}
                 for i in range(c.num_layers)}
-    return {f"layer_{i}": {"k": jnp.zeros(shape, c.dtype),
-                           "v": jnp.zeros(shape, c.dtype)}
-            for i in range(c.num_layers)}
+    return {f"layer_{i}": {
+        leaf: jnp.zeros((batch, heads, length, width), c.dtype)
+        for leaf, (heads, width) in c.cache_leaves().items()}
+        for i in range(c.num_layers)}
+
+
+def _mlp_sublayer(layer: Dict, x: jnp.ndarray, c: TransformerConfig,
+                  i: int, live=None):
+    """Layer ``i``'s MLP sublayer (with its residual) on the inference
+    paths, by the layer's kind. Returns ``(x, stats)``; ``stats`` is the
+    routing counts of :func:`grouped_experts.experts_apply` for a swiglu
+    expert layer, else None."""
+    if not c.has_experts(i):
+        return _mlp_apply(layer, x, c), None
+    h2 = _norm(x, layer["ln2"], c).astype(c.dtype)
+    if c.expert_variant == "swiglu":
+        out, stats = _gx.experts_apply(h2, layer["moe"], c, live=live)
+        return x + out, stats
+    # dense gating, matching decode_step's decode-time semantics
+    out, _ = _moe_block(h2, layer["moe"], c, dispatch="dense")
+    if c.moe_shared_expert:
+        out = out + _shared_expert(h2, layer["moe"]["shared"], c)
+    return x + out, None
 
 
 def _kv_quantize(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -1498,6 +1676,17 @@ def prefill_cache(params: Dict, tokens: jnp.ndarray,
         layer = params[f"layer_{i}"]
         h = _norm(x, layer["ln1"], c)
         h = h.astype(c.dtype)
+        if c.attention_kind == "mla":
+            q_nope, q_rope = _mla.project_query(layer["attn"], h,
+                                                positions, c)
+            latent = _mla.project_latent(layer["attn"], h, positions, c)
+            new_cache[f"layer_{i}"] = {
+                "latent": cache[f"layer_{i}"]["latent"].at[:, 0, :t].set(
+                    latent)}
+            x = x + _mla.attend_expanded(layer["attn"], q_nope, q_rope,
+                                         latent, mask[0], c)
+            x, _ = _mlp_sublayer(layer, x, c, i)
+            continue
         q = jnp.einsum("btd,dhk->bhtk", h,
                        layer["attn"]["wq"].astype(c.dtype))
         k = jnp.einsum("btd,dhk->bhtk", h,
@@ -1542,19 +1731,10 @@ def prefill_cache(params: Dict, tokens: jnp.ndarray,
         o = o.reshape(b, c.num_heads, t, c.head_dim)
         x = x + jnp.einsum("bhtk,hkd->btd", o,
                            layer["attn"]["wo"].astype(c.dtype))
-        if c.num_experts > 1:
-            h2 = _norm(x, layer["ln2"], c)
-            h2 = h2.astype(c.dtype)
-            # dense gating, matching decode_step's decode-time semantics
-            h2_out, _ = _moe_block(h2, layer["moe"], c, dispatch="dense")
-            if c.moe_shared_expert:
-                h2_out = h2_out + _shared_expert(h2, layer["moe"]["shared"],
-                                                 c)
-            x = x + h2_out
-        else:
-            x = _mlp_apply(layer, x, c)
+        x, _ = _mlp_sublayer(layer, x, c, i)
     logits = head_logits(params["embed"], params["final_ln"], x[:, -1],
-                         head=params.get("head"), norm=c.norm)
+                         head=params.get("head"), norm=c.norm,
+                         rms_norm_eps=c.rms_norm_eps)
     return logits, new_cache
 
 
@@ -1584,7 +1764,7 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
     b, s = tokens.shape
     pos0 = jnp.asarray(pos0)
     vec = pos0.ndim == 1
-    length = next(iter(cache.values()))["k"].shape[2]
+    length = jax.tree_util.tree_leaves(cache)[0].shape[2]
     blockpos = (pos0[:, None] + jnp.arange(s)[None, :] if vec
                 else pos0 + jnp.arange(s))             # (B, S) or (S,)
     x = params["embed"]["tokens"][tokens]
@@ -1613,6 +1793,21 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
         layer = params[f"layer_{i}"]
         h = _norm(x, layer["ln1"], c)
         h = h.astype(c.dtype)
+        if c.attention_kind == "mla":
+            q_nope, q_rope = _mla.project_query(layer["attn"], h,
+                                                blockpos, c)
+            new = _mla.project_latent(layer["attn"], h, blockpos, c)
+            buf = cache[f"layer_{i}"]["latent"]
+            if vec:
+                buf = buf.at[jnp.arange(b)[:, None], 0, blockpos].set(new)
+            else:
+                buf = jax.lax.dynamic_update_slice(
+                    buf, new[:, None].astype(buf.dtype), (0, 0, pos0, 0))
+            new_cache[f"layer_{i}"] = {"latent": buf}
+            x = x + _mla.attend_expanded(layer["attn"], q_nope, q_rope,
+                                         buf[:, 0], mask, c)
+            x, _ = _mlp_sublayer(layer, x, c, i)
+            continue
         q = jnp.einsum("bsd,dhk->bhsk", h,
                        layer["attn"]["wq"].astype(c.dtype))
         k_new = jnp.einsum("bsd,dhk->bhsk", h,
@@ -1658,18 +1853,10 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
         o = o.reshape(b, c.num_heads, s, c.head_dim)
         x = x + jnp.einsum("bhsk,hkd->bsd", o,
                            layer["attn"]["wo"].astype(c.dtype))
-        if c.num_experts > 1:
-            h2 = _norm(x, layer["ln2"], c)
-            h2 = h2.astype(c.dtype)
-            h2_out, _ = _moe_block(h2, layer["moe"], c, dispatch="dense")
-            if c.moe_shared_expert:
-                h2_out = h2_out + _shared_expert(h2, layer["moe"]["shared"],
-                                                 c)
-            x = x + h2_out
-        else:
-            x = _mlp_apply(layer, x, c)
+        x, _ = _mlp_sublayer(layer, x, c, i)
     logits = head_logits(params["embed"], params["final_ln"], x,
-                         head=params.get("head"), norm=c.norm)
+                         head=params.get("head"), norm=c.norm,
+                         rms_norm_eps=c.rms_norm_eps)
     return logits, new_cache
 
 

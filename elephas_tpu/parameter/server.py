@@ -1047,9 +1047,11 @@ class SocketServer(BaseParameterServer):
                                           "rejected", t0,
                                           bytes_in=nbytes_in)
                             continue
-                        conn.sendall(b"k")  # ack: delta applied
+                        # counted before the ack: a client that reads the
+                        # counter right after its push returns sees it
                         self._obs_rpc("socket", "apply_delta", "ok", t0,
                                       bytes_in=nbytes_in)
+                        conn.sendall(b"k")  # ack: delta applied
                     elif opcode == b"g":
                         # cached encoded snapshot: repeated gets cost one
                         # sendall of the same immutable payload — no

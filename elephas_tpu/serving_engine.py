@@ -469,6 +469,11 @@ class DecodeEngine:
 
             num_blocks, block_size = int(paged[0]), int(paged[1])
             validate_paged_config(config)
+            if draft_config is not None:
+                from .models.paged_decode import require_per_head_cache
+
+                require_per_head_cache(config, "paged speculative "
+                                               "decoding")
             if block_size < 1 or num_blocks < 2:
                 raise ValueError("paged needs block_size >= 1 and "
                                  "num_blocks >= 2 (block 0 is the "
@@ -798,14 +803,15 @@ class DecodeEngine:
         self._accept: Dict[int, List[int]] = {}
         if self.paged is not None:
             # widths of the decode step's flat block list, derived from
-            # the shapes: doubling up to max_slots x table width (enough
-            # for any rows), at most 6, none narrower than the batch
-            # (every row holds at least its scratch block). The Pallas
-            # kernel walks whole tables: one width
-            top = self.max_slots * self._mb
-            self._held_ladder = tuple(sorted(
-                {max(top >> k, self.max_slots) for k in range(
-                    1 if self.kernel == "pallas" else 6)}))
+            # the shapes (at most 6). The Pallas kernel walks whole
+            # tables: one width
+            from .models.paged_decode import held_ladder, held_tile
+
+            # (a latent pool's rows take whole tiles of the list)
+            self._held_tile = held_tile(config)
+            self._held_ladder = held_ladder(
+                config, self.max_slots, self._mb,
+                rungs=1 if self.kernel == "pallas" else 6)
             self._m_blocks_held = reg.counter(
                 "serving_decode_blocks_held_total",
                 "KV blocks the batch's rows held, summed over decode "
@@ -822,6 +828,22 @@ class DecodeEngine:
                 "list", labels=("width",))
             self._m_steps_by_width = {
                 w: fam.labels(width=str(w)) for w in self._held_ladder}
+            # routing counts the paged step returns with its tokens
+            # (models/grouped_experts.STATS, summed over the step's
+            # swiglu expert layers); they stay 0 for other models
+            self._m_moe = [reg.counter(name, doc).labels() for name, doc in (
+                ("serving_moe_picks_total",
+                 "expert picks made by live rows (rows x top-k x expert "
+                 "layers), summed over decode dispatches"),
+                ("serving_moe_held_picks_total",
+                 "of those, picks that fell on an expert this engine "
+                 "holds"),
+                ("serving_moe_experts_touched_total",
+                 "held experts that got at least one pick, summed over "
+                 "expert layers and decode dispatches: the experts whose "
+                 "weights a step had to read"),
+                ("serving_moe_layer_steps_total",
+                 "expert layers run, summed over decode dispatches"))]
             reg.gauge("serving_paged_blocks_free",
                       "allocatable KV blocks currently free"
                       ).set_function(
@@ -986,13 +1008,16 @@ class DecodeEngine:
 
             def _one_step_paged(params, pool, tables, last, pos, temps,
                                 topk, topp, seeds, key):
-                logits, pool = decode_step_paged(params, pool, tables,
-                                                 last, pos, cfg,
-                                                 kernel=kern,
-                                                 interpret=kern_interp,
-                                                 held_blocks=ladder)
+                logits, pool, stats = decode_step_paged(
+                    params, pool, tables, last, pos, cfg, kernel=kern,
+                    interpret=kern_interp, held_blocks=ladder,
+                    with_stats=True)
                 tok, key = _sample_tok(logits, temps, topk, topp, seeds,
                                        pos, key)
+                # a model with routing counts sends them home behind the
+                # tokens, in the same array: no second transfer
+                if stats is not None:
+                    tok = jnp.concatenate([tok, stats["counts"]])
                 return tok, pool, key
 
             @partial(jax.jit, donate_argnums=(1,))
@@ -1009,7 +1034,7 @@ class DecodeEngine:
                     tok, pool, key = _one_step_paged(
                         params, pool, tables, last, pos, temps, topk,
                         topp, seeds, key)
-                    return (pool, tok, pos + 1, key), tok
+                    return (pool, tok[:last.shape[0]], pos + 1, key), tok
 
                 (pool, _, _, key), toks = jax.lax.scan(
                     body, (pool, last, pos, key), None, length=n_sync)
@@ -1528,6 +1553,9 @@ class DecodeEngine:
         share one across engines)."""
         if self._kv_spill is not None:
             return self._kv_spill
+        from .models.paged_decode import require_per_head_cache
+
+        require_per_head_cache(self.config, "the KV spill tier (kvtier)")
         if self._kv_cache is None:
             self.enable_prefix_cache()
         from .kvtier import TieredSpill
@@ -1640,7 +1668,8 @@ class DecodeEngine:
         """One pool block as a host payload dict — the demotion read.
         Must run BEFORE the block id is reused (i.e. inside the
         eviction callback, before the free list hands it out)."""
-        return {name: (np.asarray(lc["k"][bid]), np.asarray(lc["v"][bid]))
+        return {name: tuple(np.asarray(lc[leaf][bid])
+                            for leaf in sorted(lc))
                 for name, lc in self.pool.items()}
 
     def _on_cache_evict(self, entry) -> None:
@@ -1823,11 +1852,11 @@ class DecodeEngine:
         if not indices:
             return []
         bs = self._kv_cache_bs
-        host = {name: (np.asarray(lc["k"][0]), np.asarray(lc["v"][0]))
+        host = {name: tuple(np.asarray(lc[leaf][0]) for leaf in sorted(lc))
                 for name, lc in row.items()}
-        return [{name: (k[:, i * bs:(i + 1) * bs].copy(),
-                        v[:, i * bs:(i + 1) * bs].copy())
-                 for name, (k, v) in host.items()}
+        return [{name: tuple(a[:, i * bs:(i + 1) * bs].copy()
+                             for a in parts)
+                 for name, parts in host.items()}
                 for i in indices]
 
     def _host_cache_row(self, hits):
@@ -1839,11 +1868,12 @@ class DecodeEngine:
         flat = []
         names = sorted(hits[0].payload,
                        key=lambda n: int(n.split("_", 1)[1]))
+        leaves = sorted(self.config.cache_leaves())
         for name in names:
-            flat.append(np.stack([e.payload[name][0] for e in hits]))
-            flat.append(np.stack([e.payload[name][1] for e in hits]))
+            for j in range(len(leaves)):
+                flat.append(np.stack([e.payload[name][j] for e in hits]))
         row_np = import_kv_blocks(flat, len(hits) * self._kv_cache_bs,
-                                  self.max_len)
+                                  self.max_len, leaves=leaves)
         return jax.tree_util.tree_map(
             lambda a: jnp.asarray(a, self.config.dtype), row_np)
 
@@ -2284,6 +2314,10 @@ class DecodeEngine:
         # KV payload failing at admission time would raise inside the
         # server's engine loop and read as engine death (500s for
         # everyone) instead of one bad request's 400
+        from .models.paged_decode import require_per_head_cache
+
+        require_per_head_cache(self.config, "submit_prefilled (the "
+                                            "disaggregated wire)")
         prompt_size = int(np.asarray(prompt).size)
         if isinstance(kv_blocks, dict):
             # prebuilt batch-1 row cache (``import_kv_blocks`` output):
@@ -2519,8 +2553,11 @@ class DecodeEngine:
         SPECULATIVE engine: draft KV never ships — run the prefill tier
         on plain target-only engines and give the DECODE workers the
         draft (they recompute draft KV at admission)."""
-        from .models.paged_decode import export_kv_blocks
+        from .models.paged_decode import (export_kv_blocks,
+                                          require_per_head_cache)
 
+        require_per_head_cache(self.config, "export_prefill (the "
+                                            "disaggregated wire)")
         if self.draft_config is not None:
             raise ValueError(
                 "export_prefill does not compose with speculative mode:"
@@ -4394,7 +4431,7 @@ class DecodeEngine:
                         jnp.asarray(self._topk), jnp.asarray(self._topp),
                         jnp.asarray(self._slot_seed), self._key)
             with self._psec("elephas.loop.decode.wait"):
-                toks = np.asarray(toks)                   # (B, K)
+                toks = self._split_counts(np.asarray(toks))   # (B, K)
             with self._psec("elephas.loop.emit"):
                 for slot in np.nonzero(active)[0]:
                     rid = self._rid[slot]
@@ -4423,7 +4460,7 @@ class DecodeEngine:
                     jnp.asarray(self._topk), jnp.asarray(self._topp),
                     jnp.asarray(self._slot_seed), self._key)
         with self._psec("elephas.loop.decode.wait"):
-            toks = np.asarray(toks)
+            toks = self._split_counts(np.asarray(toks))
         with self._psec("elephas.loop.emit"):
             for slot in np.nonzero(active)[0]:
                 rid = self._rid[slot]
@@ -4434,16 +4471,29 @@ class DecodeEngine:
         self._admit()
         return emitted
 
+    def _split_counts(self, toks: np.ndarray) -> np.ndarray:
+        """A step's tokens, ``(max_slots,)`` or ``(max_slots, K)``; what
+        a paged step sent behind them (its routing counts, one per
+        counter and fused step) goes to the counters."""
+        if toks.shape[0] > self.max_slots:
+            counts = toks[self.max_slots:]
+            for metric, value in zip(self._m_moe, counts):
+                metric.inc(int(np.sum(value)))
+            toks = toks[:self.max_slots]
+        return toks
+
     def _held_width(self, pos: np.ndarray) -> Tuple[int, int]:
         """(blocks the rows at ``pos`` hold, the ladder width the step
         program picks for them): the host's copy of the device's
         arithmetic, for the counters."""
         from .models.paged_decode import held_block_count
 
-        held = held_block_count(pos, self.paged[1], self._mb,
-                                self.config.attention_window)
-        assert held <= self._held_ladder[-1], (held, self._held_ladder)
-        return held, next(w for w in self._held_ladder if w >= held)
+        window = self.config.attention_window
+        held = held_block_count(pos, self.paged[1], self._mb, window)
+        need = (held if self._held_tile == 1 else held_block_count(
+            pos, self.paged[1], self._mb, window, self._held_tile))
+        assert need <= self._held_ladder[-1], (need, self._held_ladder)
+        return held, next(w for w in self._held_ladder if w >= need)
 
     def _count_held(self, pos: np.ndarray):
         held, width = self._held_width(pos)
