@@ -21,6 +21,16 @@ differently between them; f32 compute is deterministic.
 
 The step loop is host-driven by design: an online server admits and
 retires requests between steps, which is exactly the host round trip.
+Plain stepping hides that round trip instead of paying it: it keeps
+ONE step in flight (``_step_plain``), dispatching step k+1 -- whose
+inputs are step k's tokens, taken on the device, and positions one
+further -- before it reads step k's tokens, so reading, recording,
+handing over, admitting and uploading run beside the device. Tokens
+are the synchronous loop's, row for row (greedy, seeded, and unseeded
+rows stepped together); what the host cannot know a step ahead (eos, a
+cancel, a deadline, a preemption) costs one surplus token, dropped.
+The speculative and fused (``steps_per_sync > 1``) loops, whose next
+position depends on what a step returns, stay synchronous.
 For offline batch generation, :func:`generate`'s single fused scan is
 the faster shape.
 
@@ -94,7 +104,8 @@ import threading
 import time
 from collections import deque
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Dict, List, NamedTuple, Optional, Sequence, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -215,6 +226,16 @@ def validate_sampling_overrides(temperature, top_k, top_p) -> None:
         raise ValueError(f"top_k must be >= 1, got {top_k}")
     if top_p is not None and not 0.0 < top_p <= 1.0:
         raise ValueError(f"top_p must be in (0, 1], got {top_p}")
+
+
+class _Flight(NamedTuple):
+    """A dispatched decode step whose tokens the host has not read."""
+
+    home: jax.Array      # what comes home: tokens, then routing counts
+    tokens: jax.Array    # the tokens alone: the next step's ``prev``
+    rows: np.ndarray     # (max_slots,) bool: the slots it stepped
+    key_in: jax.Array    # the engine key it was given ...
+    key_out: jax.Array   # ... and the one it returned
 
 
 class DecodeEngine:
@@ -553,6 +574,13 @@ class DecodeEngine:
         self._pos = np.zeros(self.max_slots, np.int32)
         self._last = np.zeros(self.max_slots, np.int32)
         self._budget = np.zeros(self.max_slots, np.int32)
+        # the plain path keeps one decode step in flight (_step_plain):
+        # the step whose tokens the host has not read yet, and the
+        # slots whose pending token the HOST set since the last
+        # dispatch (an admission's first token) -- every other live
+        # row's pending token is that step's output, on the device
+        self._ahead: Optional[_Flight] = None
+        self._last_set = np.zeros(self.max_slots, bool)
         self._temp = np.full(self.max_slots, self.temperature, np.float32)
         self._topk = np.zeros(self.max_slots, np.int32)    # 0 = off
         self._topp = np.ones(self.max_slots, np.float32)   # 1 = off
@@ -659,6 +687,17 @@ class DecodeEngine:
         self._m_emitted = reg.counter(
             "serving_tokens_emitted_total", "output tokens emitted"
             ).labels()
+        self._m_ahead = reg.counter(
+            "serving_decode_steps_ahead_total",
+            "decode steps dispatched while the step before them was "
+            "still in flight, its tokens unread (plain stepping runs "
+            "one ahead; of serving_steps_total, all but the first "
+            "after an idle engine)").labels()
+        self._m_surplus = reg.counter(
+            "serving_decode_surplus_rows_total",
+            "row-steps whose token was dropped: the row retired (eos, "
+            "cancel, deadline, preemption) with its next step already "
+            "in flight").labels()
         self._m_finished = reg.counter(
             "serving_requests_finished_total",
             "requests retired at eos or budget").labels()
@@ -969,11 +1008,19 @@ class DecodeEngine:
                                    pos, key)
             return tok, cache, key
 
+        def _ride(last, prev):
+            # one step is kept in flight (_step_plain): a row still
+            # riding the step before this one has -1 for its token on
+            # the host and takes that step's output here, on the
+            # device; a row the host set since (an admission's first
+            # token) overrides it
+            return jnp.where(last >= 0, last, prev)
+
         @partial(jax.jit, donate_argnums=(1,))
-        def _step(params, cache, last, pos, temps, topk, topp, seeds,
-                  key):
-            return _one_step(params, cache, last, pos, temps, topk, topp,
-                             seeds, key)
+        def _step(params, cache, last, prev, pos, temps, topk, topp,
+                  seeds, key):
+            return _one_step(params, cache, _ride(last, prev), pos,
+                             temps, topk, topp, seeds, key)
 
         n_sync = self.steps_per_sync
 
@@ -1021,10 +1068,15 @@ class DecodeEngine:
                 return tok, pool, key
 
             @partial(jax.jit, donate_argnums=(1,))
-            def _step_paged(params, pool, tables, last, pos, temps,
+            def _step_paged(params, pool, tables, last, prev, pos, temps,
                             topk, topp, seeds, key):
-                return _one_step_paged(params, pool, tables, last, pos,
-                                       temps, topk, topp, seeds, key)
+                home, pool, key = _one_step_paged(
+                    params, pool, tables, _ride(last, prev), pos, temps,
+                    topk, topp, seeds, key)
+                # the tokens a second time, without what rides home
+                # behind them: the next step's ``prev``, which never
+                # leaves the device
+                return home, home[:last.shape[0]], pool, key
 
             @partial(jax.jit, donate_argnums=(1,))
             def _multi_step_paged(params, pool, tables, last, pos, temps,
@@ -1133,7 +1185,8 @@ class DecodeEngine:
         # zero and stats equals the scraped series exactly.
         self._stat_base = counter_baseline(
             self._m_steps, self._m_emitted, self._m_finished,
-            self._m_shed, self._m_expired, self._m_timed_out,
+            self._m_ahead, self._m_surplus, self._m_shed,
+            self._m_expired, self._m_timed_out,
             self._m_accepted, self._m_proposed,
             self._m_prefix_hits, self._m_prefix_tokens,
             self._m_weight_swaps,
@@ -1241,6 +1294,9 @@ class DecodeEngine:
                      topp=jnp.asarray(self._topp),
                      seeds=jnp.asarray(self._slot_seed),
                      key=jax.random.PRNGKey(0))
+        # the plain step also takes the tokens of the step before it
+        lasts = ((dummy["last"],) if self.steps_per_sync > 1
+                 else (dummy["last"], dummy["last"]))
         # the step fns donate the cache argument, so warming on the
         # engine's OWN cache (idle: every slot free, paged writes land
         # on scratch block 0) costs zero extra device memory — an
@@ -1254,11 +1310,10 @@ class DecodeEngine:
         elif self.paged is not None:
             fn = (self._multi_step_paged_fn if self.steps_per_sync > 1
                   else self._step_paged_fn)
-            _, self.pool, _ = fn(
+            self.pool = fn(
                 self.params, self.pool, jnp.asarray(self._tables),
-                dummy["last"], dummy["pos"], dummy["temps"],
-                dummy["topk"], dummy["topp"], dummy["seeds"],
-                dummy["key"])
+                *lasts, dummy["pos"], dummy["temps"], dummy["topk"],
+                dummy["topp"], dummy["seeds"], dummy["key"])[-2]
         elif self.draft_config is not None:
             out = self._spec_step_for(self._gamma_now)(
                 self.params, self.draft_params, self.cache,
@@ -1269,7 +1324,7 @@ class DecodeEngine:
             fn = (self._multi_step_fn if self.steps_per_sync > 1
                   else self._step_fn)
             _, self.cache, _ = fn(
-                self.params, self.cache, dummy["last"], dummy["pos"],
+                self.params, self.cache, *lasts, dummy["pos"],
                 dummy["temps"], dummy["topk"], dummy["topp"],
                 dummy["seeds"], dummy["key"])
         for length in sorted(set(int(n) for n in prompt_lengths)):
@@ -3109,6 +3164,7 @@ class DecodeEngine:
         self._slot_wv[slot] = self.weights_version
         self._pos[slot] = prompt.size - 1
         self._last[slot] = t0
+        self._last_set[slot] = True
         self._budget[slot] = max_new
         self._temp[slot] = temp
         self._topk[slot] = topk
@@ -3660,6 +3716,7 @@ class DecodeEngine:
         self._slot_wv[slot] = st["wv0"]
         self._pos[slot] = prompt.size - 1
         self._last[slot] = t0
+        self._last_set[slot] = True
         self._budget[slot] = item.max_new
         self._temp[slot] = st["temp"]
         self._topk[slot] = st["topk"]
@@ -3906,6 +3963,19 @@ class DecodeEngine:
         return True
 
     def _release_blocks(self, slot: int):
+        # A row that retires in a way the host could not know a step
+        # ahead (eos, cancel, deadline, preemption) may still be in the
+        # step in flight (_step_plain), which writes ONE position into
+        # a block released here. That is safe, by the device's order:
+        # the step was given its tables when it was dispatched, so it
+        # writes to a block the row owned then, at a decode position --
+        # past the prompt, hence never in a shared (cached) block, and
+        # past every full block _park_slot_blocks parks (those end at
+        # _pos, the surplus write is at _pos + 1). Whatever the host
+        # enqueues afterwards for the block's next owner (prefill
+        # chunks, install_row_paged) or reads from it (demotion,
+        # session save) runs behind that step on the device, and from
+        # the next dispatch on this slot's table points at scratch.
         if self.paged is not None and (self._slot_blocks[slot]
                                        or self._slot_cached[slot]):
             self._free_block_ids.extend(self._slot_blocks[slot])
@@ -4100,6 +4170,11 @@ class DecodeEngine:
                "tokens_emitted": emitted,
                "requests_finished": int(self._since_init(self._m_finished)),
                "tokens_per_step": (emitted / steps if steps else 0.0),
+               # plain stepping keeps one step in flight: the steps
+               # dispatched ahead of their predecessor's tokens, and
+               # the row-steps dropped because the row retired meanwhile
+               "steps_ahead": int(self._since_init(self._m_ahead)),
+               "surplus_rows": int(self._since_init(self._m_surplus)),
                # overload-safety counters: admission rejections (429),
                # queued-deadline sheds (504), mid-decode timeouts, and
                # the live backlog the admission bounds act on
@@ -4328,7 +4403,14 @@ class DecodeEngine:
         (admission-time first tokens ride along too). Finished requests
         retire and queued ones join automatically; expired queued
         requests are shed before prefill and over-deadline active slots
-        are freed (their partial output finishes as a ``timeout``)."""
+        are freed (their partial output finishes as a ``timeout``).
+
+        Plain mode runs one step ahead (:meth:`_step_plain`): the call
+        returns step k's tokens with step k+1 already queued on the
+        device, so a request admitted now joins the step dispatched
+        next, and a row that retires at eos, by ``cancel``, deadline or
+        preemption leaves one surplus token in flight, which is dropped
+        (``serving_decode_surplus_rows_total``)."""
         if self.profiler is not None:
             # iteration boundary: wall time since the LAST tick —
             # including the server loop's idle sleep — closes into the
@@ -4358,6 +4440,8 @@ class DecodeEngine:
         active = np.asarray([r is not None for r in self._rid])
         if not active.any():
             return emitted
+        if self.draft_config is None and self.steps_per_sync == 1:
+            return self._step_plain(active, emitted)
         # inactive slots decode garbage at position 0 (static batch
         # shape); their writes are overwritten by the next admission's
         # prefill and masked until then
@@ -4411,65 +4495,146 @@ class DecodeEngine:
                             emitted.setdefault(rid, []).append(int(tok))
             self._admit()
             return emitted
-        if self.steps_per_sync > 1:
-            with self._psec("elephas.loop.decode.dispatch"):
-                if self.paged is not None:
-                    self._count_held(pos)
-                    toks, self.pool, self._key = \
-                        self._multi_step_paged_fn(
-                            self.params, self.pool,
-                            jnp.asarray(self._tables),
-                            jnp.asarray(self._last), jnp.asarray(pos),
-                            jnp.asarray(self._temp),
-                            jnp.asarray(self._topk),
-                            jnp.asarray(self._topp),
-                            jnp.asarray(self._slot_seed), self._key)
-                else:
-                    toks, self.cache, self._key = self._multi_step_fn(
-                        self.params, self.cache, jnp.asarray(self._last),
-                        jnp.asarray(pos), jnp.asarray(self._temp),
-                        jnp.asarray(self._topk), jnp.asarray(self._topp),
-                        jnp.asarray(self._slot_seed), self._key)
-            with self._psec("elephas.loop.decode.wait"):
-                toks = self._split_counts(np.asarray(toks))   # (B, K)
-            with self._psec("elephas.loop.emit"):
-                for slot in np.nonzero(active)[0]:
-                    rid = self._rid[slot]
-                    for tok in toks[slot]:
-                        if self._rid[slot] is None:
-                            break   # retired mid-chunk — surplus dropped
-                        self._pos[slot] += 1
-                        self._last[slot] = tok
-                        if self._record(slot, int(tok)):
-                            emitted.setdefault(rid, []).append(int(tok))
-            self._admit()
-            return emitted
+        # steps_per_sync > 1: the fused steps of one dispatch
         with self._psec("elephas.loop.decode.dispatch"):
             if self.paged is not None:
                 self._count_held(pos)
-                toks, self.pool, self._key = self._step_paged_fn(
-                    self.params, self.pool, jnp.asarray(self._tables),
-                    jnp.asarray(self._last), jnp.asarray(pos),
-                    jnp.asarray(self._temp), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp),
-                    jnp.asarray(self._slot_seed), self._key)
+                toks, self.pool, self._key = \
+                    self._multi_step_paged_fn(
+                        self.params, self.pool,
+                        jnp.asarray(self._tables),
+                        jnp.asarray(self._last), jnp.asarray(pos),
+                        jnp.asarray(self._temp),
+                        jnp.asarray(self._topk),
+                        jnp.asarray(self._topp),
+                        jnp.asarray(self._slot_seed), self._key)
             else:
-                toks, self.cache, self._key = self._step_fn(
+                toks, self.cache, self._key = self._multi_step_fn(
                     self.params, self.cache, jnp.asarray(self._last),
                     jnp.asarray(pos), jnp.asarray(self._temp),
                     jnp.asarray(self._topk), jnp.asarray(self._topp),
                     jnp.asarray(self._slot_seed), self._key)
         with self._psec("elephas.loop.decode.wait"):
-            toks = self._split_counts(np.asarray(toks))
+            toks = self._split_counts(np.asarray(toks))   # (B, K)
         with self._psec("elephas.loop.emit"):
             for slot in np.nonzero(active)[0]:
+                rid = self._rid[slot]
+                for tok in toks[slot]:
+                    if self._rid[slot] is None:
+                        break   # retired mid-chunk — surplus dropped
+                    self._pos[slot] += 1
+                    self._last[slot] = tok
+                    if self._record(slot, int(tok)):
+                        emitted.setdefault(rid, []).append(int(tok))
+        self._admit()
+        return emitted
+
+    def _step_plain(self, live: np.ndarray,
+                    emitted: Dict[int, List[int]]
+                    ) -> Dict[int, List[int]]:
+        """One token for every live row, with the NEXT step dispatched
+        before this one's tokens are read, so the host's round trip
+        (read, record, hand over, admit, upload) runs beside the device
+        instead of between its steps. Nothing in the next step's inputs
+        needs the host: a riding row's token is the in-flight step's
+        output (taken on the device, ``_ride``), its position one
+        further; tables and sampling settings are fixed at admission;
+        a budget that ends with the in-flight token is known now.
+
+        What the host cannot know a step ahead -- eos, a cancel, a
+        deadline, a preemption -- leaves one surplus token in flight:
+        it is dropped and counted when its step is collected. Tokens
+        are the synchronous loop's, row for row; a row admitted while a
+        step is in flight joins the one dispatched next."""
+        flight = self._ahead
+        # rows whose pending token is the in-flight step's output
+        riding = (live & ~self._last_set if flight is not None
+                  else np.zeros_like(live))
+        if flight is not None and not riding.any():
+            self._abandon(flight)
+            flight = None
+        if flight is None:
+            flight, riding = self._launch(live, riding, None), live
+        assert not (riding & ~flight.rows).any(), (riding, flight.rows)
+        self._ahead = self._launch(live, riding, flight)
+        # a round trip is counted where it ends: one a call, as in the
+        # synchronous loop (a step abandoned in flight is none)
+        self._m_steps.inc()
+        with self._psec("elephas.loop.decode.wait"):
+            toks = self._split_counts(np.asarray(flight.home))
+        self._m_surplus.inc(int((flight.rows & ~riding).sum()))
+        with self._psec("elephas.loop.emit"):
+            for slot in np.nonzero(riding)[0]:
                 rid = self._rid[slot]
                 self._pos[slot] += 1
                 self._last[slot] = toks[slot]
                 if self._record(slot, int(toks[slot])):
                     emitted.setdefault(rid, []).append(int(toks[slot]))
+        if self._ahead is not None and not any(
+                r is not None for r in self._rid):
+            # the last live rows retired in a way the host could not
+            # know a step ahead: nothing waits for the surplus step
+            self._abandon(self._ahead)
         self._admit()
         return emitted
+
+    def _launch(self, live: np.ndarray, riding: np.ndarray,
+                flight: Optional[_Flight]) -> Optional[_Flight]:
+        """Dispatch a decode step for the live rows: those ``riding``
+        ``flight`` (the step in the air, None when there is none) one
+        position further, but for the ones whose budget ends with its
+        token, and the rest from the host's token. None when no row is
+        left to step."""
+        rows = live & (~riding | (self._budget > 1))
+        if not rows.any():
+            return None
+        # left-out slots decode garbage at position 0 (static batch
+        # shape) into the scratch block, or into their own contiguous
+        # row, which the next admission's install overwrites
+        pos = np.where(rows, self._pos + 1 + riding, 0).astype(np.int32)
+        last = np.where(riding, -1, self._last).astype(np.int32)
+        self._last_set[:] = False
+        if flight is not None:
+            self._m_ahead.inc()
+        key_in = self._key
+        with self._psec("elephas.loop.decode.dispatch"):
+            last = jnp.asarray(last)
+            # copies: an upload reads its host array when the transfer
+            # runs, not when it is enqueued (seen on the TPU), and the
+            # next admission writes these arrays with this step in
+            # flight; the synchronous loops wait for their step first
+            args = (last, last if flight is None else flight.tokens,
+                    jnp.asarray(pos), jnp.asarray(self._temp.copy()),
+                    jnp.asarray(self._topk.copy()),
+                    jnp.asarray(self._topp.copy()),
+                    jnp.asarray(self._slot_seed.copy()), self._key)
+            if self.paged is not None:
+                self._count_held(pos)
+                # a row left out while it still holds its blocks (its
+                # budget ends in flight) must not take the garbage
+                # write: its first block may be shared (and a copy)
+                tables = np.where(rows[:, None], self._tables, 0)
+                home, tokens, self.pool, self._key = self._step_paged_fn(
+                    self.params, self.pool, jnp.asarray(tables), *args)
+            else:
+                home, self.cache, self._key = self._step_fn(
+                    self.params, self.cache, *args)
+                tokens = home
+            # start the tokens' way home behind the step
+            home.copy_to_host_async()
+        return _Flight(home, tokens, rows, key_in, self._key)
+
+    def _abandon(self, flight: _Flight) -> None:
+        """Drop a step in flight that no live row rides: every row of
+        it is surplus, and nothing waits for it. The engine key goes
+        back to what the step was given unless something drew from it
+        since, so that unseeded rows sample as they would have without
+        the abandoned step."""
+        self._m_surplus.inc(int(flight.rows.sum()))
+        if self._key is flight.key_out:
+            self._key = flight.key_in
+        if self._ahead is flight:
+            self._ahead = None
 
     def _split_counts(self, toks: np.ndarray) -> np.ndarray:
         """A step's tokens, ``(max_slots,)`` or ``(max_slots, K)``; what

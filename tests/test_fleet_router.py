@@ -158,22 +158,44 @@ def reused_sanity_ok(ch_reused: int, rr_reused: int) -> bool:
 
 
 # -------------------------------------------- kill -> evict -> re-route
+def _wait_for(cond, what, timeout=60.0):
+    """Poll ``cond`` until it returns something true (returned); the
+    tests below wait on what they assert, not on the clock: six test
+    workers on one box stretch every interval."""
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        out = cond()
+        if out:
+            return out
+        time.sleep(0.02)
+    raise AssertionError(f"timed out after {timeout}s waiting for {what}")
+
+
 def test_replica_kill_evicts_and_reroutes_with_no_failed_requests(model):
-    """Killing one replica mid-load: the router evicts it (connect
-    errors and/or the /ready probe) within the probe interval and every
-    client request still succeeds — re-routing costs recompute, never a
-    failed response."""
+    """Killing one replica mid-load, with a decode step of its engine
+    in flight: the router evicts it (connect errors and/or the /ready
+    probe) and every client request still succeeds with the solo
+    decode's tokens — re-routing costs recompute, never a failed
+    response. A dead replica refuses connections at once, so the probe
+    timeout can be generous: a live replica that answers late on a
+    loaded box must not be taken for a dead one."""
     params, config = model
     rng = np.random.default_rng(11)
     pool = ReplicaPool(
         lambda: DecodeEngine(params, config, max_slots=2), n=3).start()
     try:
-        with FleetRouter(pool.urls, probe_interval=0.2,
-                         evict_after=2) as router:
+        with FleetRouter(pool.urls, probe_interval=0.2, evict_after=2,
+                         probe_timeout=30.0) as router:
+            def stats():
+                return _get(router.port, "/stats")
+
+            _wait_for(lambda: stats()["ring_size"] == 3,
+                      "the three replicas to join the ring")
             prompts = [[int(t) for t in rng.integers(0, 300, 5)]
                        for _ in range(4)]
             refs = [_ref(params, config, p, 4) for p in prompts]
             failures, done = [], threading.Event()
+            served = [0, 0, 0]
 
             def load(worker):
                 i = 0
@@ -188,31 +210,37 @@ def test_replica_kill_evicts_and_reroutes_with_no_failed_requests(model):
                     except Exception as exc:  # noqa: BLE001
                         failures.append((type(exc).__name__, str(exc)))
                     i += 1
+                    served[worker] += 1
+
+            def all_served(more):
+                mark = list(served)
+                _wait_for(lambda: all(n >= m + more
+                                      for n, m in zip(served, mark)),
+                          f"{more} more answers for every client")
 
             threads = [threading.Thread(target=load, args=(w,))
                        for w in range(3)]
             for t in threads:
                 t.start()
-            time.sleep(0.7)            # load established on all replicas
-            pool.kill(0)
-            killed_url = pool.urls[0]
-            # eviction within the probe window (2 x 0.2s + slack; a
-            # proxied connect error usually evicts faster)
-            deadline = time.time() + 3
-            while time.time() < deadline:
-                if _get(router.port, "/stats")["replicas_evicted"] >= 1:
-                    break
-                time.sleep(0.05)
-            stats = _get(router.port, "/stats")
-            time.sleep(0.5)            # more traffic after the eviction
+            all_served(2)              # load established
+            # the victim: a replica whose engine has a step in the air
+            victim = _wait_for(
+                lambda: next((i + 1 for i, eng in enumerate(pool.engines)
+                              if eng._ahead is not None), None),
+                "a replica with a decode step in flight") - 1
+            pool.kill(victim)
+            killed_url = pool.urls[victim]
+            snap = _wait_for(
+                lambda: (s := stats())["replicas_evicted"] >= 1
+                and killed_url not in s["ring_nodes"] and s,
+                "the killed replica's eviction")
+            all_served(2)              # more traffic after the eviction
             done.set()
             for t in threads:
                 t.join(timeout=60)
             assert not failures, failures[:5]
-            assert stats["replicas_evicted"] >= 1
-            assert stats["ring_size"] == 2
-            assert killed_url not in stats["ring_nodes"]
-            assert not stats["replicas"][killed_url]["ready"]
+            assert snap["ring_size"] == 2
+            assert not snap["replicas"][killed_url]["ready"]
             evts = recent_events(event="fleet.replica_evicted")
             assert any(e["replica"] == killed_url and e["reason"] == "dead"
                        for e in evts)

@@ -161,3 +161,34 @@ def test_paged_max_len_not_block_multiple(model):
                        paged=(16, 8))
     [out] = eng.run([prompt], max_new_tokens=3)
     assert out == _ref(params, config, prompt, 3)
+
+
+def test_preemption_with_a_step_in_flight_drops_its_surplus_token(model):
+    """A low-priority decode is preempted while its next token is in
+    flight: the token is dropped (the resume samples it again), the
+    parked blocks hold what the synchronous loop's would, and all three
+    requests decode as they do alone."""
+    from elephas_tpu.serving_qos import TenantQoS
+
+    params, config = model
+    rng = np.random.default_rng(61)
+    pa, pb, pc = (rng.integers(0, 64, n) for n in (10, 6, 5))
+    qos = TenantQoS(tenants={"batch": {"priority": "low"},
+                             "live": {"priority": "high"}})
+    eng = DecodeEngine(params, config, max_slots=2, paged=(24, 4),
+                       qos=qos)
+    ra = eng.submit(pa, 14, tenant="batch")
+    rb = eng.submit(pb, 14, tenant="live")
+    for _ in range(6):
+        eng.step()
+    assert eng._ahead is not None and eng._ahead.rows.all()
+    rc = eng.submit(pc, 4, tenant="live")     # no free slot: ra parks
+    while eng.pending:
+        eng.step()
+    assert eng.stats["preemptions"] == 1
+    assert eng.stats["surplus_rows"] == 1
+    for rid, p, n in ((ra, pa, 14), (rb, pb, 14), (rc, pc, 4)):
+        assert eng.result(rid) == _ref(params, config, p, n)
+    events = eng.request_trace(ra)["events"]
+    assert next(ev for ev in events
+                if ev["event"] == "preempted")["parked_blocks"] >= 1

@@ -205,6 +205,46 @@ def test_streaming_generate(model):
         assert exc.value.code == 404
 
 
+@pytest.mark.parametrize("paged", [None, (40, 4)],
+                         ids=["contiguous", "paged"])
+def test_concurrent_streams_carry_the_solo_decodes(model, paged):
+    """Five streams of different budgets through two slots, with the
+    engine one step ahead of what the handlers have been given: every
+    stream's lines concatenate to its solo greedy decode, no token
+    twice, none after the budget, each ending in a done line."""
+    params, config = model
+    rng = np.random.default_rng(5)
+    prompts = [[int(t) for t in rng.integers(0, 300, 5)]
+               for _ in range(5)]
+    budgets = [10, 3, 7, 12, 5]
+    lines = {}
+
+    def stream(i, port):
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{port}/v1/generate",
+            data=json.dumps({"prompt": prompts[i], "stream": True,
+                             "max_new_tokens": budgets[i]}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=120) as resp:
+            lines[i] = [json.loads(raw) for raw in resp]
+
+    engine = DecodeEngine(params, config, max_slots=2, paged=paged)
+    with ServingServer(engine) as srv:
+        threads = [threading.Thread(target=stream, args=(i, srv.port))
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        stats = _get(srv.port, "/stats")
+    for i, p in enumerate(prompts):
+        assert lines[i][-1] == {"status": "done"}
+        streamed = [t for ln in lines[i][:-1] for t in ln["tokens"]]
+        assert streamed == _ref(params, config, p, 12)[:budgets[i]]
+    assert stats["surplus_rows"] == 0
+    assert stats["steps_ahead"] >= stats["steps"] - len(prompts)
+
+
 def test_streaming_cancel_terminates(model):
     import time
 
