@@ -1474,20 +1474,12 @@ def measure_engine(max_slots=8, n_requests=16, prompt_len=16,
     drain(eng_pc)                    # compile suffix-extend path
     prefix_tps = drain(eng_pc)
 
-    # multi-step scheduling: K decode steps per dispatch — where
-    # per-dispatch latency dominates, throughput scales ~K
-    eng_ms = DecodeEngine(params, c, max_slots=max_slots,
-                          steps_per_sync=8)
-    drain(eng_ms)
-    multi_tps = drain(eng_ms)
-
     # paged KV gather cost, UNCONFOUNDED: pool sized so all slots stay
-    # concurrent (same occupancy as the multi-step baseline) — the ratio
-    # then isolates the per-step block-table gather; the capacity story
+    # concurrent (same occupancy as the contiguous engine) — the ratio
+    # then isolates the per-step block gather; the capacity story
     # (oversubscribed pool, queued admission) is pinned by CPU tests
     per_req = -(-(prompt_len + max_new_tokens) // 16)
     eng_pg = DecodeEngine(params, c, max_slots=max_slots,
-                          steps_per_sync=8,
                           paged=(1 + max_slots * per_req, 16))
     drain(eng_pg)
     paged_tps = drain(eng_pg)
@@ -1512,10 +1504,8 @@ def measure_engine(max_slots=8, n_requests=16, prompt_len=16,
             "max_slots": max_slots, "n_requests": n_requests,
             "max_new_tokens": max_new_tokens,
             "prefix_tokens_per_sec": round(prefix_tps, 1),
-            "multi_step8_tokens_per_sec": round(multi_tps, 1),
-            "multi_step8_speedup": round(multi_tps / plain_tps, 3),
-            "paged_ms8_tokens_per_sec": round(paged_tps, 1),
-            "paged_vs_multi_step8": round(paged_tps / multi_tps, 3),
+            "paged_tokens_per_sec": round(paged_tps, 1),
+            "paged_vs_contiguous": round(paged_tps / plain_tps, 3),
             "admission_ms": round(plain_adm, 2),
             "prefix_admission_ms": round(prefix_adm, 2),
             "prefix_admission_speedup": round(plain_adm / prefix_adm, 3),

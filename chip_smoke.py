@@ -10,9 +10,9 @@ calls, at the full width the repo benchmarks its flagship LM at
    for a few steps on a repeated batch, then the same init and batch
    through XLA attention: first-step loss and gradient norm must agree.
 3. **serve** — the README quickstart engine behind ``ServingServer``,
-   real HTTP traffic (shared prefix, a stream), then a second engine on
-   the Pallas paged-decode kernel; outputs are held against solo
-   ``generate`` and a float32 reference ``forward``.
+   real HTTP traffic (shared prefix, a stream); outputs are held against
+   solo ``generate`` and a float32 reference ``forward``, and one paged
+   decode step's logits against that reference.
 4. **train, four chips** — ``TPUModel(TransformerModel).fit`` on
    ``data=4`` and on ``data=2 x model=2``, when four devices are visible.
 5. **elephas job** — the reference's MNIST-shaped MLP through
@@ -55,16 +55,14 @@ FLASH_VS_XLA_GNORM_RTOL = 2e-3
 #: first-step loss, mesh fit vs the one-chip flash step (observed 8.1e-7
 #: on data=4, 3.7e-6 on data=2 x model=2)
 MESH_VS_ONE_CHIP_LOSS_RTOL = 1e-4
-#: max |logits_pallas - logits_gather| over one paged decode step
-#: (observed 0.0156 at 8 rows x 200 cached positions, bf16 pool)
-PALLAS_VS_GATHER_LOGITS_ATOL = 0.0625
-#: max |logits_gather - logits_f32| over the same step: bf16 prefill,
-#: block install and paged attention against the float32 "highest"
-#: forward (observed 0.0185; 0.0064 in the CPU pre-flight at toy size)
+#: max |logits_paged - logits_f32| over one paged decode step (8 rows x
+#: 200 cached positions, bf16 pool): bf16 prefill, block install and
+#: paged attention against the float32 "highest" forward (observed
+#: 0.0185; 0.0064 in the CPU pre-flight at toy size)
 PAGED_VS_F32_LOGITS_ATOL = 0.0625
 #: how far below the position's maximum an emitted token's logit may sit
 #: under the float32 "highest"-precision teacher-forced forward (observed
-#: 0.0 for both engines on the chip — every emitted token was the f32
+#: 0.0 on the chip — every emitted token was the f32
 #: argmax, while solo bf16 ``generate`` matched 6 of 7 requests; 0.006
 #: in the CPU pre-flight at toy size). At full width the seeded model
 #: repeats one token per request, so this oracle is easy there; the
@@ -155,12 +153,6 @@ def has_mosaic_kernel(lowered) -> bool:
     is in the lowered program; the Pallas interpreter and plain XLA
     attention both lower to ordinary HLO."""
     return "tpu_custom_call" in lowered.as_text()
-
-
-def pallas_ran_as(on_chip) -> str:
-    """How the Pallas kernels ran, for the summary: the chip run has
-    required the compiled kernel by then; the pre-flight interprets."""
-    return "compiled" if on_chip else "interpreted"
 
 
 # ------------------------------------------------------------------ device
@@ -284,7 +276,9 @@ def phase_train_one_chip(facts, sz, on_chip):
                                  None, sz.batch)
     print(f"  attention_impl='auto', unmeshed, resolves to {auto!r} here "
           f"({len(jax.devices())} device(s) visible)", flush=True)
-    facts.update(attention={"flash_kernel": pallas_ran_as(on_chip),
+    # (the chip run has required the compiled kernel by now)
+    facts.update(attention={"flash_kernel": ("compiled" if on_chip
+                                             else "interpreted"),
                             "auto_unmeshed_resolves_to": auto},
                  loss=[round(x, 5) for x in losses],
                  flash_vs_xla={"loss_rel": loss_rel, "gnorm_rel": gnorm_rel})
@@ -418,14 +412,12 @@ def check_outputs(name, outputs, budgets, oracle, ref_logits, prompts,
             "distinct_tokens": len(set(emitted))}
 
 
-def paged_step_logits(params, config, sz, interpret, ref_logits):
-    """One paged decode step over the same pool through both kernels,
-    after a batched prefill — and the same position through the float32
-    reference, which holds the whole cache path (prefill, block install,
-    table lookup, attention over the pool) to a number, where the
-    seeded model's token choices are too easy to tell a good cache from
-    a bad one. Returns (max |pallas - gather|, max |gather - f32|, which
-    kernels lowered to Mosaic)."""
+def paged_step_logits(params, config, sz, ref_logits):
+    """One paged decode step after a batched prefill, and the same
+    position through the float32 reference, which holds the whole cache
+    path (prefill, block install, table lookup, attention over the pool)
+    to a number, where the seeded model's token choices are too easy to
+    tell a good cache from a bad one. Returns max |paged - f32|."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -451,27 +443,17 @@ def paged_step_logits(params, config, sz, interpret, ref_logits):
         row = jax.tree_util.tree_map(lambda a: a[r:r + 1], cache)
         pool = install_row_paged(pool, row, tables[r], need)
     last = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    args = (params, pool, jnp.asarray(tables), last,
-            jnp.full((rows,), length, jnp.int32))
-    out, mosaic = {}, {}
-    for kernel in ("gather", "pallas"):
-        lowered = jax.jit(
-            lambda p, pl, tb, tk, ps, k=kernel: decode_step_paged(
-                p, pl, tb, tk, ps, config, kernel=k,
-                interpret=interpret)[0]
-        ).lower(*args)
-        mosaic[kernel] = has_mosaic_kernel(lowered)
-        out[kernel] = np.asarray(lowered.compile()(*args), np.float32)
-    require(np.isfinite(out["pallas"]).all(), "non-finite pallas logits")
+    step = jax.jit(lambda p, pl, tb, tk, ps: decode_step_paged(
+        p, pl, tb, tk, ps, config)[0])
+    got = np.asarray(step(params, pool, jnp.asarray(tables), last,
+                          jnp.full((rows,), length, jnp.int32)), np.float32)
     ref = ref_logits(np.concatenate(
         [np.asarray(prompts), np.asarray(last)[:, None]], axis=1))[:, -1]
-    return (float(np.abs(out["pallas"] - out["gather"]).max()),
-            float(np.abs(out["gather"] - ref).max()), mosaic)
+    return float(np.abs(got - ref).max())
 
 
-def phase_serve(facts, sz, params, on_chip):
+def phase_serve(facts, sz, params):
     from elephas_tpu import DecodeEngine, ServingServer
-    from elephas_tpu.obs.events import recent_events
 
     config = lm_config(sz, "flash")
     prefix, prompts, budgets, unit = serve_prompts(sz)
@@ -480,7 +462,6 @@ def phase_serve(facts, sz, params, on_chip):
 
     # the README quickstart engine
     engine = DecodeEngine(params, config, max_slots=sz.slots,
-                          steps_per_sync=8,
                           prefill_chunk=sz.prefill_chunk, paged=sz.pool)
     engine.register_prefix(prefix)
     engine.warmup(prompt_lengths=(unit, 5 * unit))
@@ -525,8 +506,7 @@ def phase_serve(facts, sz, params, on_chip):
         raise AssertionError("the server still answers after stop()")
     print(f"  /stats: finished={stats['requests_finished']} "
           f"prefix_tokens_reused={stats.get('prefix_tokens_reused')} "
-          f"kernel={stats['kernel']} tokens_per_step="
-          f"{stats['tokens_per_step']:.2f}", flush=True)
+          f"tokens_per_step={stats['tokens_per_step']:.2f}", flush=True)
     require(ready_status == 200 and ready == {"status": "ready"},
             f"/ready said {ready}")
     require(health_status == 200, "/health failed")
@@ -537,53 +517,16 @@ def phase_serve(facts, sz, params, on_chip):
     require(not any(failed.values()), f"failed requests: {failed}")
     require(stats["prefix_tokens_reused"] >= 3 * unit,
             f"prefix reuse {stats['prefix_tokens_reused']} < {3 * unit}")
-    require(stats["kernel"] == "gather", f"kernel {stats['kernel']}")
-    gather = check_outputs("gather engine over HTTP", outputs, budgets,
-                           oracle, ref_logits, prompts, sz.vocab)
+    engine_facts = check_outputs("engine over HTTP", outputs, budgets,
+                                 oracle, ref_logits, prompts, sz.vocab)
     del engine, server
 
-    # the Pallas paged-decode kernel: compiled on the chip; the
-    # interpreter stands in for it in the pre-flight only
-    interpret = None if on_chip else True
-    engine = DecodeEngine(params, config, max_slots=sz.slots,
-                          prefill_chunk=sz.prefill_chunk, paged=sz.pool,
-                          kernel="pallas", kernel_interpret=interpret)
-    engine.register_prefix(prefix)
-    rids = [engine.submit(p, n) for p, n in zip(prompts, budgets)]
-    done = {}
-    while len(done) < len(rids):
-        engine.step()
-        for rid in rids:
-            if rid not in done:
-                out = engine.result(rid)      # consumes the result
-                if out is not None:
-                    done[rid] = [int(t) for t in out]
-    stats = engine.stats
-    require(stats["kernel"] == "pallas" and "kernel_requested" not in stats,
-            f"asked for pallas, engine runs {stats['kernel']}")
-    require(not recent_events("serving.kernel_fallback"),
-            "a serving.kernel_fallback event was emitted")
-    pallas = check_outputs("pallas engine", [done[r] for r in rids],
-                           budgets, oracle, ref_logits, prompts, sz.vocab)
-    del engine
-    diff, vs_f32, mosaic = paged_step_logits(params, config, sz, interpret,
-                                             ref_logits)
-    print(f"  paged decode step: max |dlogit| pallas vs gather {diff:.5f}, "
-          f"gather vs f32 reference {vs_f32:.5f}; "
-          f"mosaic_kernel={mosaic['pallas']}", flush=True)
-    if on_chip:
-        require(mosaic["pallas"],
-                "the paged kernel did not lower to a Mosaic kernel")
-    require(not mosaic["gather"], "gather path holds a Pallas kernel")
-    require(diff <= PALLAS_VS_GATHER_LOGITS_ATOL,
-            f"pallas vs gather logits differ by {diff}")
+    vs_f32 = paged_step_logits(params, config, sz, ref_logits)
+    print(f"  paged decode step: max |dlogit| vs f32 reference "
+          f"{vs_f32:.5f}", flush=True)
     require(vs_f32 <= PAGED_VS_F32_LOGITS_ATOL,
             f"paged decode logits differ from the f32 reference by {vs_f32}")
-    facts.update(gather_engine=gather, pallas_engine=pallas,
-                 paged_kernel={"requested": "pallas",
-                               "ran": stats["kernel"],
-                               "lowering": pallas_ran_as(on_chip)},
-                 pallas_vs_gather_max_dlogit=round(diff, 6),
+    facts.update(engine=engine_facts,
                  paged_vs_f32_max_dlogit=round(vs_f32, 6))
 
 
@@ -783,7 +726,7 @@ def main(argv):
     with phases.phase("train_one_chip") as facts:
         params, loss0 = phase_train_one_chip(facts, sz, on_chip)
     with phases.phase("serve") as facts:
-        phase_serve(facts, sz, params, on_chip)
+        phase_serve(facts, sz, params)
     del params
     with phases.phase("train_four_chips") as facts:
         phase_train_four_chips(facts, sz, loss0, on_chip)

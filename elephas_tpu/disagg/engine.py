@@ -834,15 +834,6 @@ class DisaggEngine:
             return None
         return int(self.decode._gamma_now)
 
-    @property
-    def kernel(self) -> str:
-        """The decode engine's RESOLVED attention kernel ("gather", or
-        "pallas" when the paged-decode Pallas kernel is actually
-        compiled for this backend — a requested-but-fallen-back engine
-        reports "gather" here and flags ``kernel_requested`` in
-        `/stats`)."""
-        return str(getattr(self.decode, "kernel", "gather"))
-
     # ---------------------------------------------------------------- misc
     def register_prefix(self, tokens) -> None:
         """Register a shared prompt prefix on EVERY prefill worker's

@@ -19,8 +19,9 @@ cost follows the SUM of what the rows hold (bucketed to one of a few
 static widths, picked on the device), not ``batch × table width``: a
 row of 40 positions costs 3 blocks, whatever ``max_len`` is. The
 gathered copy is still one extra pass over the held K/V versus
-streaming the blocks in place (``kernel="pallas"``), and a contiguous
-strip read in place is still the cheapest of all at full occupancy.
+streaming the held blocks in place (no such kernel exists yet), and a
+contiguous strip read in place is still the cheapest of all at full
+occupancy.
 
 Math mirrors :func:`~elephas_tpu.models.transformer.decode_block`
 (S=1) exactly — same norms, RoPE convention, GQA grouping,
@@ -81,8 +82,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import mla as _mla
-from .transformer import (NEG_INF, TransformerConfig, _alibi_slope_list,
-                          _alibi_slopes,
+from .transformer import (NEG_INF, TransformerConfig, _alibi_slopes,
                           _apply_rope, _mlp_sublayer, _norm,
                           _sinusoidal_table, head_logits)
 
@@ -95,6 +95,9 @@ __all__ = ["init_paged_pool", "decode_step_paged", "decode_block_paged",
            "import_kv_blocks", "export_pool_blocks",
            "install_pool_blocks"]
 
+
+#: widths in :func:`held_ladder`: the widest, halved five times
+LADDER_RUNGS = 6
 
 #: blocks in one tile of the ``mla`` step's flat list; a row's held
 #: blocks are padded to whole tiles (at most ``LATENT_TILE_BLOCKS - 1``
@@ -125,15 +128,16 @@ def held_tile(config: TransformerConfig) -> int:
     return LATENT_TILE_BLOCKS if config.attention_kind == "mla" else 1
 
 
-def held_ladder(config: TransformerConfig, rows: int, max_blocks: int,
-                rungs: int = 6) -> Tuple[int, ...]:
+def held_ladder(config: TransformerConfig, rows: int,
+                max_blocks: int) -> Tuple[int, ...]:
     """Widths for :func:`decode_step_paged`'s ``held_blocks``, derived
     from the shapes: ``rows`` x table width (in whole tiles: enough for
-    any rows) halved ``rungs - 1`` times, none narrower than a tile a
-    row (every row holds at least its scratch block)."""
+    any rows) halved ``LADDER_RUNGS - 1`` times, none narrower than a
+    tile a row (every row holds at least its scratch block)."""
     tile = held_tile(config)
     top = rows * (-(-max_blocks // tile) * tile)
-    return tuple(sorted({max(top >> k, rows * tile) for k in range(rungs)}))
+    return tuple(sorted({max(top >> k, rows * tile)
+                         for k in range(LADDER_RUNGS)}))
 
 
 def init_paged_pool(config: TransformerConfig, num_blocks: int,
@@ -505,8 +509,6 @@ def _latent_attention(q, pool, tiles, scale, rank: int):
 def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
                       tokens: jnp.ndarray, pos,
                       config: TransformerConfig,
-                      kernel: str = "gather",
-                      interpret=None,
                       held_blocks: Union[None, int, Sequence[int]] = None,
                       with_stats: bool = False):
     """One autoregressive step over the block pool: token ids ``(B,)``
@@ -515,8 +517,7 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
     pool). The paged mirror of
     :func:`~elephas_tpu.models.transformer.decode_step`.
 
-    ``kernel`` selects the attention inner loop. ``"gather"`` (default,
-    plain XLA) attends over the flat list of blocks the rows hold: a
+    Attention runs over the flat list of blocks the rows hold: a
     row at ``pos`` holds ``pos // block_size + 1`` blocks (under
     ``attention_window`` only those the window touches), the list is
     laid out row after row from ``tables`` and ``pos`` alone, padded to
@@ -527,20 +528,12 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
     widths compiles a branch for each and the step picks, on the
     device, the narrowest that covers what its rows hold (the widest
     has to cover any input it will meet); ``None`` means ``B ×
-    max_blocks``, enough for any input. ``"pallas"`` runs
-    :func:`~elephas_tpu.ops.paged_attention.paged_decode_attention`,
-    which streams each table block from the pool into a flash-style
-    online-softmax kernel (no gathered copy; it walks the whole table
-    and ignores ``held_blocks``). The two agree to float rounding,
-    pinned by the variant-matrix parity tests. ``interpret`` is
-    threaded to the Pallas kernel (tests force the interpreter off-TPU;
-    production callers leave it ``None``).
+    max_blocks``, enough for any input.
 
     ``attention_kind="mla"`` runs the absorbed step over the latent pool
     (module docstring) with the same ladder; its widths count blocks and
     must be multiples of :func:`held_tile`, and the rows' need is
-    :func:`held_block_count` with that ``tile``. ``kernel`` must be
-    ``"gather"`` there.
+    :func:`held_block_count` with that ``tile``.
 
     With ``with_stats`` a third value is returned, None for a model
     without swiglu expert layers, else ``{"counts", "picks"}``: the
@@ -549,16 +542,10 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
     live rows -- those at ``pos`` > 0, which an engine's idle slots are
     not --, picks on held experts, held experts touched, expert layers
     run) and every such layer's picks ``(layers, B, top_k)``."""
-    if kernel not in ("gather", "pallas"):
-        raise ValueError(f"unknown paged decode kernel {kernel!r}; "
-                         "expected 'gather' or 'pallas'")
     c = config
     b = tokens.shape[0]
     bs = _block_size(pool)
     mla = c.attention_kind == "mla"
-    if mla and kernel != "gather":
-        raise ValueError("attention_kind='mla' has no Pallas paged "
-                         "kernel; use kernel='gather'")
     pos = jnp.asarray(pos)
     blk = jnp.take_along_axis(tables, (pos // bs)[:, None],
                               axis=1)[:, 0]        # (B,) owning block
@@ -595,7 +582,7 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
         branches = [attend_over(w) for w in widths]
         pick = jnp.searchsorted(jnp.asarray(widths), held * tile,
                                 side="left")
-    elif kernel == "gather":
+    else:
         widths = _ladder(held_blocks, b * tables.shape[1])
         alibi = c.positional == "alibi"
         held, slots = _held_slots(tables, pos, bs, c.attention_window,
@@ -653,21 +640,10 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
         pv = lc["v"].at[widx].set(v_new[:, :, 0])
         new_pool[f"layer_{i}"] = {"k": pk, "v": pv}
 
-        if kernel == "pallas":
-            # fused path: the kernel's index maps stream each table
-            # block straight from the pool — no gathered copy
-            from ..ops.paged_attention import paged_decode_attention
-            o = paged_decode_attention(
-                q[:, :, 0], pk, pv, tables, pos,
-                window=c.attention_window,
-                alibi_slopes=(_alibi_slope_list(c.num_heads)
-                              if c.positional == "alibi" else None),
-                interpret=interpret)[:, :, None, :]
-        else:
-            qg = q.reshape(b, c.kv_heads, groups, c.head_dim)
-            # (a single width is no branch: lax.switch calls it)
-            o = jax.lax.switch(pick, branches, qg, pk, pv).reshape(
-                b, c.num_heads, 1, c.head_dim)
+        qg = q.reshape(b, c.kv_heads, groups, c.head_dim)
+        # (a single width is no branch: lax.switch calls it)
+        o = jax.lax.switch(pick, branches, qg, pk, pv).reshape(
+            b, c.num_heads, 1, c.head_dim)
         x = x + jnp.einsum("bhsk,hkd->bsd", o,
                            layer["attn"]["wo"].astype(c.dtype))
         x, st = _mlp_sublayer(layer, x, c, i, live=live[:, None])

@@ -539,13 +539,9 @@ def select_attention_impl(config: TransformerConfig, mesh: Optional[Mesh],
     return "xla"
 
 
-def _alibi_slope_list(num_heads: int) -> list:
-    """Per-head geometric slopes (Press et al.) as PYTHON floats: for
-    2^n heads, 2^(-8i/n); other counts interpolate the same way
-    HF/ALiBi do. Kept off-device so callers that bake slopes into a
-    kernel as compile-time constants (the Pallas paged-decode path,
-    which runs inside a jit trace where ``jnp`` ops stage to tracers)
-    can use them directly."""
+def _alibi_slopes(num_heads: int) -> jnp.ndarray:
+    """Per-head geometric slopes (Press et al.): for 2^n heads,
+    2^(-8i/n); other counts interpolate the same way HF/ALiBi do."""
     def pow2_slopes(n):
         start = 2.0 ** (-8.0 / n)
         return [start ** (i + 1) for i in range(n)]
@@ -553,13 +549,8 @@ def _alibi_slope_list(num_heads: int) -> list:
     n = 2 ** math.floor(math.log2(num_heads))
     slopes = pow2_slopes(n)
     if n < num_heads:
-        extra = pow2_slopes(2 * n)[0::2][:num_heads - n]
-        slopes += extra
-    return slopes
-
-
-def _alibi_slopes(num_heads: int) -> jnp.ndarray:
-    return jnp.asarray(_alibi_slope_list(num_heads), jnp.float32)
+        slopes += pow2_slopes(2 * n)[0::2][:num_heads - n]
+    return jnp.asarray(slopes, jnp.float32)
 
 
 def _apply_rope(x, positions, config: "TransformerConfig"):

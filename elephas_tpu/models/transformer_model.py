@@ -496,7 +496,7 @@ class TransformerModel:
                **engine_kwargs):
         """A :class:`~elephas_tpu.serving_engine.DecodeEngine` over this
         model's parameters (continuous batching, prefix caching,
-        multi-step scheduling, paged KV — see the serving guide). Pass
+        paged KV — see the serving guide). Pass
         ``draft=`` for speculative stepping."""
         from ..serving_engine import DecodeEngine
 

@@ -1,5 +1,4 @@
 from .attention import attention, blockwise_attention
-from .paged_attention import paged_decode_attention, pallas_supported
 from .pallas_attention import flash_attention
 from .ring_attention import (ring_attention, ring_attention_sharded,
                              ring_flash_attention)

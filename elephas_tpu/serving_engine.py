@@ -29,8 +29,8 @@ handing over, admitting and uploading run beside the device. Tokens
 are the synchronous loop's, row for row (greedy, seeded, and unseeded
 rows stepped together); what the host cannot know a step ahead (eos, a
 cancel, a deadline, a preemption) costs one surplus token, dropped.
-The speculative and fused (``steps_per_sync > 1``) loops, whose next
-position depends on what a step returns, stay synchronous.
+The speculative loop, whose next position depends on what a round
+returns, stays synchronous.
 For offline batch generation, :func:`generate`'s single fused scan is
 the faster shape.
 
@@ -261,14 +261,6 @@ class DecodeEngine:
         ``generate``); only the number of host steps shrinks.
     :param draft_config: the draft model's config (same vocabulary)
     :param gamma: draft tokens proposed per round (speculative mode)
-    :param steps_per_sync: decode steps fused into each :meth:`step`
-        dispatch (plain mode): one jitted ``lax.scan`` advances every
-        slot by this many tokens per host round trip. Where dispatch
-        latency dominates, throughput scales almost linearly with it;
-        the cost is scheduling granularity —
-        admission/retirement happen every ``steps_per_sync`` tokens, and
-        a slot that hits eos/budget mid-chunk wastes the remainder.
-        Per-slot output is still exactly its solo greedy decode.
     :param prefill_chunk: when set, admission prefills prompts in
         fixed ``prefill_chunk``-token blocks (plus one natural-size
         tail), so jit compilation stops scaling with distinct prompt
@@ -286,7 +278,7 @@ class DecodeEngine:
         the device, from a ladder of widths derived from ``max_slots``
         and the table width; see
         :mod:`~elephas_tpu.models.paged_decode`). Composes with prefix
-        caching, chunked prefill, multi-step, and speculative mode
+        caching, chunked prefill and speculative mode
         (each slot's allocation budgets ``gamma`` extra positions of
         verify slack); not with ``kv_cache_quant`` or MoE.
     :param max_queue: admission bound on the backlog of queued
@@ -360,18 +352,6 @@ class DecodeEngine:
         (its measured cost: ``PERF.md``). Pass ``False`` to disable
         (the bench A/B baseline) or an instance to share one across
         wrappers.
-    :param kernel: paged decode-attention inner loop: ``"gather"``
-        (default, plain XLA — one gather per layer of the flat list of
-        blocks the rows hold, softmax normalised per row across its
-        blocks) or ``"pallas"`` (flash kernel streaming each row's
-        whole table from the pool,
-        :mod:`~elephas_tpu.ops.paged_attention`; TPU only — off-TPU the
-        engine falls back to gather with a ``serving.kernel_fallback``
-        event, and ``stats["kernel"]`` reports what actually runs).
-    :param kernel_interpret: force (``True``) the Pallas interpreter
-        for the ``"pallas"`` kernel, disabling the off-TPU fallback —
-        a test/debug path, orders of magnitude slower than either
-        production path.
     :param adaptive_gamma: steer the speculation depth per engine from
         measured draft acceptance: ``gamma`` becomes the CEILING (all
         capacity/slack accounting stays sized to it, so shrinking is
@@ -406,7 +386,7 @@ class DecodeEngine:
                  temperature: float = 0.0, eos_id: Optional[int] = None,
                  seed: int = 0, draft_params: Optional[Dict] = None,
                  draft_config: Optional[TransformerConfig] = None,
-                 gamma: int = 4, steps_per_sync: int = 1,
+                 gamma: int = 4,
                  prefill_chunk: Optional[int] = None,
                  paged: Optional[Tuple[int, int]] = None,
                  max_queue: Optional[int] = None,
@@ -419,8 +399,6 @@ class DecodeEngine:
                  qos: Optional[TenantQoS] = None,
                  profiler: Union[None, bool, LoopProfiler] = None,
                  kv_spill=None, session_store=None,
-                 kernel: str = "gather",
-                 kernel_interpret: Optional[bool] = None,
                  adaptive_gamma: bool = False, gamma_min: int = 1,
                  interleave_prefill: bool = False):
         self.params = params
@@ -477,9 +455,6 @@ class DecodeEngine:
         # gamma extra positions per slot — the CEILING, under adaptive
         # gamma, so shrinking mid-flight is always safe
         self._slack = self.gamma if draft_config is not None else 0
-        self.steps_per_sync = int(steps_per_sync)
-        if self.steps_per_sync < 1:
-            raise ValueError("steps_per_sync must be >= 1")
         self.prefill_chunk = (None if prefill_chunk is None
                               else int(prefill_chunk))
         if self.prefill_chunk is not None and self.prefill_chunk < 1:
@@ -502,33 +477,6 @@ class DecodeEngine:
             self.paged = (num_blocks, block_size)
             # per-slot table width: enough blocks to cover max_len
             self._mb = -(-self.max_len // block_size)
-        # paged decode-attention kernel selection: "gather" (default)
-        # gathers the blocks the rows hold; "pallas" streams each row's
-        # table into a flash-style online-softmax kernel
-        # (:mod:`~elephas_tpu.ops.paged_attention`). The compiled kernel
-        # needs a TPU: elsewhere the engine FALLS BACK to gather (a
-        # ``serving.kernel_fallback`` event; ``stats["kernel"]`` reports
-        # what actually runs) unless ``kernel_interpret=True`` forces
-        # the Pallas interpreter — a test/debug path, orders of
-        # magnitude slower than either production path.
-        self.kernel_requested = str(kernel)
-        if self.kernel_requested not in ("gather", "pallas"):
-            raise ValueError(f"unknown kernel {kernel!r}; expected "
-                             "'gather' or 'pallas'")
-        if self.kernel_requested == "pallas" and self.paged is None:
-            raise ValueError("kernel='pallas' is the paged decode-"
-                             "attention kernel; it requires "
-                             "paged=(num_blocks, block_size)")
-        self._kernel_interpret = kernel_interpret
-        self.kernel = self.kernel_requested
-        if self.kernel == "pallas" and not kernel_interpret:
-            from .ops.paged_attention import pallas_supported
-
-            if not pallas_supported():
-                self.kernel = "gather"
-                emit_event("serving.kernel_fallback",
-                           requested="pallas",
-                           backend=jax.default_backend())
         # chunked-prefill interleaving (ctor docstring): pending
         # admissions whose prompt is still being fed chunk-by-chunk
         # between decode steps. slot -> state dict (see
@@ -544,10 +492,6 @@ class DecodeEngine:
         # per-step budget, so it is cached, not read per step)
         self._prefill_budget = 1
         self._budget_age = 0
-        if self.steps_per_sync > 1 and draft_config is not None:
-            raise ValueError("steps_per_sync > 1 applies to plain "
-                             "stepping; speculative mode already "
-                             "amortizes dispatches via draft rounds")
         self._key = jax.random.PRNGKey(seed)
         if self.paged is not None:
             from .models.paged_decode import init_paged_pool
@@ -842,20 +786,17 @@ class DecodeEngine:
         self._accept: Dict[int, List[int]] = {}
         if self.paged is not None:
             # widths of the decode step's flat block list, derived from
-            # the shapes (at most 6). The Pallas kernel walks whole
-            # tables: one width
+            # the shapes
             from .models.paged_decode import held_ladder, held_tile
 
             # (a latent pool's rows take whole tiles of the list)
             self._held_tile = held_tile(config)
-            self._held_ladder = held_ladder(
-                config, self.max_slots, self._mb,
-                rungs=1 if self.kernel == "pallas" else 6)
+            self._held_ladder = held_ladder(config, self.max_slots,
+                                            self._mb)
             self._m_blocks_held = reg.counter(
                 "serving_decode_blocks_held_total",
                 "KV blocks the batch's rows held, summed over decode "
-                "dispatches (a fused dispatch counts its first step)"
-                ).labels()
+                "dispatches").labels()
             self._m_blocks_read = reg.counter(
                 "serving_decode_blocks_read_total",
                 "KV blocks the decode program gathered per layer (the "
@@ -967,7 +908,7 @@ class DecodeEngine:
             # batched step — all branches are computed and where() picks
             # per row, one sort + categorical over (B, V), noise next to
             # the model forward. THE sampling body: every step variant
-            # (plain/fused, contiguous/paged) calls it, so modes cannot
+            # (contiguous/paged) calls it, so modes cannot
             # drift. Order matches generate: temperature scales first,
             # THEN the nucleus is chosen on the scaled logits
             key, sub = jax.random.split(key)
@@ -1022,33 +963,9 @@ class DecodeEngine:
             return _one_step(params, cache, _ride(last, prev), pos,
                              temps, topk, topp, seeds, key)
 
-        n_sync = self.steps_per_sync
-
-        @partial(jax.jit, donate_argnums=(1,))
-        def _multi_step(params, cache, last, pos, temps, topk, topp,
-                        seeds, key):
-            # steps_per_sync decode steps in one lax.scan: each slot's
-            # chain stays autoregressive (its sampled token feeds the
-            # next step), so per-slot output is exactly the solo decode;
-            # only the host's admission/retirement granularity changes.
-            # Slots that retire mid-chunk keep decoding; the host
-            # discards their surplus tokens, and their surplus cache
-            # writes land in a freed row (dead until the next prefill)
-            def body(carry, _):
-                cache, last, pos, key = carry
-                tok, cache, key = _one_step(params, cache, last, pos,
-                                            temps, topk, topp, seeds,
-                                            key)
-                return (cache, tok, pos + 1, key), tok
-
-            (cache, _, _, key), toks = jax.lax.scan(
-                body, (cache, last, pos, key), None, length=n_sync)
-            return jnp.swapaxes(toks, 0, 1), cache, key   # (B, K)
-
         if self.paged is not None:
             from .models.paged_decode import decode_step_paged
 
-            kern, kern_interp = self.kernel, self._kernel_interpret
             # one program holds a branch per ladder width and picks
             # among them on the device, from the positions it is given
             ladder = self._held_ladder
@@ -1056,9 +973,8 @@ class DecodeEngine:
             def _one_step_paged(params, pool, tables, last, pos, temps,
                                 topk, topp, seeds, key):
                 logits, pool, stats = decode_step_paged(
-                    params, pool, tables, last, pos, cfg, kernel=kern,
-                    interpret=kern_interp, held_blocks=ladder,
-                    with_stats=True)
+                    params, pool, tables, last, pos, cfg,
+                    held_blocks=ladder, with_stats=True)
                 tok, key = _sample_tok(logits, temps, topk, topp, seeds,
                                        pos, key)
                 # a model with routing counts sends them home behind the
@@ -1078,22 +994,7 @@ class DecodeEngine:
                 # leaves the device
                 return home, home[:last.shape[0]], pool, key
 
-            @partial(jax.jit, donate_argnums=(1,))
-            def _multi_step_paged(params, pool, tables, last, pos, temps,
-                                  topk, topp, seeds, key):
-                def body(carry, _):
-                    pool, last, pos, key = carry
-                    tok, pool, key = _one_step_paged(
-                        params, pool, tables, last, pos, temps, topk,
-                        topp, seeds, key)
-                    return (pool, tok[:last.shape[0]], pos + 1, key), tok
-
-                (pool, _, _, key), toks = jax.lax.scan(
-                    body, (pool, last, pos, key), None, length=n_sync)
-                return jnp.swapaxes(toks, 0, 1), pool, key
-
             self._step_paged_fn = _step_paged
-            self._multi_step_paged_fn = _multi_step_paged
 
         @partial(jax.jit, donate_argnums=(0,))
         def _install(cache, row_cache, slot):
@@ -1130,7 +1031,6 @@ class DecodeEngine:
             return jax.jit(_extend)
 
         self._step_fn = _step
-        self._multi_step_fn = _multi_step
         self._install_fn = _install
         self._prefill_fn = _prefill
         self._extend_fn = _make_extend(cfg)
@@ -1276,7 +1176,7 @@ class DecodeEngine:
     # ------------------------------------------------------------ warmup
     def warmup(self, prompt_lengths: Sequence[int] = ()):
         """Compile the hot programs BEFORE traffic arrives: the decode
-        step (plain or fused, paged or contiguous) plus, for each
+        step (paged or contiguous) plus, for each
         length in ``prompt_lengths``, the admission prefill path exactly
         as a real admission runs it (chunked block shapes when
         ``prefill_chunk`` is set, whole-prompt prefill otherwise) and
@@ -1294,9 +1194,6 @@ class DecodeEngine:
                      topp=jnp.asarray(self._topp),
                      seeds=jnp.asarray(self._slot_seed),
                      key=jax.random.PRNGKey(0))
-        # the plain step also takes the tokens of the step before it
-        lasts = ((dummy["last"],) if self.steps_per_sync > 1
-                 else (dummy["last"], dummy["last"]))
         # the step fns donate the cache argument, so warming on the
         # engine's OWN cache (idle: every slot free, paged writes land
         # on scratch block 0) costs zero extra device memory — an
@@ -1308,12 +1205,12 @@ class DecodeEngine:
                 dummy["last"], dummy["pos"], dummy["key"])
             self.pool, self.draft_cache = out[3], out[4]
         elif self.paged is not None:
-            fn = (self._multi_step_paged_fn if self.steps_per_sync > 1
-                  else self._step_paged_fn)
-            self.pool = fn(
+            # (the plain step also takes the tokens of the step before it)
+            self.pool = self._step_paged_fn(
                 self.params, self.pool, jnp.asarray(self._tables),
-                *lasts, dummy["pos"], dummy["temps"], dummy["topk"],
-                dummy["topp"], dummy["seeds"], dummy["key"])[-2]
+                dummy["last"], dummy["last"], dummy["pos"], dummy["temps"],
+                dummy["topk"], dummy["topp"], dummy["seeds"],
+                dummy["key"])[-2]
         elif self.draft_config is not None:
             out = self._spec_step_for(self._gamma_now)(
                 self.params, self.draft_params, self.cache,
@@ -1321,11 +1218,9 @@ class DecodeEngine:
                 dummy["key"])
             self.cache, self.draft_cache = out[3], out[4]
         else:
-            fn = (self._multi_step_fn if self.steps_per_sync > 1
-                  else self._step_fn)
-            _, self.cache, _ = fn(
-                self.params, self.cache, *lasts, dummy["pos"],
-                dummy["temps"], dummy["topk"], dummy["topp"],
+            _, self.cache, _ = self._step_fn(
+                self.params, self.cache, dummy["last"], dummy["last"],
+                dummy["pos"], dummy["temps"], dummy["topk"], dummy["topp"],
                 dummy["seeds"], dummy["key"])
         for length in sorted(set(int(n) for n in prompt_lengths)):
             if not 1 <= length < self.max_len:
@@ -4308,11 +4203,10 @@ class DecodeEngine:
             # has steered down (the gap IS the staleness signal)
             out["gamma"] = int(self._gamma_now)
             out["gamma_ceiling"] = int(self.gamma)
-        # resolved attention kernel ("pallas" only when it will really
-        # run compiled; a fallback shows requested != kernel here)
-        out["kernel"] = self.kernel
-        if self.kernel != self.kernel_requested:
-            out["kernel_requested"] = self.kernel_requested
+        # the one paged decode-attention path there is. The key stays
+        # because both benchmark drivers log it (chipbench/drivers/
+        # serve.py, serve_routed.py); it goes when they stop (ROADMAP D9)
+        out["kernel"] = "gather"
         if self.interleave_prefill:
             out["prefill_chunks_interleaved"] = int(
                 self._since_init(self._m_interleaved))
@@ -4440,90 +4334,63 @@ class DecodeEngine:
         active = np.asarray([r is not None for r in self._rid])
         if not active.any():
             return emitted
-        if self.draft_config is None and self.steps_per_sync == 1:
+        # one choice, made from what the engine knows of itself
+        if self.draft_config is None:
             return self._step_plain(active, emitted)
+        return self._step_speculative(active, emitted)
+
+    def _step_speculative(self, active: np.ndarray,
+                          emitted: Dict[int, List[int]]
+                          ) -> Dict[int, List[int]]:
+        """One draft-propose / target-verify round: every active slot
+        advances by its own ``1 + accepted`` tokens in one dispatch. The
+        round is synchronous (a row's next position is what the round
+        returns). It runs at the adaptive operating depth (``self.gamma``
+        on fixed-gamma engines); verify slack was budgeted at the
+        ceiling, so any depth up to it writes safely."""
         # inactive slots decode garbage at position 0 (static batch
         # shape); their writes are overwritten by the next admission's
         # prefill and masked until then
         pos = np.where(active, self._pos + 1, 0).astype(np.int32)
         self._m_steps.inc()
-        if self.draft_config is not None:
-            # speculative round: every active slot advances by its own
-            # 1 + accepted tokens in one dispatch. The round runs at the
-            # adaptive operating depth (== self.gamma for fixed-gamma
-            # engines); verify slack was budgeted at the ceiling, so any
-            # depth <= it writes safely
-            g_now = self._gamma_now
-            with self._psec("elephas.loop.decode.dispatch"):
-                if self.paged is not None:
-                    (emit, acc, nxt, self.pool, self.draft_cache,
-                     self._key) = self._spec_step_paged_for(g_now)(
-                        self.params, self.draft_params, self.pool,
-                        self.draft_cache, jnp.asarray(self._tables),
-                        jnp.asarray(self._last), jnp.asarray(pos),
-                        self._key)
-                else:
-                    (emit, acc, nxt, self.cache, self.draft_cache,
-                     self._key) = self._spec_step_for(g_now)(
-                        self.params, self.draft_params, self.cache,
-                        self.draft_cache, jnp.asarray(self._last),
-                        jnp.asarray(pos), self._key)
-            with self._psec("elephas.loop.decode.wait"):
-                emit, acc, nxt = (np.asarray(emit), np.asarray(acc),
-                                  np.asarray(nxt))
-            n_active = int(active.sum())
-            n_accepted = int(acc[active].sum())
-            self._m_accepted.inc(n_accepted)
-            self._m_proposed.inc(g_now * n_active)
-            self._m_spec_rounds.inc(n_active)
-            if self.adaptive_gamma:
-                self._steer_gamma(n_accepted, g_now * n_active)
-            with self._psec("elephas.loop.emit"):
-                for slot in np.nonzero(active)[0]:
-                    rid = self._rid[slot]
-                    # per-request acceptance for the flight recorder's
-                    # terminal event (engine counters above are pooled)
-                    a_p = self._accept.setdefault(rid, [0, 0])
-                    a_p[0] += int(acc[slot])
-                    a_p[1] += g_now
-                    self._pos[slot] += 1 + acc[slot]
-                    self._last[slot] = nxt[slot]
-                    for tok in emit[slot, :acc[slot] + 1]:
-                        if self._rid[slot] is None:
-                            break   # retired mid-chunk (eos or budget)
-                        if self._record(slot, int(tok)):
-                            emitted.setdefault(rid, []).append(int(tok))
-            self._admit()
-            return emitted
-        # steps_per_sync > 1: the fused steps of one dispatch
+        g_now = self._gamma_now
         with self._psec("elephas.loop.decode.dispatch"):
             if self.paged is not None:
-                self._count_held(pos)
-                toks, self.pool, self._key = \
-                    self._multi_step_paged_fn(
-                        self.params, self.pool,
-                        jnp.asarray(self._tables),
-                        jnp.asarray(self._last), jnp.asarray(pos),
-                        jnp.asarray(self._temp),
-                        jnp.asarray(self._topk),
-                        jnp.asarray(self._topp),
-                        jnp.asarray(self._slot_seed), self._key)
+                (emit, acc, nxt, self.pool, self.draft_cache,
+                 self._key) = self._spec_step_paged_for(g_now)(
+                    self.params, self.draft_params, self.pool,
+                    self.draft_cache, jnp.asarray(self._tables),
+                    jnp.asarray(self._last), jnp.asarray(pos),
+                    self._key)
             else:
-                toks, self.cache, self._key = self._multi_step_fn(
-                    self.params, self.cache, jnp.asarray(self._last),
-                    jnp.asarray(pos), jnp.asarray(self._temp),
-                    jnp.asarray(self._topk), jnp.asarray(self._topp),
-                    jnp.asarray(self._slot_seed), self._key)
+                (emit, acc, nxt, self.cache, self.draft_cache,
+                 self._key) = self._spec_step_for(g_now)(
+                    self.params, self.draft_params, self.cache,
+                    self.draft_cache, jnp.asarray(self._last),
+                    jnp.asarray(pos), self._key)
         with self._psec("elephas.loop.decode.wait"):
-            toks = self._split_counts(np.asarray(toks))   # (B, K)
+            emit, acc, nxt = (np.asarray(emit), np.asarray(acc),
+                              np.asarray(nxt))
+        n_active = int(active.sum())
+        n_accepted = int(acc[active].sum())
+        self._m_accepted.inc(n_accepted)
+        self._m_proposed.inc(g_now * n_active)
+        self._m_spec_rounds.inc(n_active)
+        if self.adaptive_gamma:
+            self._steer_gamma(n_accepted, g_now * n_active)
         with self._psec("elephas.loop.emit"):
             for slot in np.nonzero(active)[0]:
                 rid = self._rid[slot]
-                for tok in toks[slot]:
+                # per-request acceptance for the flight recorder's
+                # terminal event (engine counters above are pooled)
+                a_p = self._accept.setdefault(rid, [0, 0])
+                a_p[0] += int(acc[slot])
+                a_p[1] += g_now
+                self._pos[slot] += 1 + acc[slot]
+                self._last[slot] = nxt[slot]
+                for tok in emit[slot, :acc[slot] + 1]:
                     if self._rid[slot] is None:
-                        break   # retired mid-chunk — surplus dropped
-                    self._pos[slot] += 1
-                    self._last[slot] = tok
+                        break   # retired mid-chunk (eos or budget)
                     if self._record(slot, int(tok)):
                         emitted.setdefault(rid, []).append(int(tok))
         self._admit()
@@ -4602,7 +4469,7 @@ class DecodeEngine:
             # copies: an upload reads its host array when the transfer
             # runs, not when it is enqueued (seen on the TPU), and the
             # next admission writes these arrays with this step in
-            # flight; the synchronous loops wait for their step first
+            # flight; the speculative loop waits for its round first
             args = (last, last if flight is None else flight.tokens,
                     jnp.asarray(pos), jnp.asarray(self._temp.copy()),
                     jnp.asarray(self._topk.copy()),
@@ -4637,13 +4504,12 @@ class DecodeEngine:
             self._ahead = None
 
     def _split_counts(self, toks: np.ndarray) -> np.ndarray:
-        """A step's tokens, ``(max_slots,)`` or ``(max_slots, K)``; what
-        a paged step sent behind them (its routing counts, one per
-        counter and fused step) goes to the counters."""
+        """A step's tokens, ``(max_slots,)``; what a paged step sent
+        behind them (its routing counts, one per counter) goes to the
+        counters."""
         if toks.shape[0] > self.max_slots:
-            counts = toks[self.max_slots:]
-            for metric, value in zip(self._m_moe, counts):
-                metric.inc(int(np.sum(value)))
+            for metric, value in zip(self._m_moe, toks[self.max_slots:]):
+                metric.inc(int(value))
             toks = toks[:self.max_slots]
         return toks
 

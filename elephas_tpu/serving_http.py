@@ -158,7 +158,7 @@ class ServingServer:
     HTTP.
 
     :param engine: a constructed engine (any configuration — prefix
-        caching, multi-step, paged, speculative, and their compositions
+        caching, paged, speculative, and their compositions
         all work; per-request sampling fields are rejected by the
         engine in speculative mode).
     :param host, port: bind address (port 0 picks a free port; see

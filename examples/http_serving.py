@@ -40,14 +40,11 @@ def post(port, path, payload):
     return json.loads(urllib.request.urlopen(req, timeout=120).read())
 
 
-# steps_per_sync trades admission granularity for fewer host round
-# trips — the right setting when dispatch latency dominates (see the
-# serving guide); prefix caching pins the shared "system prompt"
-# ...and the paged block pool holds HALF the contiguous cache's
-# positions (4 slots x 96 = 384 vs 23 allocatable blocks x 8 = 184):
-# admission queues when the pool runs dry, blocks recycle on retirement
-engine = DecodeEngine(params, config, max_slots=4, steps_per_sync=2,
-                      paged=(24, 8))
+# prefix caching pins the shared "system prompt", and the paged block
+# pool holds HALF the contiguous cache's positions (4 slots x 96 = 384
+# vs 23 allocatable blocks x 8 = 184): admission queues when the pool
+# runs dry, blocks recycle on retirement
+engine = DecodeEngine(params, config, max_slots=4, paged=(24, 8))
 system = tok.encode("SYSTEM: ")
 engine.register_prefix(system)
 

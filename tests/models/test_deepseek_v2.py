@@ -330,11 +330,6 @@ def test_what_has_no_latent_form_says_so(model):
         paged_decode.decode_block_paged(
             params, pool, jnp.zeros((1, 12), jnp.int32),
             jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32), cfg)
-    with pytest.raises(ValueError, match="Pallas"):
-        paged_decode.decode_step_paged(
-            params, pool, jnp.zeros((1, 12), jnp.int32),
-            jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32), cfg,
-            kernel="pallas")
 
 
 def test_config_refuses_what_does_not_fit_together():

@@ -161,10 +161,11 @@ def test_ladder_comes_from_the_shapes(model):
                         paged=(9, 4))._held_ladder == (3, 6)
 
 
-@pytest.mark.parametrize("steps_per_sync", [1, 3])
-def test_engine_crosses_widths_without_compiling(model, steps_per_sync):
+@pytest.mark.parametrize("prefill_chunk", [None, 4])
+def test_engine_crosses_widths_without_compiling(model, prefill_chunk):
     """Rows that grow across two ladder widths: the contiguous engine's
-    tokens, no compile after ``warmup()``, and counters that add up."""
+    tokens, no compile after ``warmup()``, and counters that add up
+    (with ``prefill_chunk``: the benchmark's own configuration)."""
     params, config = model
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, 64, n) for n in (3, 9, 5, 14)]
@@ -172,7 +173,7 @@ def test_engine_crosses_widths_without_compiling(model, steps_per_sync):
     expected = plain.run(prompts, max_new_tokens=30)
 
     eng = DecodeEngine(params, config, max_slots=4, max_len=64,
-                       paged=(64, 4), steps_per_sync=steps_per_sync)
+                       paged=(64, 4), prefill_chunk=prefill_chunk)
     eng.warmup(prompt_lengths=sorted({len(p) for p in prompts}))
     reg = eng.registry
     compiles = reg.get("serving_jit_compiles_total").value

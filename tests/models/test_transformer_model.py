@@ -136,7 +136,7 @@ def test_save_and_load_through_tpu_model(tmp_path):
 
 
 #: the zero-optimizer model-surface tests hit the same environment-bound
-#: XLA donation rejection as test_transformer.py's
+#: XLA donation rejection as test_transformer_sharding.py's
 #: test_zero_optimizer_sharding_saves_memory_and_matches (q.v. for the
 #: full rationale): 'INTERNAL: Expected aliased input ... to have the
 #: same size' from this jaxlib's CPU runtime when a donated replicated

@@ -1,8 +1,7 @@
-"""Adaptive engine scheduling: chunked-prefill interleaving,
-acceptance-steered speculative gamma, and the Pallas paged-decode
-kernel.
+"""Adaptive engine scheduling: chunked-prefill interleaving and
+acceptance-steered speculative gamma.
 
-Three invariants carry every test here:
+Two invariants carry every test here:
 
 * Interleaving only reorders WHEN admission prefill chunks run — each
   chunk replays the exact ``chunked_blocks`` program at the exact
@@ -14,10 +13,6 @@ Three invariants carry every test here:
   prefix at ANY draft depth, so the adaptive controller may move gamma
   freely without touching tokens — staleness is a throughput event,
   never a correctness event.
-* The Pallas kernel is the same attention math as the gather path with
-  the reduction re-associated (online softmax), so greedy tokens match
-  across the whole attention-variant matrix; off-TPU the engine falls
-  back to gather rather than eating the interpreter.
 """
 import jax
 import jax.numpy as jnp
@@ -33,8 +28,8 @@ from elephas_tpu.serving_qos import TenantQoS
 
 def _config(**overrides):
     # f32: every parity oracle below compares argmax tokens across
-    # DIFFERENT compiled programs (chunked vs fused prefill, pallas vs
-    # gather) — the standard cross-program near-tie caveat
+    # DIFFERENT compiled programs (chunked vs fused prefill) — the
+    # standard cross-program near-tie caveat
     base = dict(vocab_size=64, num_layers=2, num_heads=4, d_model=32,
                 d_ff=64, max_seq_len=64, dtype=jnp.float32)
     base.update(overrides)
@@ -283,101 +278,6 @@ def test_gamma_min_bounds(model):
         DecodeEngine(params, config, max_slots=1, draft_params=draft,
                      draft_config=dcfg, gamma=3, adaptive_gamma=True,
                      gamma_min=5)
-
-
-# ----------------------------------------------- pallas paged kernel
-_VARIANTS = {
-    "base": {},
-    "gqa": {"num_kv_heads": 2},
-    "window": {"attention_window": 16},
-    "alibi": {"positional": "alibi"},
-    "sinusoidal": {"positional": "sinusoidal"},
-}
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("variant", sorted(_VARIANTS))
-def test_pallas_parity_attention_variants(variant):
-    """Engine-level parity across the attention-variant matrix at
-    RAGGED per-row positions (mixed prompt lengths, staggered
-    admission): the fused-gather Pallas kernel (interpreter off-TPU)
-    emits the gather path's exact greedy tokens."""
-    config = _config(num_layers=1, max_seq_len=48, **_VARIANTS[variant])
-    params = init_params(config, jax.random.PRNGKey(2))
-    rng = np.random.default_rng(50)
-    prompts = [rng.integers(0, 64, int(n)).tolist()
-               for n in (3, 9, 14, 6)]
-
-    def run(kernel, interpret=None):
-        eng = DecodeEngine(params, config, max_slots=2, paged=(24, 8),
-                           kernel=kernel, kernel_interpret=interpret)
-        rids = [eng.submit(p, 8) for p in prompts]
-        _drain(eng)
-        return [eng.result(r) for r in rids]
-
-    gather = run("gather")
-    pallas = run("pallas", interpret=True)
-    assert pallas == gather
-    for p, o in zip(prompts, gather):
-        assert o == _ref(params, config, p, 8)
-
-
-def test_pallas_ops_parity_random_tables():
-    """Kernel-contract parity straight at the op: a shuffled block
-    table per row (blocks deliberately NOT in pool order), ragged
-    positions, GQA — the fused gather must match the materialized
-    ``pool[tables]`` softmax reference to float tolerance."""
-    from elephas_tpu.ops.paged_attention import paged_decode_attention
-    rng = np.random.default_rng(3)
-    b, h, kvh, d, bs, mb, nb = 3, 4, 2, 16, 8, 4, 16
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((nb, kvh, bs, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((nb, kvh, bs, d)), jnp.float32)
-    ids = rng.permutation(np.arange(1, nb))[:b * mb].reshape(b, mb)
-    pos = np.asarray([2, 13, 27])
-
-    out = np.asarray(paged_decode_attention(
-        q, kp, vp, jnp.asarray(ids), jnp.asarray(pos), interpret=True))
-
-    kg = (np.asarray(kp)[ids].transpose(0, 2, 1, 3, 4)
-          .reshape(b, kvh, -1, d))
-    vg = (np.asarray(vp)[ids].transpose(0, 2, 1, 3, 4)
-          .reshape(b, kvh, -1, d))
-    qn = np.asarray(q).reshape(b, kvh, h // kvh, d)
-    s = np.einsum("bngd,bnkd->bngk", qn, kg) / np.sqrt(d)
-    mask = np.arange(mb * bs)[None, :] <= pos[:, None]
-    s = np.where(mask[:, None, None, :], s, -1e30)
-    p = np.exp(s - s.max(-1, keepdims=True))
-    p /= p.sum(-1, keepdims=True)
-    ref = np.einsum("bngk,bnkd->bngd", p, vg).reshape(b, h, d)
-    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
-
-
-def test_pallas_falls_back_to_gather_off_tpu(model):
-    """``kernel="pallas"`` on a host without a TPU serves via the
-    gather path (never the interpreter), reports both the effective
-    and the requested kernel, and still emits exact tokens."""
-    from elephas_tpu.ops.paged_attention import pallas_supported
-    params, config, _, _ = model
-    if pallas_supported():
-        pytest.skip("TPU present: no fallback to observe")
-    eng = DecodeEngine(params, config, max_slots=2, paged=(16, 8),
-                       kernel="pallas")
-    assert eng.kernel == "gather"
-    assert eng.stats["kernel"] == "gather"
-    assert eng.stats["kernel_requested"] == "pallas"
-    p = _prompt(40, 6)
-    r = eng.submit(p, 8)
-    _drain(eng)
-    assert eng.result(r) == _ref(params, config, p, 8)
-
-
-def test_pallas_requires_paged(model):
-    params, config, _, _ = model
-    with pytest.raises(ValueError, match="paged"):
-        DecodeEngine(params, config, max_slots=1, kernel="pallas")
-    with pytest.raises(ValueError, match="kernel"):
-        DecodeEngine(params, config, max_slots=1, kernel="flash")
 
 
 # ------------------------------------------------------- obs surfaces

@@ -81,9 +81,9 @@ def test_paged_request_larger_than_pool_rejected(model):
         eng.submit(np.zeros(20, np.int32), 20)
 
 
-def test_paged_composes_with_prefix_multistep_chunked(model):
-    """paged x prefix caching x multi-step x chunked prefill — the full
-    serving stack in one engine, still token-exact."""
+def test_paged_composes_with_prefix_chunked(model):
+    """paged x prefix caching x chunked prefill — the full serving
+    stack in one engine, still token-exact."""
     params, config = model
     rng = np.random.default_rng(42)
     prefix = list(rng.integers(0, 64, 6))
@@ -91,7 +91,7 @@ def test_paged_composes_with_prefix_multistep_chunked(model):
                for n in (2, 5, 8)]
     prompts.append(rng.integers(0, 64, 4))
     eng = DecodeEngine(params, config, max_slots=2, paged=(24, 8),
-                       steps_per_sync=3, prefill_chunk=5)
+                       prefill_chunk=5)
     eng.register_prefix(prefix)
     outs = eng.run(prompts, max_new_tokens=8)
     for p, o in zip(prompts, outs):
@@ -100,20 +100,28 @@ def test_paged_composes_with_prefix_multistep_chunked(model):
     assert eng.stats["blocks_free"] == eng.stats["blocks_total"]
 
 
-def test_paged_window_and_alibi_variants():
-    """Masking variants flow through the paged gather identically."""
-    for overrides in ({"attention_window": 6},
-                      {"positional": "alibi"},
-                      {"positional": "rope"},
-                      {"positional": "sinusoidal"},
-                      {"num_kv_heads": 2}):
-        config = _config(**overrides)
-        params = init_params(config, jax.random.PRNGKey(1))
-        rng = np.random.default_rng(43)
-        prompt = rng.integers(0, 64, 7)
-        eng = DecodeEngine(params, config, max_slots=2, paged=(16, 8))
-        [out] = eng.run([prompt], max_new_tokens=8)
-        assert out == _ref(params, config, prompt, 8), overrides
+_VARIANTS = {
+    "window": {"attention_window": 6},
+    "alibi": {"positional": "alibi"},
+    "rope": {"positional": "rope"},
+    "sinusoidal": {"positional": "sinusoidal"},
+    "gqa": {"num_kv_heads": 2},
+}
+
+
+@pytest.mark.parametrize("variant", sorted(_VARIANTS))
+def test_paged_window_and_alibi_variants(variant):
+    """Masking variants flow through the paged gather identically, at
+    RAGGED per-row positions: four prompts of mixed lengths through two
+    slots, so rows sit at different positions and join mid-flight."""
+    config = _config(**_VARIANTS[variant])
+    params = init_params(config, jax.random.PRNGKey(1))
+    rng = np.random.default_rng(43)
+    prompts = [rng.integers(0, 64, n) for n in (3, 9, 14, 6)]
+    eng = DecodeEngine(params, config, max_slots=2, paged=(24, 8))
+    outs = eng.run(prompts, max_new_tokens=8)
+    for p, o in zip(prompts, outs):
+        assert o == _ref(params, config, p, 8)
 
 
 def test_paged_eos_returns_blocks_early(model):

@@ -367,68 +367,12 @@ def test_prefix_cache_speculative_mode(model):
     assert eng.stats["prefix_hits"] == 2
 
 
-# ------------------------------------------------------- multi-step sync
-
-def test_multi_step_parity_mixed_lengths(model):
-    """steps_per_sync=3: 7 requests through 2 slots, mixed prompt
-    lengths and max_new not divisible by the chunk — every output must
-    still equal its solo greedy decode (chunks only change host
-    scheduling granularity, never the per-slot chain)."""
-    params, config = model
-    rng = np.random.default_rng(11)
-    prompts = [rng.integers(0, 64, int(n))
-               for n in rng.integers(3, 12, size=7)]
-    eng = DecodeEngine(params, config, max_slots=2, steps_per_sync=3)
-    outs = eng.run(prompts, max_new_tokens=10)
-    for p, o in zip(prompts, outs):
-        assert o == _ref(params, config, p, 10)
-    # 10 tokens per request at 3/dispatch: strictly fewer device round
-    # trips than tokens emitted
-    assert eng.stats["steps"] < eng.stats["tokens_emitted"] / 2
-
-
-def test_multi_step_eos_mid_chunk(model):
-    """A slot hitting eos inside a chunk retires there; surplus chunk
-    tokens are discarded, output ≡ solo decode with the same eos."""
-    params, config = model
-    rng = np.random.default_rng(12)
-    prompt = rng.integers(0, 64, 6)
-    full = _ref(params, config, prompt, 12)
-    eos = full[5]                     # force an eos mid-generation
-    want = full[:full.index(eos)]
-    eng = DecodeEngine(params, config, max_slots=2, steps_per_sync=4,
-                       eos_id=eos)
-    [out] = eng.run([prompt], max_new_tokens=12)
-    assert out == want
-
-
-def test_multi_step_composes_with_prefix_cache(model):
-    params, config = model
-    rng = np.random.default_rng(13)
-    prefix = list(rng.integers(0, 64, 5))
-    prompts = [np.asarray(prefix + list(rng.integers(0, 64, int(n))))
-               for n in (2, 4, 6)]
-    eng = DecodeEngine(params, config, max_slots=2, steps_per_sync=4)
-    eng.register_prefix(prefix)
-    outs = eng.run(prompts, max_new_tokens=9)
-    for p, o in zip(prompts, outs):
-        assert o == _ref(params, config, p, 9)
-    assert eng.stats["prefix_hits"] == 3
-
-
-def test_multi_step_rejects_speculative(model):
-    params, config = model
-    with pytest.raises(ValueError, match="steps_per_sync"):
-        DecodeEngine(params, config, draft_params=params,
-                     draft_config=config, steps_per_sync=2)
-
-
 # ---------------------------------------------------- TP-sharded params
 
 def test_engine_with_tp_sharded_params():
     """DecodeEngine with tensor-parallel GSPMD-sharded params (2x2
     data x model mesh) must emit exactly the unsharded engine's tokens —
-    prefix caching and multi-step included. Pins the docstring's
+    prefix caching included. Pins the docstring's
     'replicated or GSPMD-sharded' params claim for the engine."""
     from jax.sharding import Mesh
 
@@ -442,7 +386,7 @@ def test_engine_with_tp_sharded_params():
                for n in (3, 6, 4)]
 
     def run(p):
-        eng = DecodeEngine(p, config, max_slots=2, steps_per_sync=3)
+        eng = DecodeEngine(p, config, max_slots=2)
         eng.register_prefix(prefix)
         return eng.run(prompts, max_new_tokens=8)
 
@@ -604,22 +548,22 @@ def test_warmup_precompiles_all_traffic_shapes(model):
         eng.warmup((4,))
 
 
-def test_warmup_paged_multistep(model):
+def test_warmup_paged(model):
     params, config = model
     rng = np.random.default_rng(51)
-    eng = DecodeEngine(params, config, max_slots=2, steps_per_sync=3,
-                       paged=(16, 8), prefill_chunk=4)
+    eng = DecodeEngine(params, config, max_slots=2, paged=(16, 8),
+                       prefill_chunk=4)
     eng.warmup(prompt_lengths=(5, 9))
     n_ext = (eng._extend_owned_fn._cache_size()
              + eng._extend_fn._cache_size())
-    n_step = eng._multi_step_paged_fn._cache_size()
+    n_step = eng._step_paged_fn._cache_size()
     prompts = [rng.integers(0, 64, 5), rng.integers(0, 64, 9)]
     outs = eng.run(prompts, max_new_tokens=7)
     for p, o in zip(prompts, outs):
         assert o == _ref(params, config, p, 7)
     assert (eng._extend_owned_fn._cache_size()
             + eng._extend_fn._cache_size()) == n_ext
-    assert eng._multi_step_paged_fn._cache_size() == n_step
+    assert eng._step_paged_fn._cache_size() == n_step
 
 
 def test_latency_stats(model):
@@ -691,23 +635,27 @@ def test_one_ahead_parity_joining_and_leaving_mid_flight(model, kind,
     assert eng.stats["surplus_rows"] == 0
 
 
-def test_one_ahead_unseeded_rows_sample_as_the_fused_loop_does(model,
-                                                               kind):
+def test_one_ahead_unseeded_rows_sample_alike_whoever_leaves_mid_flight(
+        model, kind):
     """Unseeded sampled rows draw from the engine key, which travels
-    device to device: with rows leaving mid-flight the one-ahead loop
-    splits it exactly as often as the synchronous fused loop
-    (``steps_per_sync=2``, the parent's program) does."""
+    device to device and is split once a step: a row's draw depends on
+    the number of splits, its slot and its own logits only. So rows
+    that leave mid-flight (budgets 4, 9, 7) sample the prefixes of what
+    they sample when every budget is equal, no row leaves before the
+    others and nothing is dispatched ahead for a row that is gone."""
     params, config = model
-    prompts, budgets = _prompts(37, 5, 6, 6), [4, 9, 7]
+    prompts = _prompts(37, 5, 6, 6)
     outs = []
-    for extra in ({}, {"steps_per_sync": 2}):
+    for budgets in ([4, 9, 7], [9, 9, 9]):
         eng = DecodeEngine(params, config, max_slots=3, temperature=0.8,
-                           seed=5, **kind, **extra)
+                           seed=5, **kind)
         rids = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
         _drain(eng)
         outs.append([eng.result(r) for r in rids])
-    assert outs[0] == outs[1]
-    assert [len(o) for o in outs[0]] == budgets
+        assert [len(o) for o in outs[-1]] == budgets
+        assert eng.stats["surplus_rows"] == 0
+    assert outs[0] == [o[:n] for o, n in zip(outs[1], [4, 9, 7])]
+    assert len({tuple(o[:4]) for o in outs[1]}) == 3   # sampled, not argmax
 
 
 @pytest.mark.parametrize("slots", [1, 2])
@@ -844,22 +792,31 @@ def test_one_ahead_counts_every_step_but_the_first_of_a_busy_period(
 def test_one_ahead_weight_swap_lands_behind_the_step_in_flight(model,
                                                                kind):
     """A swap staged with a step in flight applies from the next
-    dispatch on: tokens match an engine that swapped synchronously at
-    the same token (the fused loop, staged at a chunk boundary)."""
+    dispatch on: tokens match a plain greedy loop over one cache that
+    changes its parameters at the same token."""
+    from elephas_tpu.models.transformer import decode_step, prefill_cache
+
     params, config = model
-    p2 = jax.tree_util.tree_map(lambda a: a * 1.05, params)
+    # other weights altogether, so that a swap one token early or late
+    # gives other tokens
+    p2 = init_params(config, jax.random.PRNGKey(7))
     [prompt] = _prompts(53, 6)
-    outs = []
-    for extra, calls in (({}, 3), ({"steps_per_sync": 2}, 2)):
-        eng = DecodeEngine(params, config, max_slots=1, **kind, **extra)
-        rid = eng.submit(prompt, 12)
-        for _ in range(calls):
-            eng.step()
-        # one ahead: 3 decode tokens read, the 4th in flight under the
-        # old weights; fused: 4 read
-        eng.stage_params(p2, 1)
-        _drain(eng)
-        assert eng.weights_version == 1
-        outs.append(eng.result(rid))
-    assert outs[0] == outs[1]
-    assert outs[0] != _ref(params, config, prompt, 12)   # the swap shows
+    eng = DecodeEngine(params, config, max_slots=1, **kind)
+    rid = eng.submit(prompt, 12)
+    for _ in range(3):
+        eng.step()
+    # 3 decode tokens read, the 4th in flight under the old weights
+    eng.stage_params(p2, 1)
+    _drain(eng)
+    assert eng.weights_version == 1
+
+    logits, cache = prefill_cache(params, jnp.asarray(prompt)[None],
+                                  config, config.max_seq_len)
+    want = [int(jnp.argmax(logits[0]))]
+    for i in range(11):
+        logits, cache = decode_step(
+            params if i < 4 else p2, cache,
+            jnp.asarray(want[-1:], jnp.int32), len(prompt) + i, config)
+        want.append(int(jnp.argmax(logits[0])))
+    assert eng.result(rid) == want
+    assert want != _ref(params, config, prompt, 12)      # the swap shows

@@ -324,7 +324,7 @@ def test_transformer_model_serve_one_call():
         vocab_size=300, num_layers=2, num_heads=4, d_model=32, d_ff=64,
         max_seq_len=48, dtype=jnp.float32))
     tm.build(seed=0)
-    srv = tm.serve(warmup_lengths=(4,), max_slots=2, steps_per_sync=2)
+    srv = tm.serve(warmup_lengths=(4,), max_slots=2)
     try:
         prompt = [int(t) for t in np.random.default_rng(7).integers(
             0, 300, 4)]
