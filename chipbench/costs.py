@@ -10,7 +10,8 @@ import json
 import os
 
 __all__ = ["peaks", "layer_params", "matmul_params", "kv_bytes_per_token",
-           "train_flops_per_token", "decode_step_bytes", "weight_bytes"]
+           "train_flops_per_token", "decode_step_bytes", "weight_bytes",
+           "serve_token_flops"]
 
 _DTYPE_BYTES = {"bfloat16": 2, "float32": 4}
 
@@ -86,3 +87,13 @@ def decode_step_bytes(cfg: dict, param_dtype: str, kv_tokens_held: float,
     program's doing and is not counted."""
     return weight_bytes(cfg, param_dtype) + kv_tokens_held * \
         kv_bytes_per_token(cfg, cache_dtype)
+
+
+def serve_token_flops(cfg: dict, held_pick_share: float = None) -> dict:
+    """Matrix FLOPs of serving one token: ``body`` (every block, which a
+    prompt token and a served token both pay) and ``head`` (the untied
+    head, which a served token pays and a prompt pays once, for its
+    first token). Attention's own two products are left out (2-3% at
+    these contexts), so a share made from this is a floor."""
+    head = int(cfg["hidden_size"]) * int(cfg["vocab_size"])
+    return {"body": 2.0 * (matmul_params(cfg) - head), "head": 2.0 * head}

@@ -9,7 +9,7 @@ __all__ = ["attention_params", "expert_params", "shared_params",
            "router_params", "dense_mlp_params", "expert_layers",
            "total_params", "latent_bytes_per_position",
            "resident_matrix_bytes", "experts_bytes", "decode_step_bytes",
-           "attend_cost"]
+           "attend_cost", "serve_token_flops"]
 
 _DTYPE_BYTES = {"bfloat16": 2, "float32": 4}
 
@@ -114,3 +114,24 @@ def attend_cost(cfg: dict, positions_held: float,
             * layers,
             "bytes": float(positions_held)
             * latent_bytes_per_position(cfg, cache_dtype)}
+
+
+def serve_token_flops(cfg: dict, held_pick_share: float = None) -> dict:
+    """Matrix FLOPs of serving one token on this chip: ``body`` (latent
+    attention's matrices, the dense layers' MLP, and per expert layer
+    the router, the shared experts and the picks that fall on HELD
+    experts: ``num_experts_per_tok`` x ``held_pick_share``, the
+    program's counters' share, or held / scored experts without them)
+    and ``head``. The absorbed attention's own products are left out:
+    a share made from this is a floor."""
+    if held_pick_share is None:
+        held_pick_share = (int(cfg["n_routed_experts"])
+                           / int(cfg["router_experts"]))
+    per_expert_layer = (shared_params(cfg) + router_params(cfg)
+                        + int(cfg["num_experts_per_tok"]) * held_pick_share
+                        * expert_params(cfg))
+    body = (int(cfg["num_hidden_layers"]) * attention_params(cfg)
+            + int(cfg["first_k_dense_replace"]) * dense_mlp_params(cfg)
+            + expert_layers(cfg) * per_expert_layer)
+    return {"body": 2.0 * body,
+            "head": 2.0 * int(cfg["hidden_size"]) * int(cfg["vocab_size"])}

@@ -2,13 +2,17 @@
 process, open-loop traffic from the load-generator child over HTTP.
 
 Set-up (all of it counted in ``setup_s``): parameters on the device from
-the seed, one cache-path step against the plain reference, the engine at
-the configuration's sizes with every other option at the program's
-default, ``engine.warmup`` over the traffic's grid of prompt lengths, the
-server, and an untimed lead-in of the same traffic. Then the window. Then,
-outside it, the drain and the checks that decide ``correct``.
+the seed, the engine at the configuration's sizes with every other option
+at the program's default, ``engine.warmup`` over the traffic's grid of
+prompt lengths, the server, and an untimed lead-in of the same traffic at
+the window's own rate. Then the window. Then, outside it and outside
+``setup_s``: the drain, the peak of the device's memory, the engine
+dropped, and the comparisons with the plain reference that decide
+``correct`` (:meth:`Served.step_check`, :meth:`Served.token_check`), each
+number beside its limit in the result line's ``checks``.
 """
 import json
+import math
 import os
 import random
 import subprocess
@@ -20,7 +24,7 @@ import urllib.request
 import numpy as np
 
 from chipbench import check, device, traffic as traffic_mod
-from chipbench.evidence import Evidence
+from chipbench.evidence import Evidence, parse_prometheus
 from chipbench.spec import HERE
 
 #: seconds between two scrapes of ``/metrics`` in a traced run
@@ -32,9 +36,21 @@ def log(message: str):
 
 
 # ----------------------------------------------------------------- set-up
+def verdict(value, limit, at_least: bool = False) -> dict:
+    """One compared number beside its limit."""
+    value, limit = float(value), float(limit)
+    ok = value >= limit if at_least else value <= limit
+    if not math.isfinite(value):
+        value, ok = None, False              # JSON has no infinity
+    return {"value": value, "limit": limit, "ok": bool(ok)}
+
+
 class Served:
-    """The system under test, set up once: parameters, the reference,
-    the engine (warmed) and the HTTP server."""
+    """The system under test, set up once: parameters, the engine
+    (warmed) and the HTTP server; and, for after the window, the plain
+    reference and the two comparisons with it. A family whose
+    comparisons differ (``serve_routed``) overrides ``make_reference``,
+    ``step_check`` and ``token_check``."""
 
     def __init__(self, run):
         import jax
@@ -42,7 +58,7 @@ class Served:
         from elephas_tpu import DecodeEngine, ServingServer
 
         spec, cfg, mix = run.spec, run.config, run.traffic
-        family = spec.load_module("families", cfg["family"])
+        self.family = family = spec.load_module("families", cfg["family"])
         self.sizes = family.model_sizes(cfg, run.rehearse)
         engine_sizes = dict(cfg["engine"])
         if run.rehearse:
@@ -56,25 +72,6 @@ class Served:
         jax.block_until_ready(self.params)
         log(f"parameters on the device in {time.monotonic() - t0:.1f}s")
 
-        reference = spec.load_module("reference", family.REFERENCE)
-        ref_forward = jax.jit(lambda p, t: reference.forward(
-            family.to_reference(p, self.config), t, self.sizes))
-
-        def ref_logits(rows):
-            return np.asarray(ref_forward(self.params, np.asarray(rows)))
-
-        self.ref_logits = ref_logits
-        tol = cfg["check"]
-        t0 = time.monotonic()
-        self.paged_diff = check.paged_step_vs_reference(
-            self.params, self.config, ref_logits,
-            rows=int(tol["paged_rows"]), cached=int(tol["paged_cached"]),
-            block_size=int(engine_sizes["paged"][1]), seed=run.seed)
-        self.paged_ok = self.paged_diff <= float(tol["paged_logits_atol"])
-        log(f"paged step vs plain reference: max |dlogit| "
-            f"{self.paged_diff:.5f} (limit {tol['paged_logits_atol']}) in "
-            f"{time.monotonic() - t0:.1f}s")
-
         t0 = time.monotonic()
         self.engine = DecodeEngine(
             self.params, self.config,
@@ -85,8 +82,7 @@ class Served:
         self.grid = traffic_mod.grid_lengths(mix["prompt_tokens"])
         self.engine.warmup(prompt_lengths=self.grid)
         log(f"engine warmed over {len(self.grid)} prompt lengths in "
-            f"{time.monotonic() - t0:.1f}s; kernel="
-            f"{self.engine.stats['kernel']}; {run.watch.summary()}")
+            f"{time.monotonic() - t0:.1f}s; {run.watch.summary()}")
         # warm-up comes before start(), so that the stall watchdog (a
         # default server option) never meets a compile
         self.server = ServingServer(self.engine).start()
@@ -99,7 +95,49 @@ class Served:
             return resp.read().decode()
 
     def stop(self):
-        self.server.stop()
+        """Stop the server and drop the engine with its pool: the
+        comparisons that follow hold the parameters alone."""
+        import gc
+
+        if self.server is not None:
+            self.server.stop()
+        self.server = self.engine = None
+        gc.collect()
+
+    # ------------------------------------------- the plain reference
+    def make_reference(self, run):
+        """``self.ref_logits(rows (n, T)) -> float32 logits``, built
+        when the first comparison asks for it (after the window)."""
+        import jax
+
+        reference = run.spec.load_module("reference",
+                                         self.family.REFERENCE)
+        forward = jax.jit(lambda p, t: reference.forward(
+            self.family.to_reference(p, self.config), t, self.sizes))
+        self.ref_logits = lambda rows: np.asarray(
+            forward(self.params, np.asarray(rows)))
+
+    def step_check(self, run) -> dict:
+        """One cache-path step against the plain reference's logits."""
+        tol = run.config["check"]
+        t0 = time.monotonic()
+        diff = check.paged_step_vs_reference(
+            self.params, self.config, self.ref_logits,
+            rows=int(tol["paged_rows"]), cached=int(tol["paged_cached"]),
+            block_size=int(self.engine_sizes["paged"][1]), seed=run.seed)
+        log(f"paged step vs plain reference: max |dlogit| {diff:.5f} "
+            f"(limit {tol['paged_logits_atol']}) in "
+            f"{time.monotonic() - t0:.1f}s")
+        return {"step_max_dlogit": verdict(diff, tol["paged_logits_atol"])}
+
+    def token_check(self, run, picked, prompts, pad_to: int) -> dict:
+        """Every served token's reference logit lies within the margin
+        of that position's best."""
+        tol = run.config["check"]
+        margins = check.logit_margins(self.ref_logits, prompts,
+                                      [s["tokens"] for s in picked], pad_to)
+        return {"token_worst_below_best": verdict(
+            max(margins), tol["token_logit_margin"])}
 
 
 # ------------------------------------------------------------------- load
@@ -193,10 +231,41 @@ def measure(run, served, load, evidence):
             evidence.trace_window[1] = device.stop_trace()
             state = "after"
         if now < end:
-            evidence.polls.append((now, served.get("/metrics")))
+            # stamped anew: starting and stopping the profiler take
+            # seconds, and a poll's counters are those of its own instant
+            evidence.polls.append((time.monotonic(),
+                                   served.get("/metrics")))
         time.sleep(max(0.0, min(POLL_S, end - time.monotonic()))
                    if state == "after" else POLL_S)
     evidence.prom_end = served.get("/metrics")
+    log_profile_cost(evidence)
+
+
+def log_profile_cost(evidence):
+    """What the profiler costs while it is on: decode steps and tokens
+    a second, from the polls of ``/metrics``, inside the profile and in
+    the rest of the window. A device time read from the trace is a time
+    WITH that cost."""
+    if not evidence.trace_window or evidence.trace_window[1] is None:
+        return
+    lo, hi = evidence.trace_window
+    points = [(t, parse_prometheus(text)) for t, text in evidence.polls]
+    rates = {}
+    for name, keep in (("inside", lambda a, b: lo <= a and b <= hi),
+                       ("outside", lambda a, b: b <= lo or a >= hi)):
+        spans = [(a, b) for a, b in zip(points, points[1:])
+                 if keep(a[0], b[0])]
+        seconds = sum(b[0] - a[0] for a, b in spans)
+        if not seconds:
+            return
+        rates[name] = {
+            key: sum(b[1].get(series, 0.0) - a[1].get(series, 0.0)
+                     for a, b in spans) / seconds
+            for key, series in (("steps_per_s", "serving_steps_total"),
+                                ("tokens_per_s",
+                                 "serving_tokens_emitted_total"))}
+    log(f"the profile's cost: inside it {rates['inside']}, in the rest of "
+        f"the window {rates['outside']}")
 
 
 # ------------------------------------------------------------ correctness
@@ -220,35 +289,47 @@ def failed_requests(samples, cut: bool) -> list:
     return bad
 
 
-def margins_ok(run, served, samples):
-    """For a seeded sample of finished requests, every emitted token's
-    reference logit lies within the margin of that position's best."""
-    tol = run.config["check"]
+def sample_finished(run, samples) -> list:
+    """The finished requests the reference is run over: the longest
+    answer (the lowest-numbered of them) and a sample of the others
+    drawn from the seed, ``check.sample_requests`` in all."""
     done = [s for s in samples if s["end"] == "done" and s["tokens"]]
     if not done:
-        return False
-    picker = random.Random(run.seed)
-    picked = picker.sample(done, min(int(tol["sample_requests"]), len(done)))
+        return []
+    longest = max(done, key=lambda s: (len(s["tokens"]), -s["i"]))
+    rest = [s for s in done if s is not longest]
+    want = int(run.config["check"]["sample_requests"]) - 1
+    return [longest] + random.Random(run.seed).sample(
+        rest, min(want, len(rest)))
+
+
+def compare(run, served, samples) -> dict:
+    """The comparisons with the plain reference, after the window:
+    ``{name: {"value", "limit", "ok"}}``."""
+    served.make_reference(run)
+    checks = dict(served.step_check(run))
+    picked = sample_finished(run, samples)
+    if not picked:
+        checks["finished_requests"] = verdict(0, 1, at_least=True)
+        return checks
     prompts = [traffic_mod.prompt_tokens(run.seed, s["i"], s["prompt_len"],
                                          served.config.vocab_size)
                for s in picked]
     pad_to = (int(run.traffic["prompt_tokens"]["max"])
               + int(run.traffic["output_tokens"]["max"]))
     t0 = time.monotonic()
-    margins = check.logit_margins(served.ref_logits, prompts,
-                                  [s["tokens"] for s in picked], pad_to)
-    worst = max(margins)
-    log(f"f32 logit margin over {len(picked)} requests "
-        f"({sum(len(s['tokens']) for s in picked)} tokens): worst "
-        f"{worst:.5f} (limit {tol['token_logit_margin']}) in "
+    checks.update(served.token_check(run, picked, prompts, pad_to))
+    log(f"reference over {len(picked)} finished requests "
+        f"({sum(len(s['tokens']) for s in picked)} served tokens, the "
+        f"longest {len(picked[0]['tokens'])}) in "
         f"{time.monotonic() - t0:.1f}s")
-    return worst <= float(tol["token_logit_margin"])
+    return checks
 
 
 # -------------------------------------------------------------------- run
-def run(run) -> dict:
+def run(run, served_class=Served) -> dict:
     """One run of a serving cell; returns the result line's fields."""
-    served = Served(run)
+    served = served_class(run)
     evidence = Evidence(run)
     load = None
     try:
@@ -263,6 +344,8 @@ def run(run) -> dict:
         if load is not None:
             load.kill()
         served.stop()
+    # the peak is the served system's: read before the reference runs
+    memory_peak = device.peak_memory_bytes(run.devices)
     evidence.samples = samples
     evidence.sizes = served.sizes
     evidence.engine_sizes = served.engine_sizes
@@ -272,23 +355,33 @@ def run(run) -> dict:
     bad = failed_requests(samples, cut)
     for i, why in bad[:10]:
         log(f"request {i} failed: {why}")
-    correct = bool(served.paged_ok and margins_ok(run, served, samples)
-                   and not bad)
+    checks = compare(run, served, samples)
+    checks["failed_requests"] = verdict(len(bad), 0)
     log(f"compiles inside the window: {evidence.compiles_in_window}; "
         f"{run.watch.summary()}")
-    return {"correct": correct, "attempted": len(timed),
-            "failed": len(bad), "evidence": evidence}
+    return {"correct": all(c["ok"] for c in checks.values()),
+            "attempted": len(timed), "failed": len(bad),
+            "evidence": evidence, "checks": checks,
+            "memory_peak_bytes": memory_peak}
 
 
 # ------------------------------------------------------------------ sweep
-def sweep(run, rates, step_s: float, out_path: str):
-    """Find the knee: one set-up, then one open-loop step per rate. Per
-    rate: the share of requests that met both limits, the backlog at the
-    step's middle and end, tokens per second and the latency quantiles.
-    The step is cut at its end so that no backlog carries over."""
+def sweep(run, rates, step_s: float, out_path: str, lead_in_s: float = 0.0,
+          served_class=Served):
+    """One set-up, then one open-loop step per rate, cut at its end so
+    that no backlog carries over. Per rate: the share of requests that
+    met both limits, the backlog at the step's middle and end, tokens
+    per second, the latency quantiles and, from two scrapes of
+    ``/metrics`` at the step's edges, the tokens a decode step emitted.
+
+    With ``lead_in_s`` = 0 each step starts idle: the knee by the
+    latency limits. With a lead-in at the step's own rate the step is
+    the cut cell itself at that rate, and its tokens per second over the
+    mix's mean answer is the capacity (an idle start understates a full
+    engine by the seconds it takes to fill the slots)."""
     from chipbench.readers import client_samples as cs
 
-    served = Served(run)
+    served = served_class(run)
     limits = run.traffic["sweep_limits"]
     rows = []
     try:
@@ -297,14 +390,30 @@ def sweep(run, rates, step_s: float, out_path: str):
             # would repeat each other's prompts, hit the prefix cache and
             # compile a program for every new hit length
             load = Load(run, served, step_s, rate_per_s=rate, cut=True,
-                        lead_in_s=0.0, seed=run.seed + 7919 * (step + 1))
+                        lead_in_s=lead_in_s,
+                        seed=run.seed + 7919 * (step + 1))
             try:
+                start, end = load.window
+                sleep_until(start)
+                before = parse_prometheus(served.get("/metrics"))
+                sleep_until(end)
+                after = parse_prometheus(served.get("/metrics"))
                 samples = load.wait()
             finally:
                 load.kill()
-            start, end = load.window
             row = cs.sweep_row(samples, start, end, limits)
             row["rate_per_s"] = rate
+            timed = [s for s in samples if s["timed"]]
+            row["mean_answer_tokens"] = (
+                sum(s["asked"] for s in timed) / len(timed))
+            steps = (after.get("serving_steps_total", 0.0)
+                     - before.get("serving_steps_total", 0.0))
+            if steps:
+                row["tokens_per_step"] = (
+                    after.get("serving_tokens_emitted_total", 0.0)
+                    - before.get("serving_tokens_emitted_total", 0.0)
+                ) / steps
+                row["steps_per_s"] = steps / (end - start)
             row["compiles"] = run.watch.compiles_between(start, end)
             rows.append(row)
             log(f"sweep {json.dumps(row)}")
@@ -320,5 +429,6 @@ def sweep(run, rates, step_s: float, out_path: str):
     with open(out_path, "w") as fh:
         json.dump({"config": run.cell["config"],
                    "traffic": run.cell["traffic"], "step_s": step_s,
-                   "limits": limits, "rows": rows, **found}, fh, indent=1)
+                   "lead_in_s": lead_in_s, "limits": limits, "rows": rows,
+                   **found}, fh, indent=1)
     return rows

@@ -6,137 +6,89 @@ the near-tie rule, the token margins as a share) in place of
 ``check.py``'s single numbers, and a reference that is run one row at a
 time (its float32 temporaries beside 10 GB of weights).
 """
-import random
 import time
 
 import numpy as np
 
-from chipbench import check_routed, traffic as traffic_mod
+from chipbench import check_routed
 from chipbench.drivers import serve as base
+from chipbench.drivers.serve import verdict
 
 log = base.log
 
 
 class Served(base.Served):
-    """``serve.Served`` with the routed comparison; everything from the
-    engine on is the same sequence."""
+    """``serve.Served`` with the routed comparisons."""
 
-    def __init__(self, run):
+    def make_reference(self, run):
         import jax
 
-        from elephas_tpu import DecodeEngine, ServingServer
-
-        spec, cfg, mix = run.spec, run.config, run.traffic
-        family = spec.load_module("families", cfg["family"])
-        self.sizes = family.model_sizes(cfg, run.rehearse)
-        engine_sizes = dict(cfg["engine"])
-        if run.rehearse:
-            engine_sizes.update(cfg.get("rehearse", {}).get("engine", {}))
-        self.engine_sizes = engine_sizes
-        self.config = family.program_config(
-            self.sizes, max_seq_len=engine_sizes["max_len"],
-            param_dtype=cfg["param_dtype"])
-        t0 = time.monotonic()
-        self.params = family.make_params(self.config, run.seed)
-        jax.block_until_ready(self.params)
-        log(f"parameters on the device in {time.monotonic() - t0:.1f}s")
-
-        reference = spec.load_module("reference", family.REFERENCE)
-        ref_forward = jax.jit(lambda p, t: reference.forward(
-            family.to_reference(p, self.config), t, self.sizes))
-        ref_routing = jax.jit(
+        reference = run.spec.load_module("reference",
+                                         self.family.REFERENCE)
+        forward = jax.jit(lambda p, t: reference.forward(
+            self.family.to_reference(p, self.config), t, self.sizes))
+        routing = jax.jit(
             lambda p, t, picks: reference.forward_with_routing(
-                family.to_reference(p, self.config), t, self.sizes,
+                self.family.to_reference(p, self.config), t, self.sizes,
                 last_picks=picks))
-
-        def ref_logits(rows):
-            """(rows, T) -> float32 logits, one row at a time."""
-            return np.concatenate([
-                np.asarray(ref_forward(self.params, np.asarray(row)[None]))
-                for row in np.asarray(rows)])
-
-        self.ref_logits = ref_logits
-        self.ref_routing = lambda tokens, picks: ref_routing(
+        # (rows, T) -> float32 logits, one row at a time
+        self.ref_logits = lambda rows: np.concatenate([
+            np.asarray(forward(self.params, np.asarray(row)[None]))
+            for row in np.asarray(rows)])
+        self.ref_routing = lambda tokens, picks: routing(
             self.params, tokens, picks)
-        tol = cfg["check"]
+
+    def step_check(self, run) -> dict:
+        tol = run.config["check"]
         t0 = time.monotonic()
-        verdict = check_routed.paged_step_vs_reference(
+        found = check_routed.paged_step_vs_reference(
             self.params, self.config, self.ref_routing,
             rows=int(tol["paged_rows"]), cached=int(tol["paged_cached"]),
-            engine_sizes=engine_sizes, seed=run.seed, tol=tol)
-        self.paged_diff, self.paged_ok = (verdict["max_abs_dlogit"],
-                                          verdict["ok"])
-        log(f"paged step vs plain reference: {verdict} (limits: atol "
-            f"{tol['paged_logits_atol']}, rms {tol['paged_logits_rms']}, "
-            f"near tie "
-            f"{tol['near_tie_margin']}, flipped share "
-            f"{tol['max_flipped_share']}) in "
+            engine_sizes=self.engine_sizes, seed=run.seed, tol=tol)
+        log(f"paged step vs plain reference: {found} in "
             f"{time.monotonic() - t0:.1f}s")
+        return {
+            "step_max_dlogit": verdict(found["max_abs_dlogit"],
+                                       tol["paged_logits_atol"]),
+            "step_rms_dlogit": verdict(found["rms_dlogit"],
+                                       tol["paged_logits_rms"]),
+            "step_worst_flipped_gap": verdict(
+                found["worst_flipped_margin"], tol["near_tie_margin"]),
+            "step_flipped_share": verdict(
+                found["flipped_choices"] / found["choices"],
+                tol["max_flipped_share"])}
 
-        t0 = time.monotonic()
-        self.engine = DecodeEngine(
-            self.params, self.config,
-            max_slots=int(engine_sizes["max_slots"]),
-            max_len=int(engine_sizes["max_len"]),
-            paged=tuple(engine_sizes["paged"]),
-            prefill_chunk=int(engine_sizes["prefill_chunk"]))
-        self.grid = traffic_mod.grid_lengths(mix["prompt_tokens"])
-        self.engine.warmup(prompt_lengths=self.grid)
-        log(f"engine warmed over {len(self.grid)} prompt lengths in "
-            f"{time.monotonic() - t0:.1f}s; kernel="
-            f"{self.engine.stats['kernel']}; {run.watch.summary()}")
-        self.server = ServingServer(self.engine).start()
-        self.port = self.server.port
-        log("server started")
-
-
-def margins_ok(run, served, samples):
-    """``serve.margins_ok`` for tokens that may have followed a flipped
-    pick: the same seeded sample of finished requests, judged by
-    ``check_routed.judge_tokens``."""
-    tol = run.config["check"]
-    done = [s for s in samples if s["end"] == "done" and s["tokens"]]
-    if not done:
-        return False
-    picked = random.Random(run.seed).sample(
-        done, min(int(tol["sample_requests"]), len(done)))
-    prompts = [traffic_mod.prompt_tokens(run.seed, s["i"], s["prompt_len"],
-                                         served.config.vocab_size)
-               for s in picked]
-    pad_to = (int(run.traffic["prompt_tokens"]["max"])
-              + int(run.traffic["output_tokens"]["max"]))
-    t0 = time.monotonic()
-    below = check_routed.token_margins(
-        served.ref_logits, prompts, [s["tokens"] for s in picked], pad_to)
-    verdict = check_routed.judge_tokens(below, tol)
-    log(f"f32 logit margin over {len(picked)} requests: {verdict} (limits: "
-        f"share within {tol['token_logit_margin']} at least "
-        f"{tol['token_share_within_margin']}, worst "
-        f"{tol['token_logit_margin_worst']}); quantiles 0.5 / 0.9 / 0.99 "
-        f"of the distance "
-        f"{np.quantile(below, [0.5, 0.9, 0.99]).round(4).tolist()} in "
-        f"{time.monotonic() - t0:.1f}s")
-    return verdict["ok"]
+    def token_check(self, run, picked, prompts, pad_to: int) -> dict:
+        """Tokens that may have followed a flipped pick: judged by
+        ``check_routed.judge_tokens``."""
+        tol = run.config["check"]
+        below = check_routed.token_margins(
+            self.ref_logits, prompts, [s["tokens"] for s in picked], pad_to)
+        found = check_routed.judge_tokens(below, tol)
+        log(f"f32 logit margin: {found}; quantiles 0.5 / 0.9 / 0.99 of "
+            f"the distance "
+            f"{np.quantile(below, [0.5, 0.9, 0.99]).round(4).tolist()}")
+        return {
+            "token_share_within_margin": verdict(
+                found["share_within_margin"],
+                tol["token_share_within_margin"], at_least=True),
+            "token_worst_below_best": verdict(
+                found["worst"], tol["token_logit_margin_worst"])}
 
 
-def _as_served(fn, run, *args):
-    """``serve``'s ``run`` / ``sweep`` with this file's set-up (and, in a
-    rehearsal, the toy widths' limits)."""
+def _limits(run):
+    """In a rehearsal, the toy widths' limits."""
     if run.rehearse:
         run.config = dict(run.config, check={
             **run.config["check"],
             **run.config.get("rehearse", {}).get("check", {})})
-    saved = base.Served, base.margins_ok
-    base.Served, base.margins_ok = Served, margins_ok
-    try:
-        return fn(run, *args)
-    finally:
-        base.Served, base.margins_ok = saved
+    return run
 
 
 def run(run) -> dict:
-    return _as_served(base.run, run)
+    return base.run(_limits(run), Served)
 
 
-def sweep(run, rates, step_s: float, out_path: str):
-    return _as_served(base.sweep, run, rates, step_s, out_path)
+def sweep(run, rates, step_s: float, out_path: str, lead_in_s: float = 0.0):
+    return base.sweep(_limits(run), rates, step_s, out_path, lead_in_s,
+                      Served)

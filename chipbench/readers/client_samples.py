@@ -64,9 +64,11 @@ def sweep_row(samples, start: float, end: float, limits: dict) -> dict:
     """One step of the knee sweep (see ``drivers/serve.py`` ``sweep``).
     Attainment is over the requests due at least ``settle_s`` before the
     cut, so that each had time to show its first token; a request with no
-    first token by the cut, or refused, misses."""
+    first token by the cut, or refused, misses. Requests of a lead-in
+    (not ``timed``) count in the backlog and in the tokens, not in the
+    attainment."""
     settle = float(limits.get("settle_s", 5.0))
-    judged = [s for s in samples if s["due"] < end - settle]
+    judged = [s for s in samples if s["timed"] and s["due"] < end - settle]
     met = 0
     for s in judged:
         ttft, tpot = ttft_ms(s), tpot_ms(s)

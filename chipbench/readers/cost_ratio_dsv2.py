@@ -81,6 +81,12 @@ def read(evidence, what: str, match: str, scope: str = None):
         if not under:
             return None
         needed = dsv2.experts_bytes(sizes, dtype, touched)
+        # the parts, so that a reading over 100% leaves them in the log
+        device.log("cost_ratio_dsv2", f"experts: {touched:.2f} experts "
+                   f"touched a step = {needed:.4e} bytes = "
+                   f"{1e3 * needed / bandwidth:.3f} ms at the peak; "
+                   f"{1e3 * under / count:.3f} ms a step under {scope} "
+                   f"over {count:g} steps")
         return 100.0 * (needed / bandwidth) / (under / count)
     if what == "decode_step_roofline":
         needed = dsv2.decode_step_bytes(

@@ -77,10 +77,13 @@ def _map_entry(buf):
     return key, value
 
 
-def device_ops(path: str, device: int = None):
-    """``[(origin, start_ns, end_ns)]`` of the ``XLA Ops`` line of one
-    device plane (the lowest-numbered unless ``device`` is given);
-    ``origin`` is the operation's ``tf_op`` ('' when it has none)."""
+def device_ops(path: str, device: int = None,
+               line_name: str = trace_reduce.OPS_LINE, names: bool = False):
+    """``[(origin, start_ns, end_ns)]`` of the ``XLA Ops`` line (or
+    ``line_name``) of one device plane (the lowest-numbered unless
+    ``device`` is given); ``origin`` is the operation's ``tf_op`` (''
+    when it has none). With ``names`` each entry leads with the
+    operation's name: ``(name, origin, start_ns, end_ns)``."""
     with open(path, "rb") as fh:
         space = memoryview(fh.read())
     planes = {}
@@ -105,9 +108,11 @@ def device_ops(path: str, device: int = None):
         elif number == 3:
             lines.append(item)
     wanted = {key for key, name in stat_names.items() if name == "tf_op"}
-    origin = {}
+    origin, op_names = {}, {}
     for key, meta in metadata.items():
         for number, stat in fields(meta):
+            if number == 2 and names:
+                op_names[key] = _text(stat)
             if number != 5:
                 continue
             parts = dict(fields(stat))
@@ -119,8 +124,7 @@ def device_ops(path: str, device: int = None):
     out = []
     for line in lines:
         parts = list(fields(line))
-        if next((_text(v) for n, v in parts if n == 2), "") != \
-                trace_reduce.OPS_LINE:
+        if next((_text(v) for n, v in parts if n == 2), "") != line_name:
             continue
         base_ns = next((v for n, v in parts if n == 3), 0)
         for number, event in parts:
@@ -128,8 +132,10 @@ def device_ops(path: str, device: int = None):
                 continue
             ev = dict(fields(event))
             start = base_ns + ev.get(2, 0) // 1000
-            out.append((origin.get(ev.get(1), ""), start,
-                        start + ev.get(3, 0) // 1000))
+            entry = (origin.get(ev.get(1), ""), start,
+                     start + ev.get(3, 0) // 1000)
+            out.append((op_names.get(ev.get(1), "?"), *entry) if names
+                       else entry)
     return out
 
 

@@ -11,7 +11,8 @@ prints no result line.
 
 Not part of the contract: ``--rehearse`` (the CPU rehearsal the tests
 use: toy widths, ``device.platform`` says ``cpu``, no number of it is a
-device metric), ``--sweep`` (the knee sweep of a serving cell) and
+device metric), ``--sweep`` (the knee or capacity sweep of a serving
+cell), ``--rate-per-s`` (a trial at another offered rate) and
 ``--benchmark-json`` (another ``BENCHMARK.json``, for the test that the
 harness takes additions as data).
 """
@@ -41,6 +42,13 @@ def parse(argv):
                     help="comma-separated request rates; writes "
                          "chiprun_out/sweep-<cell>.json")
     ap.add_argument("--sweep-step-s", type=float, default=30.0)
+    ap.add_argument("--sweep-lead-in-s", type=float, default=0.0,
+                    help="a lead-in at each step's own rate: the step is "
+                         "then the cut cell at that rate (capacity)")
+    ap.add_argument("--rate-per-s", type=float, default=None,
+                    help="offer this rate, lead-in and window, in place "
+                         "of the traffic file's (a trial: is the reading "
+                         "capacity, or the offered rate?)")
     ap.add_argument("--benchmark-json", default=None)
     ap.add_argument("--keep-trace", default=None, metavar="DIR",
                     help="copy the traced run's .xplane.pb there")
@@ -73,6 +81,9 @@ def main(argv=None) -> int:
     run.rehearse, run.trace, run.seed = args.rehearse, args.trace, args.seed
     if args.rehearse:
         run.traffic = dict(run.traffic, **run.traffic.get("rehearse", {}))
+    if args.rate_per_s is not None:
+        run.traffic = dict(run.traffic, arrivals=dict(
+            run.traffic["arrivals"], rate_per_s=args.rate_per_s))
     run.seconds = float(args.seconds if args.seconds is not None
                         else spec.data["run_seconds"])
 
@@ -110,7 +121,8 @@ def main(argv=None) -> int:
         if args.sweep:
             rates = [float(r) for r in args.sweep.split(",")]
             driver.sweep(run, rates, args.sweep_step_s, os.path.join(
-                "chiprun_out", f"sweep-{run.cell['name']}.json"))
+                "chiprun_out", f"sweep-{run.cell['name']}.json"),
+                args.sweep_lead_in_s)
             return 0
         result = driver.run(run)
         evidence = result.pop("evidence")
@@ -123,9 +135,8 @@ def main(argv=None) -> int:
                            "samples": evidence.samples}, fh)
         group = "per_layer" if run.trace else "end_to_end"
         metrics = spec.read_metrics(group, run.cell["name"], evidence)
-        report = dict(run.device,
-                      memory_peak_bytes=device.peak_memory_bytes(
-                          run.devices))
+        report = dict(run.device, memory_peak_bytes=result.get(
+            "memory_peak_bytes", device.peak_memory_bytes(run.devices)))
         breakdown = None
         if run.trace:
             # both groups are printed on an earlier line of a traced
@@ -143,7 +154,9 @@ def main(argv=None) -> int:
             if trace:
                 breakdown = trace.breakdown()
                 print(f"[run] device time by program: "
-                      f"{json.dumps(trace.program_time())}", flush=True)
+                      f"{json.dumps(trace.program_time())}; the "
+                      f"profiler's time scale multipliers: "
+                      f"{trace.time_scales}", flush=True)
     line = {"correct": result["correct"], "attempted": result["attempted"],
             "failed": result["failed"], "metrics": metrics,
             "device": report}
@@ -151,7 +164,14 @@ def main(argv=None) -> int:
         line["breakdown"] = breakdown
     print(f"[run] peak device memory {report['memory_peak_bytes']} bytes; "
           f"total {time.monotonic() - T_PROCESS_START:.1f}s", flush=True)
+    # each number compared beside its limit: last in the line, and the
+    # last lines of standard error
+    line["checks"] = result.get("checks", {})
     print(json.dumps(line), flush=True)
+    for name, c in line["checks"].items():
+        print(f"check {name}: {c['value']} limit {c['limit']} "
+              f"{'ok' if c['ok'] else 'NOT OK'}", file=sys.stderr)
+    print(f"correct: {line['correct']}", file=sys.stderr, flush=True)
     return 0
 
 

@@ -19,9 +19,12 @@ What is computed, per device and then averaged over the devices used:
   on ``Async XLA Ops``), and the part of it during which no other
   operation runs on that device;
 - the ten kinds of operation (opcode and result shape, the layers'
-  copies of one operation added up) that took most time, and the idle
-  gaps grouped by the program that ended each gap (what the device was
-  waiting for).
+  copies of one operation added up) that took most time of their own (a
+  ``conditional`` or ``while`` less the operations that ran inside it),
+  and the idle gaps grouped by the innermost host span over each gap's
+  middle (the harness's ``chipbench.`` spans and the program's
+  ``elephas.`` spans) and the program that ended the gap (what the
+  device was waiting for).
 
 The window is the span between the two marks the harness puts on the
 host clock (``chipbench.trace_begin`` / ``chipbench.trace_end``) when the
@@ -42,6 +45,8 @@ ASYNC_LINE = "Async XLA Ops"
 MODULES_LINE = "XLA Modules"
 BEGIN_MARK = "chipbench.trace_begin"
 END_MARK = "chipbench.trace_end"
+#: host annotations that are kept: the harness's and the program's spans
+HOST_SPANS = ("chipbench.", "elephas.")
 #: HLO operation names that move data between chips
 COLLECTIVE = re.compile(
     r"^%?(all-reduce|all-gather|reduce-scatter|collective-permute|"
@@ -99,6 +104,26 @@ def _clip(intervals, lo, hi) -> list:
             if e > lo and s < hi]
 
 
+def own_ns(ops, lo, hi) -> list:
+    """``[[name, nanoseconds]]`` for the operations of one ``XLA Ops``
+    line inside ``lo..hi``: each operation's time less that of the
+    operations that ran inside it (a ``conditional`` holds its branch's
+    operations as events of the same line), so that the list adds up to
+    the line's busy time and nothing counts twice."""
+    out, stack = [], []                # stack: (end, index into out)
+    for name, start, end in sorted(ops, key=lambda o: (o[1], -o[2])):
+        start, end = max(start, lo), min(end, hi)
+        if end <= start:
+            continue
+        while stack and stack[-1][0] < end:
+            stack.pop()                # ended, or overlaps without holding
+        if stack:
+            out[stack[-1][1]][1] -= end - start
+        out.append([name, end - start])
+        stack.append((end, len(out) - 1))
+    return out
+
+
 def _subtract(a, b) -> list:
     """Parts of the disjoint sorted intervals ``a`` not covered by the
     disjoint sorted intervals ``b``."""
@@ -120,12 +145,16 @@ def _subtract(a, b) -> list:
 
 class Trace:
     """Events of one trace: ``devices[n] = {"ops": [(name, start, end)],
-    "async": [...], "modules": [...]}`` and ``host = [(name, start, end)]`` (annotations
-    whose name starts with ``chipbench.``)."""
+    "async": [...], "modules": [...]}`` and ``host = [(name, start, end)]``
+    (annotations whose name starts with one of ``HOST_SPANS``)."""
 
-    def __init__(self, devices: dict, host: list):
+    def __init__(self, devices: dict, host: list, time_scales=()):
         self.devices = devices
         self.host = host
+        #: the profiler's ``Time Scale Multiplier`` stats seen on the
+        #: device's operations (1.0 on every machine met so far; logged
+        #: because one cell's step reads 13.8-20.2 ms by the machine)
+        self.time_scales = sorted(set(time_scales))
         self.window = self._window()
 
     def _window(self):
@@ -201,25 +230,26 @@ class Trace:
                 "exposed_seconds": exposed / n / 1e9}
 
     def top_ops(self, limit: int = 10) -> list:
-        """``[[operation, seconds], ...]``: most device time first, on the
-        first device (they all run the same program)."""
+        """``[[operation, seconds], ...]``: most device time of its own
+        first (:func:`own_ns`), on the first device (they all run the
+        same program)."""
         if not self.devices or not self.window:
             return []
         lo, hi = self.window
         dev = self.devices[min(self.devices)]
         by_name = defaultdict(int)
-        for name, start, end in dev["ops"]:
-            if end > lo and start < hi:
-                by_name[op_kind(name)] += min(end, hi) - max(start, lo)
+        for name, ns in own_ns(dev["ops"], lo, hi):
+            by_name[op_kind(name)] += ns
         ranked = sorted(by_name.items(), key=lambda kv: -kv[1])[:limit]
         return [[name, ns / 1e9] for name, ns in ranked]
 
     def idle_gaps(self, limit: int = 10) -> list:
         """``[[what ended the gap, seconds], ...]``: the device's idle time
         on the first device, grouped by the program whose start ended
-        each gap (so: what the device was waiting for), plus host
-        annotations of the harness covering the gap's middle when there
-        are any."""
+        each gap (so: what the device was waiting for), behind the
+        innermost host span (the latest to start) over the gap's middle
+        when there is one: ``before elephas.loop.prefill.row_init >
+        jit_convert_element_type``."""
         if not self.devices or not self.window:
             return []
         lo, hi = self.window
@@ -228,17 +258,20 @@ class Trace:
         gaps = _subtract([(lo, hi)], busy)
         starts = sorted((s, program_name(name))
                         for name, s, _ in dev["modules"])
-        spans = [(n, s, e) for n, s, e in self.host
-                 if n not in (BEGIN_MARK, END_MARK)]
+        spans = sorted((s, e, n) for n, s, e in self.host
+                       if n not in (BEGIN_MARK, END_MARK))
+        span_starts = [s for s, _, _ in spans]
         start_ns = [s for s, _ in starts]
         by_cause = defaultdict(int)
         for gap_start, gap_end in gaps:
             at = bisect.bisect_left(start_ns, gap_end - 1)
             cause = starts[at][1] if at < len(starts) else "end of window"
             mid = (gap_start + gap_end) // 2
-            host = [n for n, s, e in spans if s <= mid < e]
-            if host:
-                cause = f"{host[-1]} > {cause}"
+            k = bisect.bisect_right(span_starts, mid) - 1
+            while k >= 0 and spans[k][1] <= mid:
+                k -= 1
+            if k >= 0:
+                cause = f"{spans[k][2]} > {cause}"
             by_cause[f"before {cause}"] += gap_end - gap_start
         ranked = sorted(by_cause.items(), key=lambda kv: -kv[1])[:limit]
         return [[name, ns / 1e9] for name, ns in ranked]
@@ -252,7 +285,7 @@ def load(path: str, device_ids=None) -> Trace:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
-    devices, host = {}, []
+    devices, host, scales = {}, [], []
     for plane in data.planes:
         match = DEVICE_PLANE.match(plane.name)
         if match:
@@ -273,13 +306,16 @@ def load(path: str, device_ids=None) -> Trace:
                     start = int(ev.start_ns)
                     dev[key].append((ev.name, start,
                                      start + int(ev.duration_ns)))
+                    if key == "ops" and len(dev[key]) % 5000 == 1:
+                        scales += [value for name, value in ev.stats
+                                   if name == "Time Scale Multiplier"]
             if dev["ops"]:
                 devices[number] = dev
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith("chipbench."):
+                    if ev.name.startswith(HOST_SPANS):
                         start = int(ev.start_ns)
                         host.append((ev.name, start,
                                      start + int(ev.duration_ns)))
-    return Trace(devices, host)
+    return Trace(devices, host, scales)

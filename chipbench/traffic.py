@@ -11,7 +11,12 @@ touch JAX). Everything is drawn from seeds:
 - ``--seed`` orders both multisets and draws the token values, so runs
   with different seeds see different prompts in a different order without
   one run carrying 10% more tokens than another (the heavy tails of the
-  lognormals would otherwise decide the spread between seeds).
+  lognormals would otherwise decide the spread between seeds). ``order``
+  says how: ``"shuffle"`` (the default) shuffles gaps and sizes apart;
+  ``"rotate"`` keeps the one arrangement the ``shape_seed`` drew and
+  begins it at a request the seed picks -- for a cell judged by a tail,
+  where WHICH short answer meets WHICH long prompt decides the 90th
+  percentile and a shuffle moved it by +-10% from seed to seed.
 
 A traffic file for a serving mix holds::
 
@@ -20,7 +25,8 @@ A traffic file for a serving mix holds::
      "prompt_tokens": {"median": 512, "sigma": 0.8,
                        "grid": 128, "min": 128, "max": 2048},
      "output_tokens": {"median": 128, "sigma": 0.7, "min": 8, "max": 512},
-     "shape_seed": 1, "lead_in_s": 10, "drain_s": 45, "cut": false}
+     "shape_seed": 1, "order": "shuffle", "lead_in_s": 10, "drain_s": 45,
+     "cut": false}
 
 ``arrivals.cv`` is the coefficient of variation of the gaps: 1 is a
 Poisson process, above 1 is bursty (gamma gaps, BurstGPT-like).
@@ -53,19 +59,20 @@ def grid_lengths(dist: dict) -> list:
 
 def _part(rng: random.Random, traffic: dict, arrivals: dict,
           duration: float):
-    """Gaps that fill exactly ``duration`` (drawn until they pass it,
-    then scaled onto it: under 3% at 30 requests), and one pair of sizes
-    for each."""
+    """``round(rate * duration)`` gaps (at least one), scaled so that
+    they fill exactly ``duration``, and one pair of sizes for each: a
+    part offers its nominal rate whatever the ``shape_seed`` (gaps drawn
+    until they passed the duration let one 40-s lead-in offer 7.4 req/s
+    in a 6.5 req/s file: PERF.md section 6, PR 31)."""
     if duration <= 0:
         return [], []
     rate = float(arrivals["rate_per_s"])
     cv = float(arrivals.get("cv", 1.0))
     shape = 1.0 / (cv * cv)
     scale = 1.0 / (rate * shape)
-    gaps, total = [], 0.0
-    while total < duration:
-        gaps.append(rng.gammavariate(shape, scale))
-        total += gaps[-1]
+    count = max(1, round(rate * duration))
+    gaps = [rng.gammavariate(shape, scale) for _ in range(count)]
+    total = sum(gaps)
     gaps = [gap * duration / total for gap in gaps]
     sizes = [(draw_length(rng, traffic["prompt_tokens"]),
               draw_length(rng, traffic["output_tokens"])) for _ in gaps]
@@ -92,8 +99,13 @@ def make_schedule(traffic: dict, seed: int, seconds: float,
     requests, begin = [], 0.0
     for duration, timed in ((lead_in, False), (float(seconds), True)):
         gaps, sizes = _part(shape_rng, traffic, arrivals, duration)
-        order_rng.shuffle(gaps)
-        order_rng.shuffle(sizes)
+        if traffic.get("order", "shuffle") == "rotate":
+            # one arrangement for every seed, begun at another request
+            k = order_rng.randrange(len(gaps)) if gaps else 0
+            gaps, sizes = gaps[k:] + gaps[:k], sizes[k:] + sizes[:k]
+        else:
+            order_rng.shuffle(gaps)
+            order_rng.shuffle(sizes)
         due = begin
         for gap, (prompt_len, new) in zip(gaps, sizes):
             requests.append({"i": len(requests), "due_s": due,

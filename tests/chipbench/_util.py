@@ -6,7 +6,7 @@ import sys
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent.parent
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device", "checks"}
 
 
 def bench_command():
@@ -33,3 +33,15 @@ def last_line(proc):
 
 def json_lines(text):
     return [ln for ln in text.splitlines() if ln.lstrip().startswith("{")]
+
+
+def run_broken(fault, *args, timeout=600):
+    """The rehearsal of a cell with the timed path broken underneath
+    (``tests/chipbench/_broken_run.py``; it adds ``--rehearse``)."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("XLA_FLAGS", None)
+    return subprocess.run(
+        [sys.executable, str(REPO / "tests" / "chipbench" /
+                             "_broken_run.py"), fault, *args],
+        capture_output=True, text=True, env=env, cwd=str(REPO),
+        timeout=timeout)

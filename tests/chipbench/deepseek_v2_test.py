@@ -11,7 +11,7 @@ import pytest
 from chipbench import check_routed, costs_deepseek_v2 as dsv2, trace_reduce
 from chipbench.readers import cost_ratio_dsv2, trace_scope_time
 
-from ._util import REPO, last_line, run_cell
+from ._util import REPO, last_line, run_broken, run_cell
 
 CELL = "deepseek-v2-chat-saturated"
 TRACE = REPO / "chipbench" / "testdata" / "tiny-v5e.xplane.pb"
@@ -334,3 +334,39 @@ def test_the_cell_rehearses(trace):
         assert set(line["metrics"]) == {"serve_tokens_per_s", "setup_s"}
     assert "paged step vs plain reference" in proc.stdout
     assert "compiles inside the window: 0" in proc.stdout
+
+
+@pytest.mark.parametrize("fault", [None, "token_altered"])
+def test_an_altered_token_is_seen_where_the_toy_limits_have_teeth(
+        fault, tmp_path, cfg):
+    """The configuration's rehearsal limits are vacuous on purpose (a
+    flipped pick at toy widths moves whole logits), so this copy of it
+    holds the rehearsal's tokens to the chip's share rule (0.8 within
+    0.25): the sound path passes it, and the path whose engine emits the
+    neighbour of every token does not."""
+    with open(REPO / "BENCHMARK.json") as fh:
+        bench = json.load(fh)
+    toy = json.loads(json.dumps(cfg))
+    toy["rehearse"]["check"].update(token_logit_margin=0.25,
+                                    token_share_within_margin=0.8)
+    entry = next(c for c in bench["configs"]
+                 if c["file"].endswith("deepseek-v2-l5-e40-serve.json"))
+    path = tmp_path / entry["file"]
+    path.parent.mkdir(parents=True)
+    path.write_text(json.dumps(toy))
+    bench["configs"] = [entry]
+    bench["workloads"] = [w for w in bench["workloads"] if w["name"] == CELL]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    args = ("--benchmark-json", str(tmp_path / "BENCHMARK.json"),
+            "--workload", CELL, "--seed", str(2**31 + 29), "--seconds", "3",
+            "--trace", "0")
+    proc = (run_broken(fault, *args) if fault
+            else run_cell(*args, "--rehearse"))
+    line = last_line(proc)
+    share = line["checks"]["token_share_within_margin"]
+    assert share["limit"] == 0.8 and line["failed"] == 0
+    if fault:
+        assert line["correct"] is False and share["value"] < 0.5
+        assert proc.stderr.strip().splitlines()[-1] == "correct: False"
+    else:
+        assert line["correct"] is True and share["value"] >= 0.8

@@ -4,8 +4,9 @@ from types import SimpleNamespace
 import pytest
 
 from chipbench.evidence import parse_prometheus
+from chipbench import costs, costs_deepseek_v2
 from chipbench.readers import (client_samples, prometheus_delta,
-                               prometheus_gauge, train_rate)
+                               prometheus_gauge, serve_mfu, train_rate)
 
 
 def sample(i, due, first, last, n, timed=True, prompt_len=128, end="done"):
@@ -179,3 +180,57 @@ def test_decode_roofline_by_hand():
                   samples=[first], sizes=sizes, param_dtype="bfloat16")
     assert cost_ratio.read(ev, "decode_hbm_roofline",
                            match="step_paged") == pytest.approx(20.0)
+
+
+# ------------------------------------------------------------- serve_mfu
+def _config(name):
+    import json
+
+    from ._util import REPO
+    with open(REPO / "chipbench" / "configs" / f"{name}.json") as fh:
+        return json.load(fh)
+
+
+@pytest.mark.parametrize("name, module, counters, body_params", [
+    # 16 blocks of 218,103,808 matrix parameters
+    ("mistral-7b-l16-serve", "costs", None, 16 * 218_103_808),
+    # attention 149.2 M x 5, the dense MLP, and per expert layer the
+    # shared experts, the router and 6 x 0.3 picks on held experts
+    ("deepseek-v2-l5-e40-serve", "costs_deepseek_v2", (1000, 300), None)])
+def test_serve_mfu_counts_served_tokens_and_the_prompts_that_started(
+        name, module, counters, body_params):
+    cfg = _config(name)
+    run = SimpleNamespace(device={"platform": "tpu", "kind": "TPU v5 lite"})
+    ev = evidence(
+        run=run, sizes=cfg, chips=1, window=[10.0, 20.0], samples=[
+            # first token inside the window: its 128 prompt tokens count,
+            # and its 11 tokens (all inside)
+            sample(0, due=10.0, first=10.5, last=11.5, n=11),
+            # first token before the window: only the 5 tokens inside
+            sample(1, due=9.0, first=9.5, last=10.4, n=10,
+                   prompt_len=4096)])
+    if counters:
+        ev.prom_start = ("serving_moe_picks_total 0\n"
+                         "serving_moe_held_picks_total 0\n")
+        ev.prom_end = (f"serving_moe_picks_total {counters[0]}\n"
+                       f"serving_moe_held_picks_total {counters[1]}\n")
+        d = costs_deepseek_v2
+        body_params = (5 * d.attention_params(cfg) + d.dense_mlp_params(cfg)
+                       + 4 * (d.shared_params(cfg) + d.router_params(cfg)
+                              + 6 * 0.3 * d.expert_params(cfg)))
+        # without the counters: held / scored experts = 40 / 160
+        assert d.serve_token_flops(cfg)["body"] < 2.0 * body_params
+    head = cfg["hidden_size"] * cfg["vocab_size"]
+    by_hand = 2.0 * ((16 + 128) * body_params + (16 + 1) * head)
+    assert serve_mfu.read(ev, module) == pytest.approx(
+        100 * by_hand / (197e12 * 10.0), rel=1e-9)
+    assert 0 < serve_mfu.read(ev, module) < 1
+    run.device["platform"] = "cpu"
+    assert serve_mfu.read(ev, module) is None
+
+
+def test_serve_mfu_at_the_readings_of_a_saturated_window_is_under_100():
+    """Mistral at 950 served and 4,000 prompt tokens a second: 18%."""
+    per = costs.serve_token_flops(_config("mistral-7b-l16-serve"))
+    flops = 950 * (per["body"] + per["head"]) + 4000 * per["body"]
+    assert 15 < 100 * flops / 197e12 < 20

@@ -64,6 +64,66 @@ def test_breakdown_by_hand():
     assert len(out["device_ops"]) <= 10 and len(out["idle_gaps"]) <= 10
 
 
+def nested_trace():
+    """One device, window 0..100 ms. A ``conditional`` runs 10..30 and
+    holds a fusion 12..20 and a copy 20..28 (events of the same line); a
+    fusion of the same kind runs alone 40..45. The program's spans: a
+    step 0..60 holding an admission 31..39 holding ``row_init`` 33..38,
+    and a dispatch 46..60 on a second thread's list order."""
+    ops = [("%conditional.1 = bf16[32,128]{1,0} conditional(%p)",
+            10 * MS, 30 * MS),
+           ("%fusion.7 = bf16[32,128]{1,0} fusion(%a), kind=kLoop",
+            12 * MS, 20 * MS),
+           ("%copy.3 = bf16[64]{0} copy(%b)", 20 * MS, 28 * MS),
+           ("%fusion.9 = bf16[32,128]{1,0} fusion(%a), kind=kLoop",
+            40 * MS, 45 * MS)]
+    modules = [("jit_step(1)", 10 * MS, 30 * MS),
+               ("jit_row(2)", 40 * MS, 45 * MS)]
+    host = [(tr.BEGIN_MARK, -1 * MS, 0), (tr.END_MARK, 100 * MS, 101 * MS),
+            ("elephas.loop.decode.dispatch", 46 * MS, 60 * MS),
+            ("elephas.loop.prefill.row_init", 33 * MS, 38 * MS),
+            ("elephas.loop.step", 0, 60 * MS),
+            ("elephas.loop.admit.request", 31 * MS, 39 * MS)]
+    return tr.Trace({0: {"ops": ops, "modules": modules}}, host)
+
+
+def test_a_conditional_and_the_operations_inside_it_count_once():
+    trace = nested_trace()
+    ops = dict(trace.top_ops())
+    # the conditional's own time is 20 - 8 - 8 = 4 ms; the two fusions
+    # add up under one kind
+    assert ops == {"conditional bf16[32,128]": pytest.approx(0.004),
+                   "fusion bf16[32,128]": pytest.approx(0.013),
+                   "copy bf16[64]": pytest.approx(0.008)}
+    assert sum(ops.values()) == pytest.approx(trace.busy_s())
+
+
+@pytest.mark.parametrize("ops, want", [
+    ([("a", 0, 10)], [["a", 10]]),
+    ([("a", 0, 10), ("b", 2, 4), ("c", 4, 9)], [["a", 3], ["b", 2],
+                                                 ["c", 5]]),
+    # two levels: c inside b inside a
+    ([("a", 0, 10), ("b", 1, 9), ("c", 2, 3)], [["a", 2], ["b", 7],
+                                                 ["c", 1]]),
+    # clipped to the window 5..20: a keeps 5..10, b is outside
+    ([("a", 0, 10), ("b", 1, 4), ("d", 12, 30)], [["a", 5], ["d", 8]])])
+def test_own_time_of_nested_operations(ops, want):
+    lo, hi = (5, 20) if len(ops) == 3 and ops[2][0] == "d" else (0, 100)
+    assert tr.own_ns(ops, lo, hi) == want
+
+
+def test_idle_gaps_are_named_by_the_innermost_program_span():
+    gaps = dict(nested_trace().idle_gaps())
+    assert gaps == {
+        # 0..10: under the step span alone
+        "before elephas.loop.step > jit_step": pytest.approx(0.010),
+        # 30..40: its middle (35) lies in row_init, inside the admission
+        "before elephas.loop.prefill.row_init > jit_row":
+            pytest.approx(0.010),
+        # 45..100: its middle (72.5) is under no span
+        "before end of window": pytest.approx(0.055)}
+
+
 def test_a_trace_with_no_marks_spans_its_device_events():
     trace = hand_trace()
     bare = tr.Trace(trace.devices, [])
