@@ -19,7 +19,7 @@ of f32; parameters and the softmax/loss stay f32 for stability.
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1729,8 +1729,31 @@ def prefill_cache(params: Dict, tokens: jnp.ndarray,
     return logits, new_cache
 
 
+#: widths in :func:`prefill_ladder`: the row's length, halved three times
+PREFILL_RUNGS = 4
+
+
+def prefill_ladder(chunk: int, length: int) -> Tuple[int, ...]:
+    """Widths for :func:`decode_block`'s ``attend_widths`` when a row of
+    ``length`` cached positions is filled ``chunk`` tokens at a time,
+    derived from the shapes: the row's length halved
+    ``PREFILL_RUNGS - 1`` times, none narrower than a chunk (512 /
+    1,024 / 2,048 for 512-token chunks of a 2,048-long row)."""
+    return tuple(sorted({max(length >> k, min(chunk, length))
+                         for k in range(PREFILL_RUNGS)}))
+
+
+def attend_width(widths: Sequence[int], need: int) -> int:
+    """The width :func:`decode_block` picks on the device for a block
+    whose last position is ``need - 1``: the host's copy of its
+    arithmetic, for counters."""
+    return next((w for w in widths if w >= need), widths[-1])
+
+
 def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
-                 config: TransformerConfig) -> Tuple[jnp.ndarray, Dict]:
+                 config: TransformerConfig,
+                 attend_widths: Sequence[int] = ()
+                 ) -> Tuple[jnp.ndarray, Dict]:
     """Multi-token cached decode: process ``(batch, S)`` tokens sitting
     at positions ``pos0 .. pos0+S-1`` of an ongoing sequence, reading and
     writing the rolling k/v cache, and return (logits ``(batch, S,
@@ -1750,12 +1773,35 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
     query attends causally: cache positions ``<= pos0+j`` for block slot
     ``j`` (all S slots' k/v are written before attention, so intra-block
     attention sees the new keys).
+
+    What the block WRITES goes into the whole row. What its attention
+    READS is the first ``W`` cached positions: by default the row's
+    whole length, which is what the S=1 step and the speculative verify
+    block (a vector ``pos0``, each row at its own offset) run at. With
+    ``attend_widths`` (ascending, the last one the row's length: e.g.
+    :func:`prefill_ladder`) the program holds per layer a branch for
+    each width and picks, on the device, the narrowest ``W >= pos0 + S``
+    -- for a vector ``pos0`` from the largest offset. Everything at and
+    beyond ``pos0 + S`` has zero weight under the causal mask, so every
+    width that covers it gives the same result: the mask, the K/V (for
+    ``attention_kind="mla"`` the latent's expansion to per-head keys and
+    values), both score products, the softmax and the value product run
+    ``W`` wide instead of ``max_len`` wide. A chunk of a prompt that
+    fills a quarter of its row does a quarter of the attention.
     """
     c = config
     b, s = tokens.shape
     pos0 = jnp.asarray(pos0)
     vec = pos0.ndim == 1
     length = jax.tree_util.tree_leaves(cache)[0].shape[2]
+    widths = tuple(int(w) for w in attend_widths) or (length,)
+    if list(widths) != sorted(set(widths)) or widths[-1] != length:
+        raise ValueError(f"attend_widths {widths} must ascend to the "
+                         f"row's length, {length}")
+    # the narrowest width that covers every position a query may see,
+    # picked on the device (a single width is no branch)
+    pick = jnp.searchsorted(jnp.asarray(widths), jnp.max(pos0) + s,
+                            side="left")
     blockpos = (pos0[:, None] + jnp.arange(s)[None, :] if vec
                 else pos0 + jnp.arange(s))             # (B, S) or (S,)
     x = params["embed"]["tokens"][tokens]
@@ -1764,13 +1810,47 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
     elif c.positional == "sinusoidal":
         x = x + _sinusoidal_table(blockpos, c.d_model)
     x = x.astype(c.dtype)                              # (B, S, D)
-    kpos = jnp.arange(length)
     qp = blockpos if vec else blockpos[None, :]        # (B|1, S)
-    mask = kpos[None, None, :] <= qp[:, :, None]       # (B|1, S, L)
-    if c.attention_window is not None:
-        mask = mask & (kpos[None, None, :]
-                       > qp[:, :, None] - c.attention_window)
     scale = 1.0 / math.sqrt(c.head_dim)
+    groups = c.num_heads // c.kv_heads
+
+    def mask_over(kpos):
+        mask = kpos[None, None, :] <= qp[:, :, None]   # (B|1, S, W)
+        if c.attention_window is not None:
+            mask = mask & (kpos[None, None, :]
+                           > qp[:, :, None] - c.attention_window)
+        return mask
+
+    def latent_attend_over(width):
+        # the first `width` positions are all a query may see when they
+        # cover pos0 + S
+        return lambda attn, q_nope, q_rope, buf: _mla.attend_expanded(
+            attn, q_nope, q_rope, buf[:, 0, :width],
+            mask_over(jnp.arange(width)), c)
+
+    def attend_over(width):
+        def attend(qg, lc):
+            ck, cv = lc["k"][:, :, :width], lc["v"][:, :, :width]
+            if c.kv_cache_quant:
+                ck = (ck * lc["k_scale"][:, :, :width]).astype(c.dtype)
+                cv = (cv * lc["v_scale"][:, :, :width]).astype(c.dtype)
+            kpos = jnp.arange(width)
+            scores = jnp.einsum("bngsk,bntk->bngst", qg, ck) * scale
+            if c.positional == "alibi":
+                dist = (qp[:, :, None] - kpos[None, None, :]).astype(
+                    jnp.float32)                       # (B|1, S, W)
+                ab = (-_alibi_slopes(c.num_heads)[None, :, None, None]
+                      * dist[:, None]).reshape(
+                          dist.shape[0], c.kv_heads, groups, s, width)
+                scores = scores + ab
+            scores = jnp.where(mask_over(kpos)[:, None, None, :, :],
+                               scores, NEG_INF)
+            weights = jax.nn.softmax(scores, axis=-1)
+            return jnp.einsum("bngst,bntk->bngsk", weights, cv)
+        return attend
+
+    branches = [(latent_attend_over if c.attention_kind == "mla"
+                 else attend_over)(w) for w in widths]
     # rope angle positions: (B, 1, S) broadcasts per-row angles over the
     # head axis of (B, H, S, K); a (S,) vector broadcasts over B and H
     rp = blockpos[:, None, :] if vec else blockpos
@@ -1778,7 +1858,6 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
         bidx = jnp.arange(b)[:, None, None]
         hidx = jnp.arange(c.kv_heads)[None, :, None]
         widx = (bidx, hidx, blockpos[:, None, :])      # -> (B, H, S)
-    groups = c.num_heads // c.kv_heads
     new_cache: Dict = {}
     for i in range(c.num_layers):
         layer = params[f"layer_{i}"]
@@ -1795,8 +1874,8 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
                 buf = jax.lax.dynamic_update_slice(
                     buf, new[:, None].astype(buf.dtype), (0, 0, pos0, 0))
             new_cache[f"layer_{i}"] = {"latent": buf}
-            x = x + _mla.attend_expanded(layer["attn"], q_nope, q_rope,
-                                         buf[:, 0], mask, c)
+            x = x + jax.lax.switch(pick, branches, layer["attn"], q_nope,
+                                   q_rope, buf)
             x, _ = _mlp_sublayer(layer, x, c, i)
             continue
         q = jnp.einsum("bsd,dhk->bhsk", h,
@@ -1819,28 +1898,16 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
         if c.kv_cache_quant:
             kq8, ks = _kv_quantize(k_new)
             vq8, vs = _kv_quantize(v_new)
-            ck8, cks = write(lc["k"], kq8), write(lc["k_scale"], ks)
-            cv8, cvs = write(lc["v"], vq8), write(lc["v_scale"], vs)
-            new_cache[f"layer_{i}"] = {"k": ck8, "k_scale": cks,
-                                       "v": cv8, "v_scale": cvs}
-            ck = (ck8 * cks).astype(c.dtype)
-            cv = (cv8 * cvs).astype(c.dtype)
+            written = {"k": write(lc["k"], kq8),
+                       "k_scale": write(lc["k_scale"], ks),
+                       "v": write(lc["v"], vq8),
+                       "v_scale": write(lc["v_scale"], vs)}
         else:
-            ck = write(lc["k"], k_new)
-            cv = write(lc["v"], v_new)
-            new_cache[f"layer_{i}"] = {"k": ck, "v": cv}
+            written = {"k": write(lc["k"], k_new),
+                       "v": write(lc["v"], v_new)}
+        new_cache[f"layer_{i}"] = written
         qg = q.reshape(b, c.kv_heads, groups, s, c.head_dim)
-        scores = jnp.einsum("bngsk,bntk->bngst", qg, ck) * scale
-        if c.positional == "alibi":
-            dist = (qp[:, :, None] - kpos[None, None, :]).astype(
-                jnp.float32)                           # (B|1, S, L)
-            ab = (-_alibi_slopes(c.num_heads)[None, :, None, None]
-                  * dist[:, None]).reshape(
-                      dist.shape[0], c.kv_heads, groups, s, length)
-            scores = scores + ab
-        scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
-        weights = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bngst,bntk->bngsk", weights, cv)
+        o = jax.lax.switch(pick, branches, qg, written)
         o = o.reshape(b, c.num_heads, s, c.head_dim)
         x = x + jnp.einsum("bhsk,hkd->bsd", o,
                            layer["attn"]["wo"].astype(c.dtype))

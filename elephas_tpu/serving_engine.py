@@ -111,9 +111,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .models.transformer import (NEG_INF, TransformerConfig, chunked_blocks,
-                                 decode_block, decode_step, init_kv_cache,
-                                 prefill_cache)
+from .models.transformer import (NEG_INF, TransformerConfig, attend_width,
+                                 chunked_blocks, decode_block, decode_step,
+                                 init_kv_cache, prefill_cache,
+                                 prefill_ladder)
 from .obs.context import current_context, use_context
 from .obs.events import FlightRecorder
 from .obs.events import emit as emit_event
@@ -267,7 +268,12 @@ class DecodeEngine:
         lengths: an online server sees at most ``prefill_chunk`` block
         shapes ever, instead of one compile per new length. Numerically
         identical to whole-prompt prefill; composes with prefix caching
-        (the suffix is what gets chunked).
+        (the suffix is what gets chunked). A chunk attends over the
+        narrowest width of a small ladder (``max_len`` halved three
+        times, none narrower than a chunk) that covers the positions
+        its row holds, picked on the device inside the one program a
+        block shape has; ``serving_prefill_positions_held_total`` /
+        ``..._read_total`` count what that saves.
     :param paged: ``(num_blocks, block_size)`` switches the KV cache to
         a shared block pool with per-slot block tables (vLLM's paged
         memory model): cache memory scales with tokens in flight
@@ -829,6 +835,28 @@ class DecodeEngine:
                       ).set_function(
                 lambda: float(len(e._free_block_ids))
                 if (e := ref()) is not None else 0.0)
+        # widths of an admission chunk's attention, derived from the
+        # shapes (no chunks, no ladder: a whole-prompt extend reads the
+        # whole row)
+        self._prefill_ladder = (
+            () if self.prefill_chunk is None
+            else prefill_ladder(self.prefill_chunk, self.max_len))
+        if self._prefill_ladder:
+            self._m_positions_held = reg.counter(
+                "serving_prefill_positions_held_total",
+                "cached positions an admission chunk's queries could see "
+                "(its last position + 1), summed over chunks").labels()
+            self._m_positions_read = reg.counter(
+                "serving_prefill_positions_read_total",
+                "cached positions the chunk's attention ran over (the "
+                "ladder width it picked), summed over chunks; held / "
+                "read is the share that was not masked away").labels()
+            fam = reg.counter(
+                "serving_prefill_chunks_total",
+                "admission chunks by the width of their attention",
+                labels=("width",))
+            self._m_chunks_by_width = {
+                w: fam.labels(width=str(w)) for w in self._prefill_ladder}
         self._m_interleaved = reg.counter(
             "serving_prefill_chunks_interleaved_total",
             "prompt-prefill chunks fed between decode steps by the "
@@ -1006,6 +1034,7 @@ class DecodeEngine:
                     big, row[0], slot, 0), cache, row_cache)
 
         max_len = self.max_len
+        chunk_widths = self._prefill_ladder
 
         @jax.jit
         def _prefill(params, prompt):
@@ -1022,9 +1051,12 @@ class DecodeEngine:
             # per block
             def _extend(params, row_cache, suffix, pos0):
                 # continue a batch-1 prefill past what the row cache
-                # already holds: the suffix attends to the cached k/v
-                logits, row_cache = decode_block(params, row_cache,
-                                                 suffix, pos0, xcfg)
+                # already holds: the suffix attends to the cached k/v,
+                # over the narrowest width of the ladder that covers it
+                # (one program a suffix shape, a branch a width)
+                logits, row_cache = decode_block(
+                    params, row_cache, suffix, pos0, xcfg,
+                    attend_widths=chunk_widths)
                 return logits[:, -1], row_cache
             if donate:
                 return partial(jax.jit, donate_argnums=(1,))(_extend)
@@ -1375,10 +1407,21 @@ class DecodeEngine:
         operate on engine-owned intermediates."""
         def block(cache, blk, pos, first):
             fn = extend_owned_fn if (owned or not first) else extend_fn
-            return fn(params, cache, jnp.asarray(blk), jnp.int32(pos))
+            return self._extend_block(fn, params, cache, blk, pos)
 
         return chunked_blocks(block, row, tokens[None], int(pos0),
                               self.prefill_chunk)
+
+    def _extend_block(self, fn, params, row, blk: np.ndarray, pos: int):
+        """Dispatch one admission chunk ``blk`` (1, S) at position
+        ``pos`` of ``row``, and count what its attention reads: the
+        host's copy of the width the program picks on the device."""
+        need = pos + blk.shape[1]
+        width = attend_width(self._prefill_ladder, need)
+        self._m_positions_held.inc(need)
+        self._m_positions_read.inc(width)
+        self._m_chunks_by_width[width].inc()
+        return fn(params, row, jnp.asarray(blk), jnp.int32(pos))
 
     def _prefill_with_prefixes(self, prompt: np.ndarray, extend_fn,
                                extend_owned_fn, prefill_fn, params, entry,
@@ -3541,9 +3584,8 @@ class DecodeEngine:
         fn = (self._extend_owned_fn if (st["owned"] or not st["first"])
               else self._extend_fn)
         with use_context(st["ctx"]):
-            st["logits"], st["row"] = fn(
-                self.params, st["row"], jnp.asarray(blk[None]),
-                jnp.int32(st["reused"] + cur))
+            st["logits"], st["row"] = self._extend_block(
+                fn, self.params, st["row"], blk[None], st["reused"] + cur)
         st["cursor"] = cur + int(blk.size)
         st["first"] = False
         self._m_interleaved.inc()

@@ -438,3 +438,42 @@ def test_the_real_size_step_compiles_for_a_v5e_and_fits(v5e):
     # the experts run as grouped matmuls (3 a layer, 4 expert layers),
     # not as 40 dense ones
     assert compiled.as_text().count("ragged-dot-none") >= 12
+
+
+def test_the_real_size_chunk_program_holds_a_branch_a_rung(v5e):
+    """The admission program of a 512-token chunk at the cell's sizes:
+    per layer one ``conditional`` over the ladder's three widths, and
+    the donated row is rewritten in place."""
+    from jax.sharding import SingleDeviceSharding
+
+    from elephas_tpu.models.transformer import decode_block, prefill_ladder
+
+    eng = PUBLISHED["engine"]
+    cfg = FAMILY.program_config(PUBLISHED, eng["max_len"], "bfloat16")
+    where = SingleDeviceSharding(v5e.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=where), tree)
+
+    params = on_chip(jax.eval_shape(lambda k: init_params(cfg, k),
+                                    jax.random.PRNGKey(0)))
+    row = on_chip(jax.eval_shape(
+        lambda: init_kv_cache(cfg, 1, eng["max_len"])))
+    ladder = prefill_ladder(eng["prefill_chunk"], eng["max_len"])
+    assert ladder == (512, 1024, 2048)
+    extend = jax.jit(lambda p, r, t, pos: decode_block(
+        p, r, t, pos, cfg, attend_widths=ladder), donate_argnums=(1,))
+    compiled = extend.lower(
+        params, row,
+        jax.ShapeDtypeStruct((1, eng["prefill_chunk"]), jnp.int32,
+                             sharding=where),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=where)).compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") == cfg.num_layers
+    assert text.count("branch_computations={") == cfg.num_layers
+    mem = compiled.memory_analysis()
+    row_bytes = sum(a.size * a.dtype.itemsize
+                    for a in jax.tree_util.tree_leaves(row))
+    assert mem.alias_size_in_bytes >= row_bytes
+    assert mem.temp_size_in_bytes < 1.0e9
