@@ -82,15 +82,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import mla as _mla
+from . import mamba2 as _mamba
 from .transformer import (NEG_INF, TransformerConfig, _alibi_slopes,
-                          _apply_rope, _mlp_sublayer, _norm,
-                          _sinusoidal_table, head_logits)
+                          _apply_rope, _attn_out, _mlp_sublayer, _norm,
+                          _qkv, _sinusoidal_table, _times, _with_mixer,
+                          head_logits)
 
 __all__ = ["init_paged_pool", "decode_step_paged", "decode_block_paged",
            "held_block_count", "held_tile", "held_ladder",
            "LATENT_TILE_BLOCKS",
            "install_row_paged", "gather_blocks_to_row",
            "validate_paged_config", "require_per_head_cache",
+           "require_stateless_cache",
            "export_kv_blocks",
            "import_kv_blocks", "export_pool_blocks",
            "install_pool_blocks"]
@@ -121,6 +124,19 @@ def require_per_head_cache(config: TransformerConfig, what: str):
                          "not available with attention_kind='mla'")
 
 
+def require_stateless_cache(config: TransformerConfig, what: str):
+    """Refuse ``what`` for a config whose rows keep recurrent state
+    (``config.ssm``): whatever skips, moves or rolls back cached
+    POSITIONS (prefix reuse, preemption, the KV tiers, the disaggregated
+    wire, the speculative verify block) would need the state as it
+    stood at that position, and nobody keeps such a snapshot yet."""
+    if config.ssm is not None:
+        raise ValueError(
+            f"{what} needs a snapshot of the recurrent state at a block "
+            "boundary, which is not kept yet: it is not available with a "
+            "state-space mixer (config.ssm)")
+
+
 def held_tile(config: TransformerConfig) -> int:
     """Blocks a row's share of the decode step's flat list is padded to
     a multiple of: 1 for per-head k/v, :data:`LATENT_TILE_BLOCKS` for
@@ -141,26 +157,44 @@ def held_ladder(config: TransformerConfig, rows: int,
 
 
 def init_paged_pool(config: TransformerConfig, num_blocks: int,
-                    block_size: int) -> Dict:
+                    block_size: int, slots: int = 0) -> Dict:
     """Shared block pool: per layer one array per cache leaf
     (``config.cache_leaves()``) of shape ``(num_blocks, heads,
     block_size, width)`` -- ``k``/``v`` with ``(kv_heads, head_dim)``, or
     the one ``latent`` leaf of ``attention_kind="mla"``. Block 0 is the
-    reserved scratch sink (allocators must hand out ids >= 1)."""
+    reserved scratch sink (allocators must hand out ids >= 1).
+
+    A config whose rows keep state (``config.state_leaves()``) gets,
+    under ``pool["state"]``, per layer one ``(slots, ...)`` array a
+    state leaf beside the blocks: two kinds of cache in one tree, the
+    positions by block table, the state by slot. ``slots`` is the batch
+    width of the step that will run over the pool."""
     validate_paged_config(config)
     c = config
-    return {f"layer_{i}": {
+    pool = {f"layer_{i}": {
         leaf: jnp.zeros((num_blocks, heads, block_size, width), c.dtype)
         for leaf, (heads, width) in c.cache_leaves().items()}
         for i in range(c.num_layers)}
+    if c.ssm is not None:
+        if slots < 1:
+            raise ValueError("a pool for a config with per-slot state "
+                             "needs slots >= 1")
+        pool["state"] = _mamba.zero_state(c, int(slots))
+    return pool
 
 
 def _block_size(pool: Dict) -> int:
-    return jax.tree_util.tree_leaves(pool)[0].shape[2]
+    return jax.tree_util.tree_leaves(pool["layer_0"])[0].shape[2]
+
+
+def _blocks(tree: Dict) -> Dict:
+    """The per-position part of a pool or of a row cache: everything
+    but the per-slot ``state``."""
+    return {name: sub for name, sub in tree.items() if name != "state"}
 
 
 def install_row_paged(pool: Dict, row_cache: Dict, block_ids,
-                      nblocks: int, start: int = 0) -> Dict:
+                      nblocks: int, start: int = 0, slot=None) -> Dict:
     """Scatter a contiguous batch-1 prefill row into pool blocks:
     positions ``[start*block_size, nblocks*block_size)`` of
     ``row_cache`` land in ``block_ids[start:nblocks]``. ``start > 0``
@@ -168,12 +202,22 @@ def install_row_paged(pool: Dict, row_cache: Dict, block_ids,
     point at SHARED cached blocks that already hold those positions —
     writing them again would be wasted HBM traffic over blocks other
     slots are reading. One jit specialization per ``(start, nblocks)``
-    pair (both bounded by the per-slot table width)."""
-    return _install_jit(pool, row_cache, jnp.asarray(block_ids),
-                        nblocks, start)
+    pair (both bounded by the per-slot table width).
+
+    A row that carries state (``row_cache["state"]``) also needs
+    ``slot``: its state overwrites that slot's, whatever the slot held
+    before (the row that retired there, or what a step made of it
+    since)."""
+    if "state" in pool and slot is None:
+        raise ValueError("a pool with per-slot state installs a row "
+                         "into a slot: give slot=")
+    return _install_jit(pool, row_cache, jnp.asarray(block_ids), nblocks,
+                        start, jnp.asarray(slot, jnp.int32)
+                        if "state" in pool else None)
 
 
-def _install(pool, row_cache, block_ids, nblocks: int, start: int = 0):
+def _install(pool, row_cache, block_ids, nblocks: int, start: int = 0,
+             slot=None):
     n_write = nblocks - start
     bs = _block_size(pool)
     ids = block_ids[start:nblocks]
@@ -191,7 +235,14 @@ def _install(pool, row_cache, block_ids, nblocks: int, start: int = 0):
         return big.at[ids].set(jnp.swapaxes(
             chunk.reshape(h, n_write, bs, d), 0, 1))
 
-    return jax.tree_util.tree_map(to_blocks, pool, row_cache)
+    new = jax.tree_util.tree_map(to_blocks, _blocks(pool),
+                                 _blocks(row_cache))
+    if slot is not None:
+        new["state"] = jax.tree_util.tree_map(
+            lambda big, row: jax.lax.dynamic_update_index_in_dim(
+                big, row[0].astype(big.dtype), slot, 0),
+            pool["state"], row_cache["state"])
+    return new
 
 
 _install_jit = jax.jit(_install, static_argnums=(3, 4),
@@ -551,7 +602,7 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
                               axis=1)[:, 0]        # (B,) owning block
     off = pos % bs
 
-    x = params["embed"]["tokens"][tokens]          # (B, D)
+    x = _times(params["embed"]["tokens"][tokens], c, "embedding")  # (B, D)
     if c.positional == "learned":
         x = x + params["embed"]["pos"][pos]
     elif c.positional == "sinusoidal":
@@ -601,6 +652,12 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
         # device from what the step is given anyway
         pick = jnp.searchsorted(jnp.asarray(widths), held, side="left")
     new_pool: Dict = {}
+    new_state: Dict = {}
+    if c.ssm is not None and jax.tree_util.tree_leaves(
+            pool["state"])[0].shape[0] != b:
+        raise ValueError("the pool's per-slot state has another number of "
+                         f"slots than the step has rows ({b}): row r is "
+                         "slot r")
     live = pos > 0
     stats = []
     for i in range(c.num_layers):
@@ -622,12 +679,7 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
             if st is not None:
                 stats.append(st)
             continue
-        q = jnp.einsum("bsd,dhk->bhsk", h,
-                       layer["attn"]["wq"].astype(c.dtype))
-        k_new = jnp.einsum("bsd,dhk->bhsk", h,
-                           layer["attn"]["wk"].astype(c.dtype))
-        v_new = jnp.einsum("bsd,dhk->bhsk", h,
-                           layer["attn"]["wv"].astype(c.dtype))
+        q, k_new, v_new = _qkv(layer, h, c)
         if c.positional == "rope":
             q = _apply_rope(q, rp, c)
             k_new = _apply_rope(k_new, rp, c)
@@ -644,14 +696,20 @@ def decode_step_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
         # (a single width is no branch: lax.switch calls it)
         o = jax.lax.switch(pick, branches, qg, pk, pv).reshape(
             b, c.num_heads, 1, c.head_dim)
-        x = x + jnp.einsum("bhsk,hkd->bsd", o,
-                           layer["attn"]["wo"].astype(c.dtype))
+        # (a config with a mixer: row r's state is slot r's, read and
+        # written once, for every row of the batch -- a row that is not
+        # live leaves a state that its slot's next install overwrites)
+        x = x + _with_mixer(_attn_out(layer, o, c), layer, h,
+                            pool.get("state"), new_state, i, c)
         x, st = _mlp_sublayer(layer, x, c, i, live=live[:, None])
         if st is not None:
             stats.append(st)
+    if new_state:
+        new_pool["state"] = new_state
     logits = head_logits(params["embed"], params["final_ln"], x[:, 0],
                          head=params.get("head"), norm=c.norm,
-                         rms_norm_eps=c.rms_norm_eps)
+                         rms_norm_eps=c.rms_norm_eps,
+                         multipliers=c.multipliers)
     if with_stats:
         return logits, new_pool, None if not stats else {
             "counts": sum(st["counts"] for st in stats),
@@ -682,6 +740,11 @@ def decode_block_paged(params: Dict, pool: Dict, tables: jnp.ndarray,
     c = config
     require_per_head_cache(c, "decode_block_paged (the speculative "
                               "verify pass)")
+    require_stateless_cache(c, "decode_block_paged (the speculative "
+                               "verify pass, which rolls rows back)")
+    if c.multipliers is not None:
+        raise ValueError("decode_block_paged has no form with "
+                         "multipliers yet")
     b, s = tokens.shape
     bs = _block_size(pool)
     mb = tables.shape[1]

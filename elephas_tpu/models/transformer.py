@@ -27,11 +27,38 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import grouped_experts as _gx
+from . import mamba2 as _mamba
 from . import mla as _mla
+from .mamba2 import Mamba2Mixer  # noqa: F401  (part of the config's surface)
 from .mla import YarnScaling  # noqa: F401  (part of the config's surface)
 from ..ops.attention import NEG_INF, attention
 from ..ops.pallas_attention import flash_attention, flash_attention_sharded
 from ..ops.ring_attention import ring_attention_sharded
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """Fixed scalar multipliers a model was trained with (maximal-update
+    parametrisation, Falcon-H1's ``*_multiplier`` keys), each applied
+    where its name says: to the embedding's output, the logits,
+    attention's input, output and keys, the mixer's input, its five
+    projection segments ``[z | x | B | C | dt]`` and its output, and the
+    gated MLP's gate (before the activation) and output."""
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    ssm_in: float = 1.0
+    ssm: Tuple[float, float, float, float, float] = (1.0,) * 5
+    ssm_out: float = 1.0
+    mlp_gate: float = 1.0
+    mlp_down: float = 1.0
+
+    def __post_init__(self):
+        if len(self.ssm) != 5:
+            raise ValueError("ssm takes five multipliers, one a segment "
+                             "of the mixer's projection: z, x, B, C, dt")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,6 +225,18 @@ class TransformerConfig:
     #: router's ``num_experts`` (``swiglu`` experts only; None: all). The
     #: layer routes over every expert and computes its own experts' part
     held_experts: Optional[Tuple[int, int]] = None
+    #: width of an attention head where it is not ``d_model / num_heads``
+    #: (a model whose attention is narrower than its residual stream)
+    attention_head_dim: Optional[int] = None
+    #: a state-space mixer (:mod:`~elephas_tpu.models.mamba2`) beside the
+    #: attention of EVERY layer, on the same normed input, their outputs
+    #: added (a hybrid block); it keeps per-slot state
+    #: (:meth:`state_leaves`) beside the per-position cache. ``mha``
+    #: attention and dense MLPs only. None: attention alone
+    ssm: Optional[Mamba2Mixer] = None
+    #: ``Multipliers`` the model applies at fixed places; None: none, and
+    #: every program is what it is without this field
+    multipliers: Optional[Multipliers] = None
 
     def __post_init__(self):
         if self.attention_impl not in ("auto", "flash", "xla"):
@@ -233,6 +272,15 @@ class TransformerConfig:
                 f"'alibi', got {self.positional!r}")
         if self.positional == "rope" and self.head_dim % 2:
             raise ValueError("rope requires an even head_dim")
+        if self.ssm is not None and (
+                self.attention_kind != "mha" or self.kv_cache_quant
+                or self.num_experts > 1):
+            raise ValueError("a state-space mixer (ssm) runs beside mha "
+                             "attention and a dense MLP, without "
+                             "kv_cache_quant")
+        if self.multipliers is not None and self.loss_vocab_chunk:
+            raise ValueError("loss_vocab_chunk streams the head without "
+                             "multipliers.lm_head: not with multipliers")
         if self.num_kv_heads is not None and (
                 self.num_kv_heads < 1
                 or self.num_heads % self.num_kv_heads):
@@ -303,6 +351,8 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.attention_head_dim is not None:
+            return self.attention_head_dim
         return self.d_model // self.num_heads
 
     def has_experts(self, layer: int) -> bool:
@@ -322,6 +372,16 @@ class TransformerConfig:
             return {"latent": (1, _mla.latent_width(self))}
         return {"k": (self.kv_heads, self.head_dim),
                 "v": (self.kv_heads, self.head_dim)}
+
+    def state_leaves(self) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+        """What one layer keeps per SLOT and not per position, ``{leaf:
+        (shape, dtype)}``: the mixer's ``conv`` inputs and ``ssm`` state
+        for a config with ``ssm``, nothing otherwise. A decode cache
+        holds them under ``cache["state"]["layer_i"]`` with a leading
+        rows (or slots) axis, beside the per-position leaves."""
+        if self.ssm is None:
+            return {}
+        return _mamba.state_leaves(self.ssm, self.dtype)
 
     @property
     def kv_heads(self) -> int:
@@ -363,7 +423,8 @@ def init_params(config: TransformerConfig, key) -> Dict:
                 "wq": dense(lk[0], (c.d_model, c.num_heads, c.head_dim), c.d_model),
                 "wk": dense(lk[1], (c.d_model, c.kv_heads, c.head_dim), c.d_model),
                 "wv": dense(lk[2], (c.d_model, c.kv_heads, c.head_dim), c.d_model),
-                "wo": dense(lk[3], (c.num_heads, c.head_dim, c.d_model), c.d_model),
+                "wo": dense(lk[3], (c.num_heads, c.head_dim, c.d_model),
+                            c.num_heads * c.head_dim),
             }
         layer = {
             "ln1": {"gamma": jnp.ones((c.d_model,), c.param_dtype),
@@ -372,6 +433,9 @@ def init_params(config: TransformerConfig, key) -> Dict:
             "ln2": {"gamma": jnp.ones((c.d_model,), c.param_dtype),
                     "beta": jnp.zeros((c.d_model,), c.param_dtype)},
         }
+        if c.ssm is not None:
+            layer["ssm"] = _mamba.init_mixer(
+                c, jax.random.fold_in(lk[0], 1), dense)
         if c.has_experts(i) and c.expert_variant == "swiglu":
             layer["moe"] = _gx.init_experts(
                 c, jax.random.split(lk[4], 7), dense)
@@ -454,6 +518,8 @@ def param_specs(config: TransformerConfig, model_axis: str = "model",
                      }),
             "ln2": {"gamma": P(None), "beta": P(None)},
         }
+        if config.ssm is not None:
+            layer_specs["ssm"] = _mamba.mixer_specs(P)
         if config.has_experts(i) and config.expert_variant == "swiglu":
             held = _gx.held_range(config)[1]
             layer_specs["moe"] = _gx.expert_specs(
@@ -596,6 +662,33 @@ def _norm(x, sub: Dict, c) -> jnp.ndarray:
     return _layer_norm(x, sub["gamma"], sub["beta"])
 
 
+def _times(x, c, name: str):
+    """``x`` times the config's multiplier ``name``
+    (:class:`Multipliers`); ``x`` itself, and so the program it had, for
+    a config without multipliers (the encoder families' configs have no
+    such field)."""
+    multipliers = getattr(c, "multipliers", None)
+    return x if multipliers is None else x * getattr(multipliers, name)
+
+
+def _qkv(layer: Dict, h: jnp.ndarray, c: TransformerConfig):
+    """Per-head ``q, k, v`` ``(B, heads, T, head_dim)`` of the normed
+    input ``h`` ``(B, T, D)``, before any rotation."""
+    h = _times(h, c, "attention_in")
+    q = jnp.einsum("btd,dhk->bhtk", h, layer["attn"]["wq"].astype(c.dtype))
+    k = jnp.einsum("btd,dhk->bhtk", h, layer["attn"]["wk"].astype(c.dtype))
+    v = jnp.einsum("btd,dhk->bhtk", h, layer["attn"]["wv"].astype(c.dtype))
+    return q, _times(k, c, "key"), v
+
+
+def _attn_out(layer: Dict, o: jnp.ndarray, c: TransformerConfig):
+    """Heads ``o`` ``(B, heads, T, head_dim)`` through the output
+    projection: attention's contribution to the residual stream."""
+    return _times(jnp.einsum("bhtk,hkd->btd", o,
+                             layer["attn"]["wo"].astype(c.dtype)),
+                  c, "attention_out")
+
+
 def _flash_blocks(c: TransformerConfig) -> Dict[str, int]:
     """Configured flash tile overrides as kwargs (empty = kernel defaults)."""
     blocks = {}
@@ -613,9 +706,7 @@ def _attn_apply(layer: Dict, x: jnp.ndarray, c: TransformerConfig,
     residual dropout on the sublayer output (training only)."""
     h = _norm(x, layer["ln1"], c)
     h = h.astype(c.dtype)
-    q = jnp.einsum("btd,dhk->bhtk", h, layer["attn"]["wq"].astype(c.dtype))
-    k = jnp.einsum("btd,dhk->bhtk", h, layer["attn"]["wk"].astype(c.dtype))
-    v = jnp.einsum("btd,dhk->bhtk", h, layer["attn"]["wv"].astype(c.dtype))
+    q, k, v = _qkv(layer, h, c)
     if c.positional == "rope":
         # rotation happens on the logically-global sequence (GSPMD keeps
         # the iota global under sharding), before any ring/flash shard_map
@@ -631,9 +722,12 @@ def _attn_apply(layer: Dict, x: jnp.ndarray, c: TransformerConfig,
         groups = c.num_heads // c.kv_heads
         k = jnp.repeat(k, groups, axis=1)
         v = jnp.repeat(v, groups, axis=1)
-    o = attn_fn(q, k, v)
-    out = jnp.einsum("bhtk,hkd->btd", o,
-                     layer["attn"]["wo"].astype(c.dtype))
+    out = _attn_out(layer, attn_fn(q, k, v), c)
+    if getattr(c, "ssm", None) is not None:
+        # the hybrid block: the mixer reads the same normed input, from
+        # a zero state, and its output is added to attention's
+        out = out + _mamba.mixer_apply(
+            layer["ssm"], h, _mamba.zero_layer_state(c, x.shape[0]), c)[0]
     return x + _dropout(out, c.dropout_rate, dropout_key)
 
 
@@ -643,14 +737,15 @@ def _mlp_apply(layer: Dict, x: jnp.ndarray, c: TransformerConfig,
     h = _norm(x, layer["ln2"], c)
     h = h.astype(c.dtype)
     if getattr(c, "mlp_variant", "gelu") == "swiglu":
-        gate = jax.nn.silu(h @ layer["mlp"]["w1"].astype(c.dtype)
-                           + layer["mlp"]["b1"].astype(c.dtype))
+        gate = jax.nn.silu(_times(h @ layer["mlp"]["w1"].astype(c.dtype)
+                                  + layer["mlp"]["b1"].astype(c.dtype),
+                                  c, "mlp_gate"))
         h = gate * (h @ layer["mlp"]["w3"].astype(c.dtype))
     else:
         h = jax.nn.gelu(h @ layer["mlp"]["w1"].astype(c.dtype)
                         + layer["mlp"]["b1"].astype(c.dtype))
-    h = (h @ layer["mlp"]["w2"].astype(c.dtype)
-         + layer["mlp"]["b2"].astype(c.dtype))
+    h = _times(h @ layer["mlp"]["w2"].astype(c.dtype)
+               + layer["mlp"]["b2"].astype(c.dtype), c, "mlp_down")
     return x + _dropout(h, c.dropout_rate, dropout_key)
 
 
@@ -685,7 +780,7 @@ def embed_apply(embed: Dict, tokens: jnp.ndarray,
     dtype. Shared by the monolithic forward and the pipelined LM entry.
     RoPE configs carry position in the per-layer q/k rotation instead of
     an additive table; sinusoidal adds the parameter-free table."""
-    x = embed["tokens"][tokens]
+    x = _times(embed["tokens"][tokens], config, "embedding")
     if config.positional == "learned":
         x = x + embed["pos"][:tokens.shape[1]]
     elif config.positional == "sinusoidal":
@@ -697,16 +792,18 @@ def embed_apply(embed: Dict, tokens: jnp.ndarray,
 def head_logits(embed: Dict, final_ln: Dict, x: jnp.ndarray,
                 head: Optional[jnp.ndarray] = None,
                 norm: str = "layernorm",
-                rms_norm_eps: float = 1e-5) -> jnp.ndarray:
+                rms_norm_eps: float = 1e-5,
+                multipliers: Optional[Multipliers] = None) -> jnp.ndarray:
     """Final norm + LM head (tied to the embedding unless an untied
-    ``head`` matrix is given); f32 logits for a stable softmax. Shared
-    by the monolithic forward and the pipelined LM exit."""
+    ``head`` matrix is given); f32 logits for a stable softmax, times
+    ``multipliers.lm_head`` where the config has multipliers. Shared by
+    the monolithic forward and the pipelined LM exit."""
     x = x.astype(jnp.float32)
     x = (_rms_norm(x, final_ln["gamma"], rms_norm_eps) if norm == "rmsnorm"
          else _layer_norm(x, final_ln["gamma"], final_ln["beta"]))
-    if head is not None:
-        return x @ head.astype(jnp.float32)
-    return x @ embed["tokens"].T.astype(jnp.float32)
+    logits = (x @ head.astype(jnp.float32) if head is not None
+              else x @ embed["tokens"].T.astype(jnp.float32))
+    return logits if multipliers is None else logits * multipliers.lm_head
 
 
 def next_token_loss(logits: jnp.ndarray, tokens: jnp.ndarray,
@@ -1056,7 +1153,8 @@ def forward_with_aux(params: Dict, tokens: jnp.ndarray,
                                     segment_ids=segment_ids)
     return head_logits(params["embed"], params["final_ln"], x,
                        head=params.get("head"), norm=config.norm,
-                       rms_norm_eps=config.rms_norm_eps), aux_total
+                       rms_norm_eps=config.rms_norm_eps,
+                       multipliers=config.multipliers), aux_total
 
 
 def _hidden_with_aux(params: Dict, tokens: jnp.ndarray,
@@ -1596,10 +1694,15 @@ def init_kv_cache(config: TransformerConfig, batch: int,
                                "v": jnp.zeros(shape, jnp.int8),
                                "v_scale": jnp.zeros(sshape, jnp.float32)}
                 for i in range(c.num_layers)}
-    return {f"layer_{i}": {
+    cache = {f"layer_{i}": {
         leaf: jnp.zeros((batch, heads, length, width), c.dtype)
         for leaf, (heads, width) in c.cache_leaves().items()}
         for i in range(c.num_layers)}
+    if c.ssm is not None:
+        # what a row keeps per SLOT beside its positions: zero is the
+        # state of a row that has seen no token
+        cache["state"] = _mamba.zero_state(c, batch)
+    return cache
 
 
 def _mlp_sublayer(layer: Dict, x: jnp.ndarray, c: TransformerConfig,
@@ -1619,6 +1722,20 @@ def _mlp_sublayer(layer: Dict, x: jnp.ndarray, c: TransformerConfig,
     if c.moe_shared_expert:
         out = out + _shared_expert(h2, layer["moe"]["shared"], c)
     return x + out, None
+
+
+def _with_mixer(out: jnp.ndarray, layer: Dict, h: jnp.ndarray, state: Dict,
+                new_state: Dict, i: int, c: TransformerConfig):
+    """The hybrid block on the cache paths: attention's output ``out``
+    plus layer ``i``'s state-space mixer over the same normed input
+    ``h``, run from the rows' ``state`` (``{"layer_i": ...}``), whose
+    successor goes into ``new_state``. ``out`` itself for a config
+    without a mixer."""
+    if c.ssm is None:
+        return out
+    mixed, new_state[f"layer_{i}"] = _mamba.mixer_apply(
+        layer["ssm"], h, state[f"layer_{i}"], c)
+    return out + mixed
 
 
 def _kv_quantize(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -1663,6 +1780,7 @@ def prefill_cache(params: Dict, tokens: jnp.ndarray,
     mask = mask[None, None]                                  # (1, 1, T, T)
     scale = 1.0 / math.sqrt(c.head_dim)
     new_cache: Dict = {}
+    new_state: Dict = {}
     for i in range(c.num_layers):
         layer = params[f"layer_{i}"]
         h = _norm(x, layer["ln1"], c)
@@ -1678,12 +1796,7 @@ def prefill_cache(params: Dict, tokens: jnp.ndarray,
                                          latent, mask[0], c)
             x, _ = _mlp_sublayer(layer, x, c, i)
             continue
-        q = jnp.einsum("btd,dhk->bhtk", h,
-                       layer["attn"]["wq"].astype(c.dtype))
-        k = jnp.einsum("btd,dhk->bhtk", h,
-                       layer["attn"]["wk"].astype(c.dtype))
-        v = jnp.einsum("btd,dhk->bhtk", h,
-                       layer["attn"]["wv"].astype(c.dtype))
+        q, k, v = _qkv(layer, h, c)
         if c.positional == "rope":
             q = _apply_rope(q, positions, c)
             k = _apply_rope(k, positions, c)
@@ -1720,12 +1833,15 @@ def prefill_cache(params: Dict, tokens: jnp.ndarray,
         weights = jax.nn.softmax(scores, axis=-1)
         o = jnp.einsum("bngqt,bntk->bngqk", weights, v)
         o = o.reshape(b, c.num_heads, t, c.head_dim)
-        x = x + jnp.einsum("bhtk,hkd->btd", o,
-                           layer["attn"]["wo"].astype(c.dtype))
+        x = x + _with_mixer(_attn_out(layer, o, c), layer, h,
+                            cache.get("state"), new_state, i, c)
         x, _ = _mlp_sublayer(layer, x, c, i)
+    if new_state:
+        new_cache["state"] = new_state
     logits = head_logits(params["embed"], params["final_ln"], x[:, -1],
                          head=params.get("head"), norm=c.norm,
-                         rms_norm_eps=c.rms_norm_eps)
+                         rms_norm_eps=c.rms_norm_eps,
+                         multipliers=c.multipliers)
     return logits, new_cache
 
 
@@ -1752,7 +1868,8 @@ def attend_width(widths: Sequence[int], need: int) -> int:
 
 def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
                  config: TransformerConfig,
-                 attend_widths: Sequence[int] = ()
+                 attend_widths: Sequence[int] = (),
+                 last_only: bool = False
                  ) -> Tuple[jnp.ndarray, Dict]:
     """Multi-token cached decode: process ``(batch, S)`` tokens sitting
     at positions ``pos0 .. pos0+S-1`` of an ongoing sequence, reading and
@@ -1788,12 +1905,22 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
     values), both score products, the softmax and the value product run
     ``W`` wide instead of ``max_len`` wide. A chunk of a prompt that
     fills a quarter of its row does a quarter of the attention.
+
+    A config with a state-space mixer (``ssm``) carries the rows' state
+    under ``cache["state"]``: every layer's mixer runs over the block
+    from it (one token: the state's update; more: the chunk scan) and
+    the returned cache holds the state after the block's last token.
+
+    With ``last_only`` the head runs for the block's last position
+    alone and the logits are ``(batch, 1, vocab)``: all a prompt's chunk
+    needs, and at a vocabulary of 261,120 the difference between 1 and
+    512 rows of the largest matrix in the model.
     """
     c = config
     b, s = tokens.shape
     pos0 = jnp.asarray(pos0)
     vec = pos0.ndim == 1
-    length = jax.tree_util.tree_leaves(cache)[0].shape[2]
+    length = jax.tree_util.tree_leaves(cache["layer_0"])[0].shape[2]
     widths = tuple(int(w) for w in attend_widths) or (length,)
     if list(widths) != sorted(set(widths)) or widths[-1] != length:
         raise ValueError(f"attend_widths {widths} must ascend to the "
@@ -1804,7 +1931,7 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
                             side="left")
     blockpos = (pos0[:, None] + jnp.arange(s)[None, :] if vec
                 else pos0 + jnp.arange(s))             # (B, S) or (S,)
-    x = params["embed"]["tokens"][tokens]
+    x = _times(params["embed"]["tokens"][tokens], c, "embedding")
     if c.positional == "learned":
         x = x + params["embed"]["pos"][blockpos]
     elif c.positional == "sinusoidal":
@@ -1859,6 +1986,7 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
         hidx = jnp.arange(c.kv_heads)[None, :, None]
         widx = (bidx, hidx, blockpos[:, None, :])      # -> (B, H, S)
     new_cache: Dict = {}
+    new_state: Dict = {}
     for i in range(c.num_layers):
         layer = params[f"layer_{i}"]
         h = _norm(x, layer["ln1"], c)
@@ -1878,12 +2006,7 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
                                    q_rope, buf)
             x, _ = _mlp_sublayer(layer, x, c, i)
             continue
-        q = jnp.einsum("bsd,dhk->bhsk", h,
-                       layer["attn"]["wq"].astype(c.dtype))
-        k_new = jnp.einsum("bsd,dhk->bhsk", h,
-                           layer["attn"]["wk"].astype(c.dtype))
-        v_new = jnp.einsum("bsd,dhk->bhsk", h,
-                           layer["attn"]["wv"].astype(c.dtype))
+        q, k_new, v_new = _qkv(layer, h, c)
         if c.positional == "rope":
             q = _apply_rope(q, rp, c)
             k_new = _apply_rope(k_new, rp, c)
@@ -1909,12 +2032,16 @@ def decode_block(params: Dict, cache: Dict, tokens: jnp.ndarray, pos0,
         qg = q.reshape(b, c.kv_heads, groups, s, c.head_dim)
         o = jax.lax.switch(pick, branches, qg, written)
         o = o.reshape(b, c.num_heads, s, c.head_dim)
-        x = x + jnp.einsum("bhsk,hkd->bsd", o,
-                           layer["attn"]["wo"].astype(c.dtype))
+        x = x + _with_mixer(_attn_out(layer, o, c), layer, h,
+                            cache.get("state"), new_state, i, c)
         x, _ = _mlp_sublayer(layer, x, c, i)
-    logits = head_logits(params["embed"], params["final_ln"], x,
+    if new_state:
+        new_cache["state"] = new_state
+    logits = head_logits(params["embed"], params["final_ln"],
+                         x[:, -1:] if last_only else x,
                          head=params.get("head"), norm=c.norm,
-                         rms_norm_eps=c.rms_norm_eps)
+                         rms_norm_eps=c.rms_norm_eps,
+                         multipliers=c.multipliers)
     return logits, new_cache
 
 

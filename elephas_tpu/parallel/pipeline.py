@@ -241,7 +241,8 @@ def make_pipelined_lm_loss(config, mesh: Mesh, axis: str = "pipe",
         x = pipe_fn(pipe_params["stages"], x)
         logits = head_logits(pipe_params["embed"], pipe_params["final_ln"],
                              x, head=pipe_params.get("head"),
-                             norm=config.norm)
+                             norm=config.norm,
+                             multipliers=config.multipliers)
         return next_token_loss(logits, tokens)
 
     return loss

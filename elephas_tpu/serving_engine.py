@@ -419,6 +419,10 @@ class DecodeEngine:
         if (draft_params is None) != (draft_config is None):
             raise ValueError("draft_params and draft_config go together")
         if draft_config is not None:
+            from .models.paged_decode import require_stateless_cache
+
+            require_stateless_cache(config, "a draft model (speculative "
+                                            "decoding rolls rows back)")
             if draft_config.vocab_size != config.vocab_size:
                 raise ValueError(
                     f"draft vocab {draft_config.vocab_size} != target "
@@ -504,7 +508,10 @@ class DecodeEngine:
 
             nb, bsz = self.paged
             self.cache = None        # the pool replaces the contiguous cache
-            self.pool = init_paged_pool(config, nb, bsz)
+            # (a config whose rows keep recurrent state gets it per
+            # slot, in the same donated tree as the blocks)
+            self.pool = init_paged_pool(config, nb, bsz,
+                                        slots=self.max_slots)
             self._tables = np.zeros((self.max_slots, self._mb), np.int32)
             self._free_block_ids = deque(range(1, nb))  # 0 = scratch
             self._slot_blocks: List[List[int]] = [
@@ -835,6 +842,11 @@ class DecodeEngine:
                       ).set_function(
                 lambda: float(len(e._free_block_ids))
                 if (e := ref()) is not None else 0.0)
+            # every paged engine says what admission pressure could
+            # reclaim, so that free + reclaimable reads the pool's room
+            # whether or not a prefix cache holds blocks (without one,
+            # as for a config with per-slot state: 0)
+            self._reclaimable_gauge()
         # widths of an admission chunk's attention, derived from the
         # shapes (no chunks, no ladder: a whole-prompt extend reads the
         # whole row)
@@ -857,6 +869,27 @@ class DecodeEngine:
                 labels=("width",))
             self._m_chunks_by_width = {
                 w: fam.labels(width=str(w)) for w in self._prefill_ladder}
+        # a state-space mixer's work and what it keeps (0 for a config
+        # without one): a row's state is read and written once per
+        # decode dispatch and layer, a prompt's tokens are scanned once
+        # per layer, and the slots' state stays on the device
+        self._m_ssm_updates = reg.counter(
+            "serving_ssm_row_updates_total",
+            "recurrent-state updates by decode dispatches: live rows x "
+            "layers with a state-space mixer").labels()
+        self._m_ssm_scanned = reg.counter(
+            "serving_ssm_scan_tokens_total",
+            "tokens run through the chunk scan by admission chunks: a "
+            "chunk's tokens x layers with a state-space mixer").labels()
+        self._ssm_layers = (config.num_layers if config.ssm is not None
+                            else 0)
+        reg.gauge(
+            "serving_ssm_state_bytes",
+            "recurrent state resident on the device: slots x layers x "
+            "the bytes of a row's state leaves").set(float(
+                self.max_slots * self._ssm_layers * sum(
+                    int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+                    for shape, dtype in config.state_leaves().values())))
         self._m_interleaved = reg.counter(
             "serving_prefill_chunks_interleaved_total",
             "prompt-prefill chunks fed between decode steps by the "
@@ -1054,9 +1087,13 @@ class DecodeEngine:
                 # already holds: the suffix attends to the cached k/v,
                 # over the narrowest width of the ladder that covers it
                 # (one program a suffix shape, a branch a width)
+                # (a config with a mixer runs the head for the last
+                # position alone; the others keep the programs they
+                # were compiled as)
                 logits, row_cache = decode_block(
                     params, row_cache, suffix, pos0, xcfg,
-                    attend_widths=chunk_widths)
+                    attend_widths=chunk_widths,
+                    last_only=xcfg.ssm is not None)
                 return logits[:, -1], row_cache
             if donate:
                 return partial(jax.jit, donate_argnums=(1,))(_extend)
@@ -1083,7 +1120,8 @@ class DecodeEngine:
         self._kv_cache = None
         self._kv_cache_bs: Optional[int] = None
         if prefix_cache is None:
-            prefix_cache = self.paged is not None
+            # (a hit skips tokens whose recurrent state nobody kept)
+            prefix_cache = self.paged is not None and config.ssm is None
         if prefix_cache:
             self.enable_prefix_cache(
                 block_size=prefix_cache_block_size,
@@ -1267,7 +1305,7 @@ class DecodeEngine:
 
                 nprefill = -(-length // self.paged[1])
                 self.pool = install_row_paged(
-                    self.pool, row, self._tables[0], nprefill)
+                    self.pool, row, self._tables[0], nprefill, slot=0)
             else:
                 self.cache = self._install_fn(self.cache, row, 0)
             if self.draft_config is not None:
@@ -1289,6 +1327,9 @@ class DecodeEngine:
         holds one batch-1 cache row (``num_layers × kv_heads × max_len ×
         head_dim`` k+v, per model) on device until
         :meth:`clear_prefixes`."""
+        from .models.paged_decode import require_stateless_cache
+
+        require_stateless_cache(self.config, "register_prefix")
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         if tokens.size < 1:
             raise ValueError("prefix must hold at least one token")
@@ -1421,6 +1462,7 @@ class DecodeEngine:
         self._m_positions_held.inc(need)
         self._m_positions_read.inc(width)
         self._m_chunks_by_width[width].inc()
+        self._m_ssm_scanned.inc(blk.shape[1] * self._ssm_layers)
         return fn(params, row, jnp.asarray(blk), jnp.int32(pos))
 
     def _prefill_with_prefixes(self, prompt: np.ndarray, extend_fn,
@@ -1469,6 +1511,10 @@ class DecodeEngine:
         if self._kv_cache is not None:
             return
         from .models.block_cache import BlockCache
+        from .models.paged_decode import require_stateless_cache
+
+        require_stateless_cache(self.config, "the prefix cache "
+                                             "(enable_prefix_cache)")
 
         if self.paged is not None:
             if (block_size is not None
@@ -1513,10 +1559,16 @@ class DecodeEngine:
             lambda: float(len(e._kv_cache))
             if (e := ref()) is not None and e._kv_cache is not None
             else 0.0)
-        reg.gauge("serving_kv_cache_reclaimable_blocks",
-                  "cached blocks on the LRU free list (zero-ref, "
-                  "unpinned — reclaimable by admission pressure)"
-                  ).set_function(
+        self._reclaimable_gauge()
+
+    def _reclaimable_gauge(self) -> None:
+        import weakref
+
+        ref = weakref.ref(self)
+        self.registry.gauge(
+            "serving_kv_cache_reclaimable_blocks",
+            "cached blocks on the LRU free list (zero-ref, unpinned — "
+            "reclaimable by admission pressure)").set_function(
             lambda: float(e._kv_cache.reclaimable_count())
             if (e := ref()) is not None and e._kv_cache is not None
             else 0.0)
@@ -1546,9 +1598,12 @@ class DecodeEngine:
         share one across engines)."""
         if self._kv_spill is not None:
             return self._kv_spill
-        from .models.paged_decode import require_per_head_cache
+        from .models.paged_decode import (require_per_head_cache,
+                                          require_stateless_cache)
 
         require_per_head_cache(self.config, "the KV spill tier (kvtier)")
+        require_stateless_cache(self.config, "the KV spill tier "
+                                             "(enable_kv_spill)")
         if self._kv_cache is None:
             self.enable_prefix_cache()
         from .kvtier import TieredSpill
@@ -1583,6 +1638,10 @@ class DecodeEngine:
         chains hash differently. Implies the prefix cache."""
         if self._session_store is not None:
             return self._session_store
+        from .models.paged_decode import require_stateless_cache
+
+        require_stateless_cache(self.config, "the session store "
+                                             "(enable_session_store)")
         if self._kv_cache is None:
             self.enable_prefix_cache()
         from .kvtier import SessionStore
@@ -2307,10 +2366,13 @@ class DecodeEngine:
         # KV payload failing at admission time would raise inside the
         # server's engine loop and read as engine death (500s for
         # everyone) instead of one bad request's 400
-        from .models.paged_decode import require_per_head_cache
+        from .models.paged_decode import (require_per_head_cache,
+                                          require_stateless_cache)
 
         require_per_head_cache(self.config, "submit_prefilled (the "
                                             "disaggregated wire)")
+        require_stateless_cache(self.config, "submit_prefilled (the "
+                                             "disaggregated wire)")
         prompt_size = int(np.asarray(prompt).size)
         if isinstance(kv_blocks, dict):
             # prebuilt batch-1 row cache (``import_kv_blocks`` output):
@@ -2547,10 +2609,13 @@ class DecodeEngine:
         on plain target-only engines and give the DECODE workers the
         draft (they recompute draft KV at admission)."""
         from .models.paged_decode import (export_kv_blocks,
-                                          require_per_head_cache)
+                                          require_per_head_cache,
+                                          require_stateless_cache)
 
         require_per_head_cache(self.config, "export_prefill (the "
                                             "disaggregated wire)")
+        require_stateless_cache(self.config, "export_prefill (the "
+                                             "disaggregated wire)")
         if self.draft_config is not None:
             raise ValueError(
                 "export_prefill does not compose with speculative mode:"
@@ -3173,6 +3238,11 @@ class DecodeEngine:
         free instead of parking and the request still re-queues
         (resume recomputes; a preemption fault may cost compute, never
         the request)."""
+        from .models.paged_decode import require_stateless_cache
+
+        # (not reachable through _preempt_enabled, which asks for the
+        # prefix cache; refused by name all the same)
+        require_stateless_cache(self.config, "preemption (_preempt_slot)")
         rid = self._rid[slot]
         tenant = self._slot_tenant[slot] or DEFAULT_TENANT
         priority = int(self._slot_priority[slot])
@@ -3325,7 +3395,8 @@ class DecodeEngine:
 
                 nprefill = -(-prompt.size // self.paged[1])
                 self.pool = install_row_paged(
-                    self.pool, row_cache, self._tables[slot], nprefill)
+                    self.pool, row_cache, self._tables[slot], nprefill,
+                    slot=slot)
             else:
                 self.cache = self._install_fn(self.cache, row_cache,
                                               slot)
@@ -3416,7 +3487,7 @@ class DecodeEngine:
         with self._psec("elephas.loop.prefill.install"):
             self.pool = install_row_paged(self.pool, row,
                                           self._tables[slot], nprefill,
-                                          start=j)
+                                          start=j, slot=slot)
             self._insert_full_blocks(slot, prompt, skip=j, rid=rid)
         if self.draft_config is not None:
             # speculative paged admission: the chain hit (or miss) above
@@ -3607,7 +3678,7 @@ class DecodeEngine:
                 nprefill = -(-prompt.size // self.paged[1])
                 self.pool = install_row_paged(
                     self.pool, st["row"], self._tables[slot], nprefill,
-                    start=st["j"])
+                    start=st["j"], slot=slot)
                 # a weight swap landed mid-pendency: the row mixes KV
                 # from two versions — registering it under the NEW
                 # version's chain keys would poison the cache
@@ -3842,7 +3913,8 @@ class DecodeEngine:
             if self.paged is not None:
                 nprefill = -(-prompt.size // self.paged[1])
                 self.pool = install_row_paged(
-                    self.pool, row, self._tables[slot], nprefill)
+                    self.pool, row, self._tables[slot], nprefill,
+                    slot=slot)
             else:
                 self.cache = self._install_fn(self.cache, row, slot)
         if self.draft_config is not None:
@@ -4505,6 +4577,7 @@ class DecodeEngine:
         self._last_set[:] = False
         if flight is not None:
             self._m_ahead.inc()
+        self._m_ssm_updates.inc(int(rows.sum()) * self._ssm_layers)
         key_in = self._key
         with self._psec("elephas.loop.decode.dispatch"):
             last = jnp.asarray(last)
