@@ -211,9 +211,12 @@ def install_row_paged(pool: Dict, row_cache: Dict, block_ids,
     if "state" in pool and slot is None:
         raise ValueError("a pool with per-slot state installs a row "
                          "into a slot: give slot=")
-    return _install_jit(pool, row_cache, jnp.asarray(block_ids), nblocks,
-                        start, jnp.asarray(slot, jnp.int32)
-                        if "state" in pool else None)
+    # host values: the call transfers them, no program converts. A copy
+    # of the ids, because a transfer reads its host array when it runs
+    # and the caller's is a row of a table it goes on writing
+    return _install_jit(pool, row_cache, np.array(block_ids, np.int32),
+                        nblocks, start,
+                        np.int32(slot) if "state" in pool else None)
 
 
 def _install(pool, row_cache, block_ids, nblocks: int, start: int = 0,
@@ -259,7 +262,7 @@ def gather_blocks_to_row(pool: Dict, block_ids, max_len: int) -> Dict:
     extends past them — no recompute of the cached positions, one
     O(prefix) device gather instead. One jit specialization per block
     count (bounded by the per-slot table width)."""
-    return _gather_jit(pool, jnp.asarray(block_ids), int(max_len))
+    return _gather_jit(pool, np.asarray(block_ids, np.int32), int(max_len))
 
 
 @partial(jax.jit, static_argnums=(2,))

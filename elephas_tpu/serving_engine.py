@@ -1099,14 +1099,45 @@ class DecodeEngine:
                 return partial(jax.jit, donate_argnums=(1,))(_extend)
             return jax.jit(_extend)
 
+        def _make_fresh_row(xcfg):
+            # an admission's empty row in ONE program (made leaf by leaf
+            # it is two tiny programs a leaf, the device empty between
+            # them); its outputs are new buffers every call, so the
+            # donating extend may take them
+            @jax.jit
+            def _fresh_row():
+                return init_kv_cache(xcfg, 1, max_len)
+            return _fresh_row
+
+        @jax.jit
+        def _first_greedy(logits):
+            # the admission's first token, from the last chunk's
+            # (1, vocab) logits as the program returned them
+            return jnp.argmax(logits[0])
+
+        @jax.jit
+        def _first_sampled(logits, temp, topk, topp, seed, fold, key):
+            # the step's own sampling body over the one row: a seed >= 0
+            # keys the draw by (seed, position of the token), any other
+            # draw consumes a split of the engine key, which comes back
+            # beside the token (``fold`` is the token's own position,
+            # one past the last the row holds)
+            tok, key = _sample_tok(logits, temp[None], topk[None],
+                                   topp[None], seed[None], fold[None] - 1,
+                                   key)
+            return tok[0], key
+
         self._step_fn = _step
         self._install_fn = _install
         self._prefill_fn = _prefill
         self._extend_fn = _make_extend(cfg)
         self._extend_owned_fn = _make_extend(cfg, donate=True)
-        self._fresh_row_fn = lambda: init_kv_cache(cfg, 1, max_len)
+        self._fresh_row_fn = _make_fresh_row(cfg)
+        self._first_greedy_fn = _first_greedy
+        self._first_sampled_fn = _first_sampled
         # registered shared prompt prefixes, longest first:
-        # (tokens, last-position logits, target row cache, draft row cache)
+        # (tokens, last-position logits (1, vocab), target row cache,
+        # draft row cache)
         self._prefixes: List = []
         self._m_prefix_hits = reg.counter(
             "serving_prefix_hits_total",
@@ -1207,8 +1238,7 @@ class DecodeEngine:
             self._prefill_draft_fn = _prefill_draft
             self._extend_draft_fn = _make_extend(dcfg)
             self._extend_draft_owned_fn = _make_extend(dcfg, donate=True)
-            self._fresh_draft_row_fn = lambda: init_kv_cache(dcfg, 1,
-                                                             max_len)
+            self._fresh_draft_row_fn = _make_fresh_row(dcfg)
             if self.paged is not None:
                 from .models.speculative import speculative_round_paged
 
@@ -1248,9 +1278,11 @@ class DecodeEngine:
         """Compile the hot programs BEFORE traffic arrives: the decode
         step (paged or contiguous) plus, for each
         length in ``prompt_lengths``, the admission prefill path exactly
-        as a real admission runs it (chunked block shapes when
-        ``prefill_chunk`` is set, whole-prompt prefill otherwise) and
-        the cache-install program. Call on an IDLE engine (it scribbles
+        as a real admission runs it (the fresh row, chunked block shapes
+        when ``prefill_chunk`` is set, whole-prompt prefill otherwise),
+        the cache-install program and the first token's sampler (greedy
+        or sampled, as the engine's ``temperature`` picks it). Call on an
+        IDLE engine (it scribbles
         into free slots' cache rows, which the next admission
         overwrites); afterwards the first real request pays no jit
         latency for any warmed shape."""
@@ -1296,10 +1328,21 @@ class DecodeEngine:
             if not 1 <= length < self.max_len:
                 raise ValueError(f"prompt length {length} out of range")
             fake = np.zeros(length, np.int32)
-            _, row = self._prefill_with_prefixes(
+            logits, row = self._prefill_with_prefixes(
                 fake, self._extend_fn, self._extend_owned_fn,
                 self._prefill_fn, self.params, None, 2,
                 self._fresh_row_fn)
+            # the first token's sampler, by the engine's own setting
+            # (and the engine key left where it is). The other one
+            # compiles when a request first overrides the setting: the
+            # sort inside costs the TPU's compiler some twenty seconds,
+            # which a greedy server's every cold start would pay
+            if self.temperature > 0:
+                self._first_sampled_fn(
+                    logits, np.float32(self.temperature), np.int32(0),
+                    np.float32(1.0), np.int32(-1), np.int32(1), self._key)
+            else:
+                self._first_greedy_fn(logits)
             if self.paged is not None:
                 from .models.paged_decode import install_row_paged
 
@@ -1355,7 +1398,7 @@ class DecodeEngine:
             else:
                 _, d_row = self._prefill_draft_fn(
                     self.draft_params, jnp.asarray(tokens[None]))
-        self._prefixes.append((tokens, logits[0], row, d_row))
+        self._prefixes.append((tokens, logits, row, d_row))
         self._prefixes.sort(key=lambda e: -e[0].size)
         if self._kv_cache is not None:
             self._pin_prefix_blocks(tokens, row)
@@ -1463,41 +1506,36 @@ class DecodeEngine:
         self._m_positions_read.inc(width)
         self._m_chunks_by_width[width].inc()
         self._m_ssm_scanned.inc(blk.shape[1] * self._ssm_layers)
-        return fn(params, row, jnp.asarray(blk), jnp.int32(pos))
+        # (host values: the call transfers them, no program converts)
+        return fn(params, row, blk, np.int32(pos))
 
     def _prefill_with_prefixes(self, prompt: np.ndarray, extend_fn,
                                extend_owned_fn, prefill_fn, params, entry,
                                cache_idx: int, fresh_fn):
         """Batch-1 prefill that reuses a matched prefix entry's cache row.
-        Returns (last-position logits (vocab,), row cache)."""
+        Returns (last-position logits (1, vocab), row cache)."""
         chunked = self.prefill_chunk is not None
         if entry is None:
             if chunked:
                 with self._psec("elephas.loop.prefill.row_init"):
                     row = fresh_fn()
                 with self._psec("elephas.loop.prefill.chunks"):
-                    logits, row = self._extend_chunked(
+                    return self._extend_chunked(
                         params, row, prompt, 0, extend_fn,
                         extend_owned_fn, owned=True)
-                return logits[0], row
             with self._psec("elephas.loop.prefill.chunks"):
-                logits, row = prefill_fn(params,
-                                         jnp.asarray(prompt[None]))
-            return logits[0], row
+                return prefill_fn(params, prompt[None])
         ptoks, plogits = entry[0], entry[1]
         row = entry[cache_idx]
         if prompt.size == ptoks.size:
             return plogits, row
         with self._psec("elephas.loop.prefill.chunks"):
             if chunked:
-                logits, row = self._extend_chunked(
+                return self._extend_chunked(
                     params, row, prompt[ptoks.size:], int(ptoks.size),
                     extend_fn, extend_owned_fn, owned=False)
-            else:
-                suffix = jnp.asarray(prompt[None, ptoks.size:])
-                logits, row = extend_fn(params, row, suffix,
-                                        jnp.int32(ptoks.size))
-        return logits[0], row
+            return extend_fn(params, row, prompt[None, ptoks.size:],
+                             np.int32(ptoks.size))
 
     # ------------------------------------------------- automatic KV cache
     def enable_prefix_cache(self, block_size: Optional[int] = None,
@@ -1934,18 +1972,15 @@ class DecodeEngine:
         ``[0, pos0)`` — the remainder half of every cache hit. ``row``
         is always engine-owned here (a fresh gather/import), so the
         donating extend variants apply. Returns (last-position logits
-        ``(vocab,)``, full row)."""
+        ``(1, vocab)``, full row)."""
         suffix = prompt[pos0:]
         with self._psec("elephas.loop.prefill.chunks"):
             if self.prefill_chunk is not None:
-                logits, row = self._extend_chunked(
+                return self._extend_chunked(
                     self.params, row, suffix, pos0, self._extend_fn,
                     self._extend_owned_fn, owned=True)
-            else:
-                logits, row = self._extend_owned_fn(
-                    self.params, row, jnp.asarray(suffix[None]),
-                    jnp.int32(pos0))
-        return logits[0], row
+            return self._extend_owned_fn(self.params, row, suffix[None],
+                                         np.int32(pos0))
 
     def _host_cache_prefill(self, rid: Optional[int],
                             prompt: np.ndarray):
@@ -1953,7 +1988,7 @@ class DecodeEngine:
         and :meth:`export_prefill`: longest cached chain (or the longer
         registered row) supplies the prompt head, the remainder
         prefills, and the freshly computed full blocks insert. Returns
-        (last-position logits ``(vocab,)``, row, cache_tokens_reused,
+        (last-position logits ``(1, vocab)``, row, cache_tokens_reused,
         registered_tokens_reused) — at most one of the two reuse counts
         is nonzero (whichever layer covered more served)."""
         cache, bs = self._kv_cache, self._kv_cache_bs
@@ -2630,6 +2665,9 @@ class DecodeEngine:
             raise ValueError(f"prompt ({prompt.size}) must leave room "
                              f"below max_len {self.max_len}")
         validate_sampling_overrides(temperature, top_k, top_p)
+        if seed is not None and not 0 <= int(seed) < 2 ** 31:
+            # (the decode side's rule: a slot's seed is an int32)
+            raise ValueError(f"seed must be in [0, 2**31), got {seed}")
         temp = (self.temperature if temperature is None
                 else float(temperature))
         topk = 0 if top_k is None else int(top_k)
@@ -3695,7 +3733,7 @@ class DecodeEngine:
                 else:
                     self._install_draft_row(slot, prompt,
                                             entry=st["entry"])
-            t0 = self._sample_first(st["logits"][0], st["temp"],
+            t0 = self._sample_first(st["logits"], st["temp"],
                                     st["topk"], st["topp"],
                                     seed=self._seed.get(rid),
                                     fold=int(prompt.size))
@@ -3869,27 +3907,26 @@ class DecodeEngine:
                       topp: float, seed: Optional[int] = None,
                       fold: int = 0) -> int:
         """Sample the admission-time first token from final-position
-        prefill logits ``(vocab,)`` — the host-side mirror of the step
-        fns' ``_sample_tok`` (same filter order: temperature scales,
-        then top-k/top-p on the scaled logits). A per-request ``seed``
+        prefill logits ``(1, vocab)``, as the last chunk's program
+        returned them, in ONE program — the mirror of the step fns'
+        ``_sample_tok`` (same filter order: temperature scales, then
+        top-k/top-p on the scaled logits). A per-request ``seed``
         derives the key from ``fold_in(PRNGKey(seed), fold)`` where
         ``fold`` is the sampled token's absolute sequence position —
         the same rule the step fns use, so a resumed request's
         admission token re-samples exactly what the original decode
-        emitted at that position."""
+        emitted at that position. The settings go in as numpy scalars
+        (plain transfers); ``int()`` of the token is the only wait."""
         with self._psec("elephas.loop.prefill.first_token"):
-            if temp > 0:
-                if seed is not None:
-                    sub = jax.random.fold_in(
-                        jax.random.PRNGKey(int(seed)), int(fold))
-                else:
-                    self._key, sub = jax.random.split(self._key)
-                filt = _filter_logits_rows(
-                    logits[None] / temp,
-                    jnp.asarray([topk], jnp.int32),
-                    jnp.asarray([topp], jnp.float32))[0]
-                return int(jax.random.categorical(sub, filt))
-            return int(jnp.argmax(logits))
+            if not temp > 0:
+                return int(self._first_greedy_fn(logits))
+            tok, key = self._first_sampled_fn(
+                logits, np.float32(temp), np.int32(topk), np.float32(topp),
+                np.int32(-1 if seed is None else seed), np.int32(fold),
+                self._key)
+            if seed is None:
+                self._key = key
+            return int(tok)
 
     def _install_prefilled(self, slot: int, prompt: np.ndarray,
                            pre: Tuple) -> int:

@@ -19,7 +19,7 @@ declare -a PATHS=(
   "tests/models --ignore=tests/models/test_transformer.py --ignore=tests/models/test_transformer_sharding.py --ignore=tests/models/test_transformer_generate.py --ignore=tests/models/test_transformer_training.py --ignore=tests/models/test_speculative.py --ignore=tests/models/test_distill.py"
   "tests/models/test_transformer.py tests/models/test_transformer_sharding.py tests/models/test_transformer_generate.py tests/models/test_transformer_training.py"
   "tests/models/test_speculative.py tests/models/test_distill.py tests/test_serving.py tests/test_serving_http.py tests/test_serving_overload.py tests/test_fleet_router.py tests/test_fleet_autoscaler.py tests/test_disagg.py tests/test_prefix_cache.py tests/test_speculative_serving.py tests/test_tenant_qos.py tests/test_weightsync.py tests/test_observability.py tests/test_slo_plane.py tests/test_tracing_propagation.py tests/test_crash_safe_serving.py tests/test_network_resilience.py tests/test_kv_tiered.py tests/test_trace_plane.py tests/test_adaptive_sched.py"
-  "tests/test_serving_engine.py tests/test_paged_engine.py tests/test_ssm_engine.py tests/test_hybrid_engine.py"
+  "tests/test_serving_engine.py tests/test_paged_engine.py tests/test_ssm_engine.py tests/test_hybrid_engine.py tests/test_admission_dispatch.py"
   "tests/integration tests/parallel tests/data"
 )
 fail=0
