@@ -7,6 +7,9 @@ bodies, no web framework. Request handler threads only enqueue/poll;
 ONE background engine thread drives ``step()``, so the device program
 stays single-threaded while requests arrive, finish, and cancel
 concurrently — continuous batching does the interleaving on-device.
+A blocked handler (a stream, a blocking ``/v1/generate``) waits on its
+own request's mailbox, not on the serving lock: a step wakes only the
+handlers it has tokens or a result for, however many requests queue.
 
 Endpoints (JSON in/out):
 
@@ -77,7 +80,7 @@ import re
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
 from .obs.context import (current_context, new_root, parse_traceparent,
@@ -91,6 +94,50 @@ from .utils.faults import fault_site
 __all__ = ["ServingServer"]
 
 _IDLE_SLEEP = 0.005
+
+#: how long a blocked handler waits on its mailbox before it looks at
+#: the server's state itself: a backstop against a lost signal, not how
+#: news arrives (every path that ends a request posts to its mailbox)
+_WAIT_BACKSTOP_S = 1.0
+
+
+class _Mailbox:
+    """What the engine loop owes ONE blocked handler (a stream or a
+    ``/v1/generate`` waiter): the tokens not yet written, the finished
+    request's outcome, and whether the request went away without one
+    (cancelled, drained, the engine died). It has its own lock, so the
+    handler waits here without the serving lock and a step wakes only
+    the handlers it has news for."""
+
+    __slots__ = ("_cond", "tokens", "info", "gone")
+
+    def __init__(self):
+        self._cond = threading.Condition(threading.Lock())
+        self.tokens: list = []
+        self.info: Optional[Dict] = None
+        self.gone = False
+
+    def post(self, tokens=(), info: Optional[Dict] = None,
+             gone: bool = False):
+        """Deliver news and wake the handler; with no arguments a bare
+        wake, for a handler that must look at the server (stop)."""
+        with self._cond:
+            self.tokens.extend(tokens)
+            if info is not None:
+                self.info = info
+            self.gone = self.gone or gone
+            self._cond.notify()
+
+    def take(self, stop: threading.Event, timeout: float):
+        """Wait for news (at most ``timeout``; not at all once ``stop``
+        is set), then take it: ``(tokens, info, gone, waited)``."""
+        with self._cond:
+            waited = not (self.tokens or self.info is not None
+                          or self.gone or stop.is_set())
+            if waited:
+                self._cond.wait(timeout)
+            tokens, self.tokens = self.tokens, []
+            return tokens, self.info, self.gone, waited
 
 
 class QuietThreadingHTTPServer(ThreadingHTTPServer):
@@ -259,15 +306,18 @@ class ServingServer:
             self._engine_has_resume = True
             self._engine_has_session = True
         self._host, self._port = host, int(port)
-        self._lock = threading.Lock()          # guards every engine call
+        # the serving lock: guards every engine call and the dicts below.
+        # No handler waits on it — each waits on its request's mailbox
+        self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
-        # finished-but-unfetched outputs, insertion-ordered and capped:
-        # a client that submits and never polls must not leak memory for
-        # the life of the server (oldest results evict first)
+        # finished-but-unfetched outputs of /v1/submit, insertion-ordered
+        # and capped: a client that submits and never polls must not leak
+        # memory for the life of the server (oldest results evict first).
+        # A blocked handler's result goes to its mailbox, never here
         self._results: Dict[int, list] = {}
         self._tracked: set = set()             # rids the loop must watch
-        self._streams: Dict[int, list] = {}    # live token feeds
-        self._waiters: set = set()             # rids with a blocked handler
+        self._streams: Dict[int, _Mailbox] = {}   # streaming handlers
+        self._waiters: Dict[int, _Mailbox] = {}   # blocked /v1/generate
         self._failure: Optional[str] = None    # set when the loop dies
         self._stop = threading.Event()
         self._httpd: Optional[ThreadingHTTPServer] = None
@@ -301,6 +351,17 @@ class ServingServer:
         # over a reused engine/registry must not report a predecessor's
         # drain totals in /stats (the scrape keeps pooled totals)
         self._drained_base = counter_baseline(self._m_drained)
+        # a blocked handler's returns from its mailbox wait, and those
+        # that found nothing (the backstop timeout, a spurious wake):
+        # the empty share says the loop wakes only handlers with news
+        self._m_wakeups = reg.counter(
+            "serving_http_handler_wakeups_total",
+            "returns of a blocked stream or /v1/generate handler from "
+            "its wait").labels()
+        self._m_wakeups_empty = reg.counter(
+            "serving_http_handler_wakeups_empty_total",
+            "returns of a blocked handler from its wait that found no "
+            "token, no result and no stop").labels()
         # set by stop(): the ENGINE LOOP enforces the drain deadline and
         # signals completion (it holds the lock across every step, so a
         # stop() thread polling for the lock could starve past its
@@ -702,10 +763,10 @@ class ServingServer:
         rejected with 503, while requests already in flight (including
         live streams) keep running. Idempotent; :meth:`stop` calls it
         first, but an orchestrator may flip it early so the load
-        balancer stops routing here before the actual stop."""
+        balancer stops routing here before the actual stop. It ends no
+        handler's wait: draining lets every request in flight finish."""
         with self._cond:
             self._draining = True
-            self._cond.notify_all()
 
     def stop(self, drain_timeout: float = 0.0):
         """Shut down, draining gracefully for up to ``drain_timeout``
@@ -728,6 +789,9 @@ class ServingServer:
             # terminal lines (a stalled client must not wedge stop)
             done.wait(timeout=float(drain_timeout) + 10)
         self._stop.set()
+        with self._cond:
+            # every handler still blocked answers its terminal line now
+            self._post_all_locked()
         if self.watchdog is not None:
             # before the loop joins: a stopping loop's beats ending is
             # shutdown, not a stall to alert on
@@ -781,44 +845,53 @@ class ServingServer:
             for rid in list(self._tracked):
                 if self.engine.cancel(rid):
                     self._m_drained.inc()
+                box = self._mailbox(rid)
+                if box is not None:
+                    box.post(gone=True)
             self._tracked.clear()
-            self._cond.notify_all()
         if not (self._tracked or self._streams or self._waiters):
             self._drain_done = None
             done.set()
 
-    def _deliver_locked(self, emitted: Dict) -> bool:
+    def _mailbox(self, rid: int) -> Optional[_Mailbox]:
+        """The blocked handler's mailbox for ``rid``, if it has one."""
+        return self._streams.get(rid) or self._waiters.get(rid)
+
+    def _post_all_locked(self, **news):
+        """Post ``news`` to every blocked handler's mailbox."""
+        for box in (*self._streams.values(), *self._waiters.values()):
+            box.post(**news)
+
+    def _deliver_locked(self, emitted: Dict) -> Tuple[bool, list]:
         """What the engine loop owes the handlers after a step, under
-        the serving lock: route the emitted tokens into their streams,
-        harvest finished requests, wake the blocked handlers, enforce a
-        drain. Returns whether the engine has nothing left to step."""
-        for rid, toks in emitted.items():
-            if rid in self._streams:
-                self._streams[rid].extend(toks)
-        if emitted:
-            self._cond.notify_all()
+        the serving lock: harvest finished requests (a blocked handler's
+        outcome, with its last tokens, into its mailbox at once; a
+        submit's into the result store), enforce a drain. Returns
+        whether the engine has nothing left to step, and the live
+        streams' new tokens as ``[(mailbox, tokens)]``, which the loop
+        posts once it has released the lock and yielded. Only handlers
+        with news wake."""
         finished = []
         for rid in list(self._tracked):
             out = self._result_info(rid)
             if out is not None:
-                self._results[rid] = out
+                box = self._mailbox(rid)
+                if box is not None:
+                    # posted before the rid is untracked: the backstop
+                    # in _wait reads an untracked rid with no news as gone
+                    box.post(tokens=emitted.get(rid, ()), info=out)
+                else:
+                    self._results[rid] = out
                 finished.append(rid)
         if finished:
             self._tracked.difference_update(finished)
             while len(self._results) > self.max_stored_results:
-                # abandoned submits: evict oldest unfetched — but never
-                # a result a blocked /v1/generate handler or live
-                # stream is about to claim
-                victim = next(
-                    (r for r in self._results
-                     if r not in self._waiters
-                     and r not in self._streams), None)
-                if victim is None:
-                    break
-                self._results.pop(victim)
-            self._cond.notify_all()
+                # abandoned submits: evict the oldest unfetched
+                self._results.pop(next(iter(self._results)))
+        feeds = [(self._streams[rid], toks) for rid, toks in emitted.items()
+                 if rid in self._streams and rid not in finished]
         self._check_drain_locked()
-        return not self.engine.pending
+        return not self.engine.pending, feeds
 
     def _engine_loop(self):
         """The single driver of the device program: steps whenever work
@@ -848,7 +921,7 @@ class ServingServer:
                         # (slow) iteration when work arrives
                         prof.tick()
                     with section("elephas.server.deliver"):
-                        idle = self._deliver_locked(emitted)
+                        idle, feeds = self._deliver_locked(emitted)
                 finally:
                     self._cond.release()
                 with section("elephas.server.housekeeping"):
@@ -886,6 +959,13 @@ class ServingServer:
                     # plan). sleep(0) parks this thread just long
                     # enough for a waiting acquirer to win.
                     time.sleep(_IDLE_SLEEP if idle else 0)
+                with section("elephas.server.deliver"):
+                    # after the yield and outside the lock: the stream
+                    # handlers these wake would otherwise contend for the
+                    # interpreter with the lock waiters the yield is for,
+                    # and hold the loop up there while the device drains
+                    for box, toks in feeds:
+                        box.post(tokens=toks)
         except Exception as exc:  # noqa: BLE001 — record ANY engine death
             with self._cond:
                 self._failure = f"{type(exc).__name__}: {exc}"
@@ -895,7 +975,7 @@ class ServingServer:
                     # a loop that can no longer finish anything
                     self._drain_done.set()
                     self._drain_done = None
-                self._cond.notify_all()
+                self._post_all_locked(gone=True)
 
     def _prompt_ids(self, body: Dict):
         if "prompt" in body:
@@ -971,38 +1051,40 @@ class ServingServer:
                     "retry_after_ms": exc.retry_after_ms},
                     headers=retry_after_header(exc.retry_after_ms))
             self._tracked.add(rid)
+            # registered under the SAME lock as submit, so the very first
+            # engine-loop step already posts to the mailbox
             if stream:
-                # registered under the SAME lock as submit, so the very
-                # first engine-loop step already routes into the feed
-                self._streams[rid] = []
+                self._streams[rid] = _Mailbox()
             if waiter:
-                # likewise: the eviction guard must see this rid as
-                # waited-on before the engine loop can ever finish it
-                self._waiters.add(rid)
+                self._waiters[rid] = _Mailbox()
             return rid
+
+    def _wait(self, rid: int, box: _Mailbox):
+        """One wait of a blocked handler on its mailbox, without the
+        serving lock: ``(tokens, info, gone)``. A wait that ends with
+        nothing is the backstop's: a request that left ``_tracked``
+        with no news posted (a lost signal) reads as gone on the next
+        take — a result is always posted before its rid is untracked."""
+        tokens, info, gone, waited = box.take(self._stop, _WAIT_BACKSTOP_S)
+        if waited:
+            self._m_wakeups.inc()
+            if not (tokens or info is not None or gone
+                    or self._stop.is_set()):
+                self._m_wakeups_empty.inc()
+                if rid not in self._tracked:   # lock-free read
+                    box.post(gone=True)
+        return tokens, info, gone
 
     def _run_stream(self, rid: int, write_line):
         """Relay a request's tokens to ``write_line`` as the engine
         emits them; terminates with a status line on completion,
-        cancellation, or server shutdown. Writes happen OUTSIDE the
-        condition lock — a stalled client must never hold up the
-        server-wide lock on backpressure."""
+        cancellation, or server shutdown. Writes happen OUTSIDE every
+        lock — a stalled client must never hold up the server-wide lock
+        on backpressure."""
+        box = self._streams[rid]
         try:
             while True:
-                stopping = False
-                with self._cond:
-                    while (not self._streams.get(rid)
-                           and rid in self._tracked
-                           and rid not in self._results):
-                        self._cond.wait(timeout=0.5)
-                        if self._stop.is_set():
-                            stopping = True
-                            break
-                    toks = self._streams.get(rid) or []
-                    if toks:
-                        self._streams[rid] = []
-                    info = self._results.pop(rid, None)  # fed via stream
-                    gone = info is None and rid not in self._tracked
+                toks, info, gone = self._wait(rid, box)
                 if toks:
                     write_line({"tokens": toks})
                 if info is not None:
@@ -1015,7 +1097,7 @@ class ServingServer:
                     else:
                         write_line({"status": "done"})
                     return
-                if stopping or (gone and not toks):
+                if gone or self._stop.is_set():
                     # lock-free like /health: the terminal status must
                     # not wait out a compile the engine loop is holding
                     # the lock across
@@ -1032,17 +1114,26 @@ class ServingServer:
                 # complete a waiting drain even if the engine loop (its
                 # usual driver) is already dead
                 self._check_drain_locked()
-                self._cond.notify_all()   # a draining stop() waits on this
+
+    def _withdraw_locked(self, rid: int) -> bool:
+        """A client took ``rid`` back: cancel it, drop its stored
+        result, and end its blocked handler's wait (``cancelled``; a
+        result already posted, which the cancel came too late for, is
+        still delivered)."""
+        cancelled = self.engine.cancel(rid)
+        self._tracked.discard(rid)
+        self._results.pop(rid, None)
+        box = self._mailbox(rid)
+        if box is not None:
+            box.post(gone=True)
+        return cancelled
 
     def _abort_stream(self, rid: int):
         """Server-side teardown for a stream whose client went away:
         cancel the in-flight request and drop every trace of it."""
         with self._cond:
-            self.engine.cancel(rid)
-            self._tracked.discard(rid)
-            self._results.pop(rid, None)
+            self._withdraw_locked(rid)
             self._streams.pop(rid, None)
-            self._cond.notify_all()
 
     def _finish_payload(self, info: Dict) -> Dict:
         """Response body for a finished request. A mid-decode deadline
@@ -1064,24 +1155,28 @@ class ServingServer:
 
     def _generate(self, body: Dict) -> Dict:
         rid = self._submit(body, waiter=True)
-        with self._cond:
-            # exit on completion OR when the rid vanishes (cancelled by
-            # another client, or its result fetched/evicted) — a blocked
-            # handler must never outlive its request
-            try:
-                while rid not in self._results and rid in self._tracked:
-                    self._cond.wait(timeout=0.5)
-                    if self._stop.is_set():
-                        raise ValueError("server shutting down")
-            finally:
-                self._waiters.discard(rid)
+        box = self._waiters[rid]
+        # exit on completion OR when the rid goes away (cancelled by
+        # another client, drained, the engine died) — a blocked handler
+        # must never outlive its request
+        try:
+            while True:
+                _, info, gone = self._wait(rid, box)
+                if info is not None or gone:
+                    break
+                if self._stop.is_set():
+                    raise ValueError("server shutting down")
+        finally:
+            with self._cond:
+                self._waiters.pop(rid, None)
                 self._check_drain_locked()   # see _run_stream's finally
-            if rid in self._results:
-                return self._finish_payload(self._results.pop(rid))
-            if self._failure is not None:
-                return {"status": "error", "id": rid,
-                        "error": f"engine failed: {self._failure}"}
-            return {"status": "cancelled", "id": rid}
+        if info is not None:
+            return self._finish_payload(info)
+        failure = self._failure
+        if failure is not None:
+            return {"status": "error", "id": rid,
+                    "error": f"engine failed: {failure}"}
+        return {"status": "cancelled", "id": rid}
 
     def _poll(self, rid: int) -> Dict:
         with self._cond:
@@ -1103,11 +1198,7 @@ class ServingServer:
     def _cancel(self, body: Dict) -> Dict:
         rid = int(body.get("id", -1))
         with self._cond:
-            cancelled = self.engine.cancel(rid)
-            self._tracked.discard(rid)
-            self._results.pop(rid, None)
-            self._cond.notify_all()   # wake a /v1/generate blocked on rid
-            return {"cancelled": bool(cancelled)}
+            return {"cancelled": bool(self._withdraw_locked(rid))}
 
     # ------------------------------------------------------------ tracing
     def _request_trace(self, rid: int) -> Dict:

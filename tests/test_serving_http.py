@@ -398,3 +398,277 @@ def test_eviction_never_takes_a_waiters_result(model):
         for i, p in enumerate(prompts):
             assert results[i].get("tokens") == _ref(params, config, p, 6), \
                 f"client {i} lost its result to eviction: {results[i]}"
+
+
+# ------------------------------------------------- per-request mailboxes
+class _FakeEngine:
+    """The engine surface ServingServer drives, with no device: a
+    submitted request stays live until the test finishes it (posts its
+    outcome to ``finished``) or cancels it; ``fail`` makes the next
+    step raise."""
+
+    def __init__(self):
+        self._next = 0
+        self.live = set()
+        self.finished = {}
+        self.fail = None
+
+    def submit(self, prompt, max_new_tokens, admit=True, **kwargs):
+        rid, self._next = self._next, self._next + 1
+        self.live.add(rid)
+        return rid
+
+    @property
+    def pending(self):
+        return bool(self.live)
+
+    def step(self):
+        if self.fail is not None:
+            raise self.fail
+        import time
+
+        time.sleep(0.001)
+        return {}
+
+    def finish(self, rid, tokens):
+        self.live.discard(rid)
+        self.finished[rid] = {"tokens": tokens, "timeout": False,
+                              "expired": False}
+
+    def result_info(self, rid):
+        return self.finished.pop(rid, None)
+
+    def cancel(self, rid):
+        if rid in self.live:
+            self.live.discard(rid)
+            return True
+        return False
+
+
+def _wait_blocked(srv, n):
+    """Until ``n`` handlers are registered and each is inside its
+    mailbox wait (a Condition's ``_waiters`` holds one lock a waiter)."""
+    import time
+
+    deadline = time.time() + 30
+    while time.time() < deadline:
+        with srv._cond:
+            boxes = [*srv._streams.values(), *srv._waiters.values()]
+        if len(boxes) == n and all(b._cond._waiters for b in boxes):
+            return
+        time.sleep(0.002)
+    raise AssertionError(f"{len(boxes)} of {n} handlers blocked")
+
+
+class _Handlers:
+    """Blocked handlers in threads: ``streams`` run ``_run_stream`` over
+    a submitted rid, ``waiters`` run ``_generate`` (a blocking
+    /v1/generate); each one's lines, payload or error is kept."""
+
+    def __init__(self, srv, streams, waiters):
+        self.lines, self.out = {}, {}
+        self.stream_rids = [srv._submit({"prompt": [1, 2]}, stream=True)
+                            for _ in range(streams)]
+        self.threads = []
+        for rid in self.stream_rids:
+            self.lines[rid] = []
+            self.threads.append(threading.Thread(
+                target=srv._run_stream, args=(rid, self.lines[rid].append)))
+        for i in range(waiters):
+            self.threads.append(threading.Thread(target=self._generate,
+                                                 args=(srv, i)))
+        for t in self.threads:
+            t.start()
+        _wait_blocked(srv, streams + waiters)
+        with srv._cond:
+            self.waiter_rids = sorted(srv._waiters)
+
+    def _generate(self, srv, i):
+        try:
+            self.out[i] = srv._generate({"prompt": [3]})
+        except ValueError as exc:
+            self.out[i] = exc
+
+    def join(self):
+        for t in self.threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in self.threads), \
+            "a blocked handler outlived its request"
+
+
+def _wakeups(srv):
+    return srv._m_wakeups.value, srv._m_wakeups_empty.value
+
+
+def _deliver(srv, emitted):
+    """One engine-loop delivery: the harvest under the serving lock,
+    then the live streams' tokens posted outside it, as the loop does."""
+    with srv._cond:
+        _, feeds = srv._deliver_locked(emitted)
+    for box, toks in feeds:
+        box.post(tokens=toks)
+
+
+def test_a_step_wakes_only_the_handlers_it_has_news_for(monkeypatch):
+    """K live streams, N streams still queued and W blocking waiters,
+    all blocked: one delivery of K+W rows' tokens wakes the K streams
+    alone (a waiter takes no token stream), the waiters' results wake
+    the W waiters alone, and no wake is empty."""
+    from elephas_tpu import serving_http
+
+    monkeypatch.setattr(serving_http, "_WAIT_BACKSTOP_S", 60.0)
+    K, N, W = 4, 12, 3
+    srv = ServingServer(_FakeEngine(), watchdog=False)
+    h = _Handlers(srv, K + N, W)
+    live = h.stream_rids[:K]
+    _deliver(srv, {rid: [rid, 7] for rid in live + h.waiter_rids})
+    _wait_blocked(srv, K + N + W)
+    assert _wakeups(srv) == (K, 0)
+    for rid in h.stream_rids:
+        assert h.lines[rid] == ([{"tokens": [rid, 7]}] if rid in live
+                                else [])
+    for rid in h.waiter_rids:
+        srv.engine.finish(rid, [rid, 7, 8])
+    _deliver(srv, {})
+    for t in h.threads[K + N:]:
+        t.join(timeout=30)
+    assert _wakeups(srv) == (K + W, 0)
+    assert sorted(out["tokens"] for out in h.out.values()) == \
+        [[rid, 7, 8] for rid in h.waiter_rids]
+    assert all(out["status"] == "done" for out in h.out.values())
+    srv.stop()
+    h.join()
+    for rid in h.stream_rids:
+        assert h.lines[rid][-1] == {"status": "cancelled"}
+
+
+@pytest.mark.parametrize("ending", ["done", "stop", "drain_deadline",
+                                    "cancel", "abort_stream",
+                                    "engine_error"])
+def test_every_ending_reaches_every_blocked_handler(ending, monkeypatch):
+    """Each way a request can end wakes its blocked handler with its
+    terminal line, whichever handler it is, through a running engine
+    loop: finished (``done``), ``stop()``, a drain's deadline
+    (``cancelled``, counted as drained), ``/v1/cancel`` and a vanished
+    stream client (``cancelled``), the engine raising (``error``)."""
+    from elephas_tpu import serving_http
+
+    monkeypatch.setattr(serving_http, "_WAIT_BACKSTOP_S", 60.0)
+    srv = ServingServer(_FakeEngine(), watchdog=False).start()
+    stopped = False
+    try:
+        h = _Handlers(srv, 3, 2)
+        rids = h.stream_rids + h.waiter_rids
+        if ending == "done":
+            with srv._cond:
+                for rid in rids:
+                    srv.engine.finish(rid, [rid])
+        elif ending == "stop":
+            srv.stop()
+            stopped = True
+        elif ending == "drain_deadline":
+            srv.stop(drain_timeout=0.2)
+            stopped = True
+            assert srv._n_drained == len(rids)
+        elif ending == "cancel":
+            for rid in rids:
+                assert srv._cancel({"id": rid}) == {"cancelled": True}
+        elif ending == "abort_stream":
+            for rid in rids:
+                srv._abort_stream(rid)
+        else:
+            with srv._cond:
+                srv.engine.fail = RuntimeError("injected device loss")
+        h.join()
+    finally:
+        if not stopped:
+            srv.stop()
+    assert not (srv._streams or srv._waiters)
+    # an abrupt stop leaves the work it abandoned in the engine
+    assert bool(srv._tracked) == (ending == "stop")
+    for rid in h.stream_rids:
+        last = h.lines[rid][-1]
+        if ending == "done":
+            assert h.lines[rid] == [{"status": "done"}]
+        elif ending == "engine_error":
+            assert last["status"] == "error"
+            assert "injected device loss" in last["error"]
+        else:
+            assert last == {"status": "cancelled"}
+    for out in h.out.values():
+        if ending == "done":
+            assert out["status"] == "done" and len(out["tokens"]) == 1
+        elif ending == "stop":
+            assert isinstance(out, ValueError)
+        elif ending == "engine_error":
+            assert out["status"] == "error"
+        else:
+            assert out["status"] == "cancelled"
+    assert _wakeups(srv)[1] == 0
+
+
+def test_the_backstop_ends_a_wait_whose_signal_was_lost(monkeypatch):
+    """A request that leaves the server with no post to its mailbox (a
+    lost signal) still ends its handler, at the backstop's timeout,
+    and that wake is counted as empty."""
+    from elephas_tpu import serving_http
+
+    monkeypatch.setattr(serving_http, "_WAIT_BACKSTOP_S", 0.05)
+    srv = ServingServer(_FakeEngine(), watchdog=False)
+    h = _Handlers(srv, 1, 1)
+    with srv._cond:
+        srv._tracked.clear()          # untracked, and nobody posted
+    h.join()
+    assert h.lines[h.stream_rids[0]] == [{"status": "cancelled"}]
+    assert h.out[0]["status"] == "cancelled"
+    total, empty = _wakeups(srv)
+    assert empty >= 2 and total >= empty
+
+
+def test_metrics_export_the_handler_wakeups(model):
+    """``/metrics`` carries both wake-up counters beside the engine's
+    series, at the values the server counted; a streamed request wakes
+    its handler at least once (a cold first step's compile may outlast
+    the backstop, so an empty wake or two is allowed here)."""
+    params, config = model
+    prompt = [int(t) for t in np.random.default_rng(8).integers(0, 300, 5)]
+    with ServingServer(DecodeEngine(params, config, max_slots=2)) as srv:
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{srv.port}/v1/generate",
+            data=json.dumps({"prompt": prompt, "max_new_tokens": 6,
+                             "stream": True}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=120) as resp:
+            lines = [json.loads(raw) for raw in resp]
+        assert lines[-1] == {"status": "done"}
+        with urllib.request.urlopen(f"http://127.0.0.1:{srv.port}/metrics",
+                                    timeout=120) as resp:
+            text = resp.read().decode()
+    series = dict(line.rsplit(" ", 1) for line in text.splitlines()
+                  if line.startswith("serving_http_handler_wakeups"))
+    total = float(series["serving_http_handler_wakeups_total"])
+    empty = float(series["serving_http_handler_wakeups_empty_total"])
+    assert (total, empty) == _wakeups(srv)
+    assert total >= 1 and 0 <= empty <= total
+    assert "serving_steps_total" in text
+
+
+def test_a_cancel_after_the_result_is_posted_still_delivers_it(monkeypatch):
+    """A ``/v1/cancel`` that comes once the request has finished cancels
+    nothing (``cancelled: false``), and its blocked handlers answer the
+    result, not ``cancelled``."""
+    from elephas_tpu import serving_http
+
+    monkeypatch.setattr(serving_http, "_WAIT_BACKSTOP_S", 60.0)
+    srv = ServingServer(_FakeEngine(), watchdog=False)
+    h = _Handlers(srv, 1, 1)
+    rids = h.stream_rids + h.waiter_rids
+    for rid in rids:
+        srv.engine.finish(rid, [rid, 5])
+    _deliver(srv, {h.stream_rids[0]: [h.stream_rids[0], 5]})
+    for rid in rids:
+        assert srv._cancel({"id": rid}) == {"cancelled": False}
+    h.join()
+    assert h.lines[h.stream_rids[0]] == [
+        {"tokens": [h.stream_rids[0], 5]}, {"status": "done"}]
+    assert h.out[0] == {"status": "done", "tokens": [h.waiter_rids[0], 5]}
