@@ -24,6 +24,10 @@ import pytest
 from elephas_tpu.models import (Activation, Dense, Dropout, Input, Model,
                                 Sequential)
 
+# a time limit on every test (tests/_time_limit.py), and pytester for the
+# test of that limit
+pytest_plugins = ["pytester", "tests._time_limit"]
+
 
 @pytest.fixture
 def classification_model():

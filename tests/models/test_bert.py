@@ -14,6 +14,11 @@ from elephas_tpu.models.bert import (BertConfig, encode, init_params,
                                      mlm_loss, param_specs, pool,
                                      shard_params)
 
+# compiled whole, once a shape and config: op by op, a pass is a
+# hundred small compiles
+ENCODE = jax.jit(encode, static_argnames="config")
+MLM_LOSS = jax.jit(mlm_loss, static_argnums=5)
+
 
 def _config(**kw):
     base = dict(vocab_size=64, num_layers=2, num_heads=4, d_model=32,
@@ -35,7 +40,7 @@ def test_encode_shapes_and_pooler():
     config = _config()
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = _tokens()
-    hidden = encode(params, tokens, config=config)
+    hidden = ENCODE(params, tokens, config=config)
     assert hidden.shape == (8, 16, 32)
     pooled = pool(params, hidden, config)
     assert pooled.shape == (8, 32)
@@ -50,18 +55,18 @@ def test_padding_does_not_leak_into_real_positions():
     tokens = np.asarray(_tokens(2, 12))
     padded = tokens.copy()
     padded[:, 8:] = config.pad_token_id
-    h1 = np.asarray(encode(params, jnp.asarray(padded), config=config))
+    h1 = np.asarray(ENCODE(params, jnp.asarray(padded), config=config))
     # same prefix, garbage AT pad positions' embeddings can't be changed
     # via tokens (pad id is fixed), so instead: extending the pad run
     # with different *lengths* must keep the shared real prefix equal
     padded2 = tokens.copy()
     padded2[:, 8:] = config.pad_token_id
     padded2[:, 11] = config.pad_token_id  # same — sanity
-    h2 = np.asarray(encode(params, jnp.asarray(padded2), config=config))
+    h2 = np.asarray(ENCODE(params, jnp.asarray(padded2), config=config))
     np.testing.assert_allclose(h1[:, :8], h2[:, :8], atol=1e-6)
     # and a genuinely different suffix BEHIND the mask: replace pad ids
     # with other tokens but mask them out via a shorter sequence compare
-    short = np.asarray(encode(params, jnp.asarray(padded[:, :8]),
+    short = np.asarray(ENCODE(params, jnp.asarray(padded[:, :8]),
                               config=config))
     np.testing.assert_allclose(h1[:, :8], short, atol=1e-4)
 
@@ -109,7 +114,7 @@ def test_mlm_sharded_matches_unsharded():
     masked, positions, weights = mask_tokens(tokens, jax.random.PRNGKey(5),
                                              config)
     labels = jax.vmap(jnp.take)(tokens, positions)
-    ref = float(mlm_loss(params, masked, positions, labels, weights,
+    ref = float(MLM_LOSS(params, masked, positions, labels, weights,
                          config))
 
     mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
@@ -171,10 +176,10 @@ def test_bert_dropout_training_and_deterministic_inference():
     config = _config(dropout_rate=0.1)
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = _tokens(8, 12)
-    a = np.asarray(encode(params, tokens, config=config))
-    b = np.asarray(encode(params, tokens, config=config))
+    a = np.asarray(ENCODE(params, tokens, config=config))
+    b = np.asarray(ENCODE(params, tokens, config=config))
     np.testing.assert_array_equal(a, b)
-    d = np.asarray(encode(params, tokens, config=config,
+    d = np.asarray(ENCODE(params, tokens, config=config,
                           dropout_key=jax.random.PRNGKey(1)))
     assert np.abs(d - a).max() > 1e-6
     tx = optax.adam(1e-3)

@@ -7,6 +7,7 @@ YaRN), seeded weights, on the CPU. The reference is the benchmark's own
 file, ``chipbench/reference/deepseek_v2.py``: independent of the
 program's model code.
 """
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -18,11 +19,13 @@ import pytest
 
 from elephas_tpu import DecodeEngine
 from elephas_tpu.models import grouped_experts, paged_decode
-from elephas_tpu.models.transformer import (TransformerConfig, decode_step,
-                                            forward, init_kv_cache,
-                                            init_params, param_specs,
-                                            prefill_cache,
+from elephas_tpu.models.transformer import (TransformerConfig, chunked_blocks,
+                                            init_kv_cache, init_params,
+                                            param_specs,
                                             prefill_cache_chunked)
+
+from ._transformer_util import (DECODE_BLOCK, DECODE_PAGED, DECODE_STEP,
+                                FORWARD, PREFILL)
 
 REPO = Path(__file__).resolve().parent.parent.parent
 
@@ -50,9 +53,33 @@ def _model(param_dtype="float32", dtype=jnp.float32, seed=3, **sizes):
     return cfg, FAMILY.make_params(cfg, seed), dict(SIZES, **sizes)
 
 
+# compiled whole once a shape, as ``_transformer_util``'s entry points are
+PREFILL_CHUNKED = jax.jit(prefill_cache_chunked, static_argnums=(2, 3),
+                          static_argnames="chunk")
+
+
+ROUTE = jax.jit(grouped_experts.route, static_argnums=2)
+EXPERTS_APPLY = jax.jit(grouped_experts.experts_apply, static_argnums=2)
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_program(name, cfg, sizes_json, **kw):
+    sizes = json.loads(sizes_json)
+    if name == "forward":
+        return jax.jit(lambda p, t: REFERENCE.forward_with_routing(
+            FAMILY.to_reference(p, cfg), t, sizes))
+    return jax.jit(lambda *a: getattr(REFERENCE, name)(*a, sizes, **kw))
+
+
+def _sized(name, sizes, *args, cfg=None, **kw):
+    """``REFERENCE.<name>(*args, sizes, **kw)``: one program a
+    configuration, compiled once a shape"""
+    return _reference_program(name, cfg, json.dumps(sizes, sort_keys=True),
+                              **kw)(*args)
+
+
 def _reference(params, cfg, sizes, tokens):
-    return jax.jit(lambda p, t: REFERENCE.forward_with_routing(
-        FAMILY.to_reference(p, cfg), t, sizes))(params, tokens)
+    return _sized("forward", sizes, params, tokens, cfg=cfg)
 
 
 @pytest.fixture(scope="module")
@@ -69,7 +96,7 @@ def tokens():
 def test_forward_agrees_with_the_reference_in_float32(model, tokens):
     cfg, params, sizes = model
     want, _, _ = _reference(params, cfg, sizes, tokens)
-    got = forward(params, tokens, cfg)
+    got = FORWARD(params, tokens, cfg)
     # float32 on both sides, different order of operations (grouped
     # against masked experts, fused against split rope): rounding only
     np.testing.assert_allclose(got, want, atol=5e-5)
@@ -78,7 +105,7 @@ def test_forward_agrees_with_the_reference_in_float32(model, tokens):
 def test_bf16_agrees_but_for_flipped_picks(tokens):
     cfg, params, sizes = _model("bfloat16", jnp.bfloat16)
     want, _, _ = _reference(params, cfg, sizes, tokens)
-    got = np.asarray(forward(params, tokens, cfg), np.float32)
+    got = np.asarray(FORWARD(params, tokens, cfg), np.float32)
     worst = np.abs(got - np.asarray(want)).max(axis=-1)   # per position
     # bf16 has 8 bits of mantissa and the logits a magnitude of 4: most
     # positions differ by rounding. Where a near-tied pick flips, here or
@@ -89,19 +116,19 @@ def test_bf16_agrees_but_for_flipped_picks(tokens):
     assert (worst < 0.5).mean() > 0.6
     # the same bf16 weights computed in float32: rounding only
     cfg32, _, _ = _model("bfloat16", jnp.float32)
-    np.testing.assert_allclose(forward(params, tokens, cfg32), want,
+    np.testing.assert_allclose(FORWARD(params, tokens, cfg32), want,
                                atol=1e-4)
 
 
 def test_cached_paths_agree_with_the_forward(model, tokens):
     cfg, params, _ = model
-    full = forward(params, tokens, cfg)
-    logits, cache = prefill_cache(params, tokens[:, :24], cfg, MAX_LEN)
+    full = FORWARD(params, tokens, cfg)
+    logits, cache = PREFILL(params, tokens[:, :24], cfg, MAX_LEN)
     np.testing.assert_allclose(logits, full[:, 23], atol=5e-5)
-    logits, cache = decode_step(params, cache, tokens[:, 24],
+    logits, cache = DECODE_STEP(params, cache, tokens[:, 24],
                                 jnp.full((3,), 24), cfg)
     np.testing.assert_allclose(logits, full[:, 24], atol=5e-5)
-    logits, _ = prefill_cache_chunked(params, tokens, cfg, MAX_LEN, chunk=16)
+    logits, _ = PREFILL_CHUNKED(params, tokens, cfg, MAX_LEN, chunk=16)
     np.testing.assert_allclose(logits, full[:, -1], atol=5e-5)
 
 
@@ -112,8 +139,12 @@ def _paged_rows(cfg, params, prompts, block_size=4):
                                         block_size)
     tables = np.zeros((len(prompts) + 1, mb), np.int32)
     for r, prompt in enumerate(prompts):
-        _, row = prefill_cache_chunked(params, jnp.asarray(prompt)[None],
-                                       cfg, MAX_LEN, chunk=16)
+        # prefill_cache_chunked's loop, each chunk a program compiled once
+        # a chunk length (its position traced), not once a prompt length
+        _, row = chunked_blocks(
+            lambda cache, blk, pos, _: DECODE_BLOCK(params, cache, blk, pos,
+                                                    cfg),
+            init_kv_cache(cfg, 1, MAX_LEN), jnp.asarray(prompt)[None], 0, 16)
         need = len(prompt) // block_size + 1
         tables[r, :need] = 1 + r * mb + np.arange(need)
         pool = paged_decode.install_row_paged(pool, row, tables[r], need)
@@ -147,7 +178,7 @@ def test_paged_absorbed_step_agrees_with_the_reference(model, paged,
     cfg, params, _ = model
     pool, tables, last, want, want_picks = paged
     pos = jnp.asarray(LENGTHS + [0], jnp.int32)    # one idle row
-    logits, new_pool, stats = paged_decode.decode_step_paged(
+    logits, new_pool, stats = DECODE_PAGED(
         params, pool, jnp.asarray(tables), jnp.asarray(last + [0]), pos,
         cfg, held_blocks=held_blocks, with_stats=True)
     np.testing.assert_allclose(logits[:-1], want, atol=5e-5)
@@ -199,8 +230,8 @@ def test_group_limited_greedy_picks_equal_the_references(model):
     cfg, params, sizes = model
     h = jax.random.normal(jax.random.PRNGKey(5), (64, cfg.d_model))
     gate = params["layer_1"]["moe"]["gate"]
-    weights, picks, _ = grouped_experts.route(h, gate, cfg)
-    want_w, want_p, margin, _ = REFERENCE.route(h, gate, sizes)
+    weights, picks, _ = ROUTE(h, gate, cfg)
+    want_w, want_p, margin, _ = _sized("route", sizes, h, gate)
     decided = np.asarray(margin) > 1e-4
     assert decided.sum() >= 60
     np.testing.assert_array_equal(np.asarray(picks)[decided],
@@ -222,7 +253,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     moe = params["layer_1"]["moe"]
     h = jax.random.normal(jax.random.PRNGKey(7), (2, 24, cfg.d_model))
     whole = FAMILY.to_reference(params, cfg)["layers"][1]
-    want, _, _ = REFERENCE.expert_layer(whole, h, whole_sizes)
+    want, _, _ = _sized("expert_layer", whole_sizes, whole, h)
     total = jnp.zeros_like(want)
     for rank in range(4):
         rank_cfg = FAMILY.program_config(
@@ -233,13 +264,14 @@ def test_the_shares_add_up_to_the_uncut_layer():
                    for k in ("w1", "w3", "w2")}}
         if rank == 0:
             part["shared"] = moe["shared"]
-        out, stats = grouped_experts.experts_apply(h, part, rank_cfg)
+        out, stats = EXPERTS_APPLY(h, part, rank_cfg)
         total = total + out
         # and each rank's part is the reference's part for that share
-        share, _, _ = REFERENCE.expert_layer(
+        share, _, _ = _sized(
+            "expert_layer",
+            dict(SIZES, n_routed_experts=2, held_first=2 * rank),
             {**whole, **{k: whole[k][2 * rank:2 * rank + 2]
                          for k in ("e_gate", "e_up", "e_down")}}, h,
-            dict(SIZES, n_routed_experts=2, held_first=2 * rank),
             shared=rank == 0)
         np.testing.assert_allclose(out, share, atol=2e-5)
     np.testing.assert_allclose(total, want, atol=5e-5)
@@ -254,20 +286,21 @@ def test_no_token_is_dropped_when_every_row_picks_the_same_experts(model):
                                   (5, 40, cfg.d_model)))
     gate[:, 2], gate[:, 3] = 0.02, 0.01
     moe["gate"] = jnp.asarray(gate)
-    out, stats = grouped_experts.experts_apply(h, moe, cfg)
+    out, stats = EXPERTS_APPLY(h, moe, cfg)
     assert stats["counts"].tolist() == [400, 400, 2, 1]
     layer = {**FAMILY.to_reference(params, cfg)["layers"][1],
              "router": moe["gate"]}
-    want, picks, _ = REFERENCE.expert_layer(layer, h, sizes)
+    want, picks, _ = _sized("expert_layer", sizes, layer, h)
     assert set(np.asarray(picks).ravel().tolist()) == {2, 3}
     np.testing.assert_allclose(out, want, atol=5e-5)
     # rows that are not live get no expert, only the shared one
     live = jnp.zeros((5, 40), bool).at[0].set(True)
-    out, stats = grouped_experts.experts_apply(h, moe, cfg, live=live)
+    out, stats = EXPERTS_APPLY(h, moe, cfg, live=live)
     assert stats["counts"].tolist() == [80, 80, 2, 1]
     np.testing.assert_allclose(out[0], want[0], atol=5e-5)
-    only_shared, _, _ = REFERENCE.expert_layer(
-        {**layer, "e_down": jnp.zeros_like(layer["e_down"])}, h, sizes)
+    only_shared, _, _ = _sized(
+        "expert_layer", sizes,
+        {**layer, "e_down": jnp.zeros_like(layer["e_down"])}, h)
     np.testing.assert_allclose(out[1:], only_shared[1:], atol=5e-5)
 
 
@@ -275,7 +308,7 @@ def test_latent_pool_round_trips(model, tokens):
     cfg, params, _ = model
     # 32 + 8 values, padded to the TPU's 128
     assert cfg.cache_leaves() == {"latent": (1, 128)}
-    _, row = prefill_cache(params, tokens[:1, :37], cfg, MAX_LEN)
+    _, row = PREFILL(params, tokens[:1, :37], cfg, MAX_LEN)
     assert jax.tree_util.tree_map(jnp.shape, row) == \
         jax.tree_util.tree_map(jnp.shape, init_kv_cache(cfg, 1, MAX_LEN))
     pool = paged_decode.init_paged_pool(cfg, 40, 8)

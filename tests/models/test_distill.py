@@ -12,6 +12,10 @@ from elephas_tpu.models.transformer import (TransformerConfig, init_params,
                                             make_train_step)
 from elephas_tpu.utils.text import ByteTokenizer
 
+# compiled whole: op by op, two forwards are a hundred small compiles
+DISTILL_LOSS = jax.jit(distill_loss, static_argnums=(3, 4),
+                       static_argnames=("temperature", "hard_weight"))
+
 
 @pytest.fixture(scope="module")
 def trained_target():
@@ -35,27 +39,10 @@ def _draft_config(tok):
                              max_seq_len=64, dtype=jnp.float32)
 
 
-def test_distill_loss_decreases(trained_target):
-    params, config, rows, tok = trained_target
-    dcfg = _draft_config(tok)
-    draft = init_params(dcfg, jax.random.PRNGKey(5))
-    tx = optax.adam(3e-3)
-    opt = tx.init(draft)
-    step = make_distill_step(dcfg, config, tx, temperature=2.0,
-                             hard_weight=0.1)
-    first = last = None
-    for i in range(60):
-        draft, opt, loss = step(draft, params, opt, rows)
-        if i == 0:
-            first = float(loss)
-    last = float(loss)
-    assert np.isfinite(last) and last < first * 0.7, (first, last)
-
-
-def test_distilled_draft_raises_acceptance(trained_target):
-    """The reason this module exists: on the same prompts, the distilled
-    draft's speculative acceptance beats the undistilled one's, and the
-    output stays exactly the target's greedy decode either way."""
+@pytest.fixture(scope="module")
+def distilled(trained_target):
+    """One distillation of 120 steps, which both tests below read: the
+    untrained draft, the distilled one, and the loss at each step."""
     params, config, rows, tok = trained_target
     dcfg = _draft_config(tok)
     draft0 = init_params(dcfg, jax.random.PRNGKey(5))
@@ -63,10 +50,25 @@ def test_distilled_draft_raises_acceptance(trained_target):
     opt = tx.init(draft0)
     step = make_distill_step(dcfg, config, tx, temperature=2.0,
                              hard_weight=0.1)
-    draft = draft0
+    draft, losses = draft0, []
     for _ in range(120):
-        draft, opt, _ = step(draft, params, opt, rows)
+        draft, opt, loss = step(draft, params, opt, rows)
+        losses.append(loss)
+    return dcfg, draft0, draft, [float(loss) for loss in losses]
 
+
+def test_distill_loss_decreases(distilled):
+    *_, losses = distilled
+    first, last = losses[0], losses[59]      # over the first 60 steps
+    assert np.isfinite(last) and last < first * 0.7, (first, last)
+
+
+def test_distilled_draft_raises_acceptance(trained_target, distilled):
+    """The reason this module exists: on the same prompts, the distilled
+    draft's speculative acceptance beats the undistilled one's, and the
+    output stays exactly the target's greedy decode either way."""
+    params, config, rows, _ = trained_target
+    dcfg, draft0, draft, _ = distilled
     prompt = np.asarray(rows[:4, :8])
     out0, stats0 = speculative_generate(
         params, draft0, prompt, 16, config, dcfg, gamma=4,
@@ -84,7 +86,7 @@ def test_hard_weight_zero_pure_kl(trained_target):
     params, config, rows, tok = trained_target
     dcfg = _draft_config(tok)
     draft = init_params(dcfg, jax.random.PRNGKey(6))
-    l0 = float(distill_loss(draft, params, rows, dcfg, config))
-    l_hard = float(distill_loss(draft, params, rows, dcfg, config,
+    l0 = float(DISTILL_LOSS(draft, params, rows, dcfg, config))
+    l_hard = float(DISTILL_LOSS(draft, params, rows, dcfg, config,
                                 hard_weight=0.5))
     assert np.isfinite(l0) and l_hard > l0  # CE term adds mass

@@ -23,6 +23,30 @@ def _config(**kw):
     return EncDecConfig(**base)
 
 
+# each compiled whole once a shape: op by op, a pass is a hundred
+# small compiles
+ENCODE = jax.jit(encode, static_argnums=2)
+DECODE_LOGITS = jax.jit(decode_logits, static_argnums=4)
+LOSS = jax.jit(seq2seq_loss, static_argnums=3)
+LOSS_GRAD = jax.jit(jax.grad(seq2seq_loss), static_argnums=3)
+
+
+def _assert_teacher_forced(params, memory, src, out, config):
+    """``out`` is what greedy decoding with argmax feedback produces:
+    the decoder is causal, so one teacher-forced pass over ``[bos] +
+    out[:, :-1]`` holds at each position the logits the step at that
+    position saw, and their argmax must be ``out`` there, up to a row's
+    first eos; every later position holds eos."""
+    bos = np.full((out.shape[0], 1), config.bos_token_id, out.dtype)
+    tgt_in = np.concatenate([bos, out[:, :-1]], axis=1)
+    logits = np.asarray(DECODE_LOGITS(params, memory, src,
+                                      jnp.asarray(tgt_in), config))
+    eos = out == config.eos_token_id
+    after_eos = np.cumsum(eos, axis=1) - eos > 0
+    want = np.where(after_eos, config.eos_token_id, logits.argmax(-1))
+    np.testing.assert_array_equal(out, want)
+
+
 def _copy_data(n=64, t=8, seed=0, config=None):
     c = config or _config()
     rng = np.random.default_rng(seed)
@@ -36,11 +60,11 @@ def test_shapes_and_loss():
     config = _config()
     params = init_params(config, jax.random.PRNGKey(0))
     src, tgt = _copy_data(4)
-    memory = encode(params, src, config)
+    memory = ENCODE(params, src, config)
     assert memory.shape == (4, 8, 32)
-    logits = decode_logits(params, memory, src, tgt[:, :-1], config)
+    logits = DECODE_LOGITS(params, memory, src, tgt[:, :-1], config)
     assert logits.shape == (4, 8, 32)
-    loss = float(seq2seq_loss(params, src, tgt, config))
+    loss = float(LOSS(params, src, tgt, config))
     assert np.isfinite(loss) and abs(loss - np.log(32)) < 1.0
 
 
@@ -71,19 +95,8 @@ def test_greedy_decode_matches_teacher_forced_argmax():
     src, _ = _copy_data(3, 6)
     max_len = 5
     out = np.asarray(greedy_decode(params, src, max_len, config))
-
-    # oracle: iterative full decode_logits with argmax feedback
-    memory = encode(params, src, config)
-    seq = np.full((3, 1), config.bos_token_id, dtype="int32")
-    done = np.zeros(3, bool)
-    for _ in range(max_len):
-        logits = np.asarray(decode_logits(params, memory, src,
-                                          jnp.asarray(seq), config))
-        nxt = logits[:, -1].argmax(-1).astype("int32")
-        nxt = np.where(done, config.eos_token_id, nxt)
-        done = done | (nxt == config.eos_token_id)
-        seq = np.concatenate([seq, nxt[:, None]], axis=1)
-    np.testing.assert_array_equal(out, seq[:, 1:])
+    _assert_teacher_forced(params, ENCODE(params, src, config), src, out,
+                           config)
 
 
 def test_encoder_padding_isolation_and_loss_mask():
@@ -92,25 +105,24 @@ def test_encoder_padding_isolation_and_loss_mask():
     src, tgt = _copy_data(2, 8)
     src = np.asarray(src).copy()
     src[:, 5:] = config.pad_token_id
-    m1 = np.asarray(encode(params, jnp.asarray(src), config))
-    m_short = np.asarray(encode(params, jnp.asarray(src[:, :5]), config))
+    m1 = np.asarray(ENCODE(params, jnp.asarray(src), config))
+    m_short = np.asarray(ENCODE(params, jnp.asarray(src[:, :5]), config))
     np.testing.assert_allclose(m1[:, :5], m_short, atol=1e-4)
 
     # the loss equals a manual masked CE over the teacher-forced logits
     tgt_a = np.asarray(tgt).copy()
     tgt_a[:, 6:] = config.pad_token_id
-    memory = encode(params, jnp.asarray(src), config)
+    memory = ENCODE(params, jnp.asarray(src), config)
     bos = np.full((2, 1), config.bos_token_id, dtype="int32")
     tgt_in = np.concatenate([bos, tgt_a[:, :-1]], axis=1)
-    logits = np.asarray(decode_logits(params, memory, jnp.asarray(src),
+    logits = np.asarray(DECODE_LOGITS(params, memory, jnp.asarray(src),
                                       jnp.asarray(tgt_in), config))
     logp = jax.nn.log_softmax(jnp.asarray(logits), axis=-1)
     picked = np.take_along_axis(np.asarray(logp), tgt_a[..., None],
                                 axis=-1)[..., 0]
     w = (tgt_a != config.pad_token_id)
     manual = -(picked * w).sum() / w.sum()
-    got = float(seq2seq_loss(params, jnp.asarray(src),
-                             jnp.asarray(tgt_a), config))
+    got = float(LOSS(params, jnp.asarray(src), jnp.asarray(tgt_a), config))
     np.testing.assert_allclose(got, manual, atol=1e-5, rtol=1e-5)
 
 
@@ -118,7 +130,7 @@ def test_sharded_forward_matches_unsharded():
     config = _config()
     params = init_params(config, jax.random.PRNGKey(0))
     src, tgt = _copy_data(8)
-    expected = float(seq2seq_loss(params, src, tgt, config))
+    expected = float(LOSS(params, src, tgt, config))
     mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
     sp = shard_params(params, config, mesh)
     sd = jax.device_put(src, NamedSharding(mesh, P("data", None)))
@@ -156,8 +168,7 @@ def test_review_fixes_bounds_specs_and_dropout_arity():
     sp = shard_params(params, config, mesh)  # crashed before
     got = float(jax.jit(lambda p, s, t: seq2seq_loss(p, s, t, config))(
         sp, src, tgt))
-    np.testing.assert_allclose(got, float(seq2seq_loss(params, src, tgt,
-                                                       config)),
+    np.testing.assert_allclose(got, float(LOSS(params, src, tgt, config)),
                                atol=2e-4, rtol=2e-4)
     # dropout configs REQUIRE the key
     dcfg = _config(dropout_rate=0.1)
@@ -181,25 +192,16 @@ def test_relative_position_bias():
 
     base_cfg = _config()
     base_params = {k: v for k, v in params.items() if k != "rel_bias"}
-    memory = encode(params, src, config)
-    memory_base = encode(base_params, src, base_cfg)
+    memory = ENCODE(params, src, config)
+    memory_base = ENCODE(base_params, src, base_cfg)
     assert np.abs(np.asarray(memory) - np.asarray(memory_base)).max() > 1e-6
 
     # cached greedy decode == teacher-forced argmax with bias active
     out = np.asarray(greedy_decode(params, src, 5, config))
-    seq = np.full((4, 1), config.bos_token_id, dtype="int32")
-    done = np.zeros(4, bool)
-    for _ in range(5):
-        logits = np.asarray(decode_logits(params, memory, src,
-                                          jnp.asarray(seq), config))
-        nxt = logits[:, -1].argmax(-1).astype("int32")
-        nxt = np.where(done, config.eos_token_id, nxt)
-        done = done | (nxt == config.eos_token_id)
-        seq = np.concatenate([seq, nxt[:, None]], axis=1)
-    np.testing.assert_array_equal(out, seq[:, 1:])
+    _assert_teacher_forced(params, memory, src, out, config)
 
     # bias receives gradient; specs cover it
-    g = jax.grad(seq2seq_loss)(params, src, tgt, config)
+    g = LOSS_GRAD(params, src, tgt, config)
     assert np.abs(np.asarray(g["rel_bias"]["dec"])).sum() > 0
     jax.tree_util.tree_map(lambda p, s: None, params, param_specs(config))
 

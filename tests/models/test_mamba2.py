@@ -7,6 +7,7 @@ multiplier away from 1, seeded norms) to the benchmark's plain reference,
 program. All float32 on the CPU.
 """
 import dataclasses
+import functools
 import importlib.util
 from pathlib import Path
 
@@ -23,6 +24,8 @@ from elephas_tpu.models.transformer import (Mamba2Mixer, Multipliers,
                                             param_specs, prefill_cache,
                                             prefill_cache_chunked)
 
+from ._transformer_util import FORWARD
+
 REPO = Path(__file__).resolve().parent.parent.parent
 
 
@@ -36,6 +39,9 @@ def _load(kind, name):
 
 SCAN = jax.jit(mamba2.ssd_chunk_scan, static_argnums=6)
 UPDATE = jax.jit(mamba2.ssd_update)
+# each compiled whole once a shape (and configuration): op by op, a call
+# is dozens of small compiles
+CONV = jax.jit(mamba2.causal_conv)
 FAMILY = _load("families", "falcon_h1")
 REFERENCE = _load("reference", "falcon_h1")
 SIZES = dict(FAMILY.REHEARSE_SIZES, rms_norm_eps=1e-5, rope_theta=1e11,
@@ -66,6 +72,7 @@ def whole(model):
 
 
 # ------------------------------------------------------------ recurrence
+@functools.partial(jax.jit, static_argnums=(0, 1))
 def _inputs(t, seed=0, b=2, h=4, p=8, g=2, n=16):
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
     x = jax.random.normal(keys[0], (b, t, h, p))
@@ -128,20 +135,20 @@ def test_the_convolution_carries_its_last_inputs():
          "conv_b": jnp.arange(6.0) / 10}
     xbc = jax.random.normal(jax.random.PRNGKey(1), (2, 9, 6))
     zero = jnp.zeros((2, 3, 6))
-    whole, carried = mamba2.causal_conv(p, xbc, zero)
+    whole, carried = CONV(p, xbc, zero)
     np.testing.assert_array_equal(carried, xbc[:, -3:])
     # by hand: tap k multiplies the input 3 - k tokens back
     padded = np.concatenate([np.zeros((2, 3, 6)), np.asarray(xbc)], 1)
     by_hand = sum(padded[:, k:k + 9] * np.asarray(p["conv_w"])[k]
                   for k in range(4)) + np.asarray(p["conv_b"])
     np.testing.assert_allclose(whole, jax.nn.silu(by_hand), atol=1e-5)
-    head, carried = mamba2.causal_conv(p, xbc[:, :4], zero)
+    head, carried = CONV(p, xbc[:, :4], zero)
     parts = [head]
     for k in range(4, 9):                      # then one token at a time
-        out, carried = mamba2.causal_conv(p, xbc[:, k:k + 1], carried)
+        out, carried = CONV(p, xbc[:, k:k + 1], carried)
         parts.append(out)
     np.testing.assert_allclose(jnp.concatenate(parts, 1), whole, atol=1e-6)
-    cold, _ = mamba2.causal_conv(p, xbc[:, 4:], zero)
+    cold, _ = CONV(p, xbc[:, 4:], zero)
     assert np.abs(cold[:, 0] - whole[:, 4]).max() > 0.05
 
 
@@ -169,9 +176,8 @@ def test_the_mixer_says_what_it_is():
 # ------------------------------------------------- the model's own paths
 def test_forward_is_the_plain_reference(model):
     config, params, tokens, want = model
-    np.testing.assert_allclose(
-        jax.jit(lambda p, t: forward(p, t, config))(params, tokens), want,
-        atol=5e-5)
+    np.testing.assert_allclose(FORWARD(params, tokens, config), want,
+                               atol=5e-5)
     specs = param_specs(config)
     assert (jax.tree_util.tree_structure(specs, is_leaf=lambda s: not
                                          isinstance(s, dict))
@@ -265,7 +271,7 @@ def test_every_small_leaf_of_the_mixer_is_seen(model, leaf):
     flat = jnp.ones_like if leaf in ("norm", "D") else jnp.zeros_like
     broken = dict(params, layer_1=dict(layer, ssm=dict(
         layer["ssm"], **{leaf: flat(layer["ssm"][leaf])})))
-    assert np.abs(forward(broken, tokens, config) - want).max() > 100 * 5e-5
+    assert np.abs(FORWARD(broken, tokens, config) - want).max() > 100 * 5e-5
 
 
 def test_the_gated_norm_norms_in_groups(model):
@@ -327,7 +333,9 @@ def test_a_stateless_pool_is_what_it_was():
                                 d_state=4))
     with pytest.raises(ValueError, match="register_prefix needs a snapshot"):
         paged_decode.require_stateless_cache(hybrid, "register_prefix")
-    params = init_params(hybrid, jax.random.PRNGKey(0))
+    # the parameters' structure, not their values
+    params = jax.eval_shape(lambda key: init_params(hybrid, key),
+                            jax.random.PRNGKey(0))
     assert set(params["layer_0"]["ssm"]) == {
         "w_in", "conv_w", "conv_b", "A_log", "dt_bias", "D", "norm",
         "w_out"}

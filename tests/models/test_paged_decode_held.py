@@ -8,13 +8,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from elephas_tpu.models.paged_decode import (decode_step_paged,
-                                             held_block_count,
+from elephas_tpu.models.paged_decode import (held_block_count,
                                              init_paged_pool,
                                              install_row_paged)
-from elephas_tpu.models.transformer import (TransformerConfig, decode_step,
-                                            init_params, prefill_cache)
+from elephas_tpu.models.transformer import TransformerConfig, init_params
 from elephas_tpu.serving_engine import DecodeEngine
+
+from ._transformer_util import DECODE_PAGED, DECODE_STEP, PREFILL
 
 BS = 4                  # block size
 MAX_LEN = 32            # 8 table entries a row
@@ -57,17 +57,20 @@ def ragged(request):
     want = np.zeros((rows, 64), np.float32)
     last = np.zeros(rows, np.int32)
     pos = np.zeros(rows, np.int32)
+    # one prefill of every row's whole prompt, compiled once: the model
+    # is causal, so a row's cache below position n is its n-token
+    # prefill's, and the step at n writes position n and reads no later
+    _, caches = PREFILL(params, jnp.asarray(prompts), config, MAX_LEN)
     for r, n in enumerate(CACHED):
         if n == 0:
             continue
-        _, cache = prefill_cache(params, jnp.asarray(prompts[r:r + 1, :n]),
-                                 config, MAX_LEN)
+        cache = jax.tree_util.tree_map(lambda a: a[r:r + 1], caches)
         need = n // BS + 1
         # interleaved ids: a row's blocks are not adjacent in the pool
         tables[r, :need] = 1 + r + rows * np.arange(need)
         pool = install_row_paged(pool, cache, tables[r], need)
         last[r], pos[r] = prompts[r, n], n
-        logits, _ = decode_step(params, cache, jnp.asarray(last[r:r + 1]),
+        logits, _ = DECODE_STEP(params, cache, jnp.asarray(last[r:r + 1]),
                                 jnp.asarray(pos[r:r + 1]), config)
         want[r] = np.asarray(logits[0])
     return config, params, pool, tables, last, pos, want
@@ -87,10 +90,9 @@ def test_held_step_matches_contiguous_step(ragged, width):
     held = {"exact": total, "padded": total + 5,
             "ladder-mid": (2, total - 1, total, total + 8),
             "ladder-top": (total - 2, total + 6), None: None}[width]
-    got, _ = jax.jit(
-        lambda pl: decode_step_paged(params, pl, jnp.asarray(tables),
-                                     jnp.asarray(last), jnp.asarray(pos),
-                                     config, held_blocks=held))(pool)
+    got, _ = DECODE_PAGED(params, pool, jnp.asarray(tables),
+                          jnp.asarray(last), jnp.asarray(pos), config,
+                          held_blocks=held)
     got = np.asarray(got)
     assert np.isfinite(got).all()
     active = np.asarray(CACHED) > 0
@@ -114,10 +116,10 @@ def test_padding_slots_never_change_a_logit(ragged):
     total = _held(config, pos)
     active = np.asarray(CACHED) > 0
     for held in (total, total + 9, (total - 1, total + 3), None):
-        step = jax.jit(lambda pl: decode_step_paged(
+        a, b = (np.asarray(DECODE_PAGED(
             params, pl, jnp.asarray(tables), jnp.asarray(last),
             jnp.asarray(pos), config, held_blocks=held)[0])
-        a, b = np.asarray(step(pool)), np.asarray(step(other))
+            for pl in (pool, other))
         np.testing.assert_array_equal(a[active], b[active])
 
 

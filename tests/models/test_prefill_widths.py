@@ -15,10 +15,11 @@ import pytest
 
 from elephas_tpu import serving_engine
 from elephas_tpu.models.transformer import (TransformerConfig, attend_width,
-                                            decode_block, generate,
-                                            init_kv_cache, init_params,
-                                            prefill_ladder)
+                                            decode_block, init_kv_cache,
+                                            init_params, prefill_ladder)
 from elephas_tpu.serving_engine import DecodeEngine
+
+from .._greedy import greedy_reference
 
 REPO = Path(__file__).resolve().parent.parent.parent
 MAX_LEN = 64
@@ -195,9 +196,8 @@ def served(request):
 def test_the_engine_serves_what_it_served_before(served, which):
     config, params, prompts, _, tokens, before, _ = served
     assert tokens[which] == before[which]
-    oracle = generate(params, jnp.asarray(prompts[which][None]),
-                      NEW_TOKENS, config)
-    assert tokens[which] == [int(t) for t in np.asarray(oracle)[0]]
+    assert tokens[which] == greedy_reference(params, config, prompts[which],
+                                             NEW_TOKENS)
 
 
 def test_the_counters_count_what_the_chunks_read(served):

@@ -10,10 +10,10 @@ import numpy as np
 from elephas_tpu.models.quantization import (QTensor, dequantize_lm_params,
                                              quantize_lm_params,
                                              quantize_weight)
-from elephas_tpu.models.transformer import (TransformerConfig, decode_step,
-                                            forward, generate,
-                                            init_kv_cache, init_params,
-                                            lm_loss)
+from elephas_tpu.models.transformer import (TransformerConfig, generate,
+                                            init_kv_cache, init_params)
+
+from ._transformer_util import DECODE_STEP, FORWARD, LM_LOSS
 
 
 def _config(**overrides):
@@ -47,12 +47,12 @@ def test_quantized_forward_and_loss_close():
     qparams = quantize_lm_params(params)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0,
                                 config.vocab_size)
-    ref = np.asarray(forward(params, tokens, config))
-    got = np.asarray(forward(qparams, tokens, config))
+    ref = np.asarray(FORWARD(params, tokens, config))
+    got = np.asarray(FORWARD(qparams, tokens, config))
     # int8 per-channel keeps logits within a few percent of fp scale
     assert np.abs(got - ref).max() < 0.05 * np.abs(ref).max() + 0.05
-    l_ref = float(lm_loss(params, tokens, config))
-    l_q = float(lm_loss(qparams, tokens, config))
+    l_ref = float(LM_LOSS(params, tokens, config))
+    l_q = float(LM_LOSS(qparams, tokens, config))
     assert abs(l_q - l_ref) < 0.05 * l_ref
 
 
@@ -65,10 +65,10 @@ def test_quantized_decode_matches_quantized_forward():
     params = quantize_lm_params(init_params(config, jax.random.PRNGKey(0)))
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 10),
                                            0, config.vocab_size))
-    full = np.asarray(forward(params, jnp.asarray(tokens), config))
+    full = np.asarray(FORWARD(params, jnp.asarray(tokens), config))
     cache = init_kv_cache(config, 2, max_len=10)
     for t in range(10):
-        logits, cache = decode_step(params, cache,
+        logits, cache = DECODE_STEP(params, cache,
                                     jnp.asarray(tokens[:, t]), t, config)
         np.testing.assert_allclose(np.asarray(logits), full[:, t],
                                    atol=2e-3, rtol=2e-3)
@@ -102,8 +102,8 @@ def test_quantize_moe_and_untied_head():
     assert not isinstance(qparams["layer_0"]["moe"]["gate"], QTensor)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                 config.vocab_size)
-    ref = np.asarray(forward(params, tokens, config))
-    got = np.asarray(forward(qparams, tokens, config))
+    ref = np.asarray(FORWARD(params, tokens, config))
+    got = np.asarray(FORWARD(qparams, tokens, config))
     assert np.abs(got - ref).max() < 0.05 * np.abs(ref).max() + 0.05
 
 
@@ -116,12 +116,12 @@ def test_quantized_untied_head_chunked_loss():
     assert isinstance(qparams["head"], QTensor)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                 config.vocab_size)
-    l_ref = float(lm_loss(params, tokens, config))
-    l_q = float(lm_loss(qparams, tokens, config))
+    l_ref = float(LM_LOSS(params, tokens, config))
+    l_q = float(LM_LOSS(qparams, tokens, config))
     assert abs(l_q - l_ref) < 0.05 * l_ref
     # chunked and dense quantized losses agree with each other too
     dense_cfg = dataclasses.replace(config, loss_vocab_chunk=None)
-    l_dense = float(lm_loss(qparams, tokens, dense_cfg))
+    l_dense = float(LM_LOSS(qparams, tokens, dense_cfg))
     np.testing.assert_allclose(l_q, l_dense, atol=1e-5, rtol=1e-5)
 
 
@@ -138,9 +138,9 @@ def test_int8_kv_cache_decode_close_to_fp():
     assert cache_q["layer_0"]["k"].dtype == jnp.int8
     max_rel = 0.0
     for t in range(12):
-        l_fp, cache_fp = decode_step(params, cache_fp,
+        l_fp, cache_fp = DECODE_STEP(params, cache_fp,
                                      jnp.asarray(tokens[:, t]), t, config)
-        l_q, cache_q = decode_step(params, cache_q,
+        l_q, cache_q = DECODE_STEP(params, cache_q,
                                    jnp.asarray(tokens[:, t]), t, qcfg)
         diff = np.abs(np.asarray(l_q) - np.asarray(l_fp)).max()
         max_rel = max(max_rel, diff / (np.abs(np.asarray(l_fp)).max()

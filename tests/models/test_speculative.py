@@ -9,9 +9,10 @@ import numpy as np
 import pytest
 
 from elephas_tpu.models.speculative import speculative_generate
-from elephas_tpu.models.transformer import (TransformerConfig, decode_block,
-                                            decode_step, generate,
-                                            init_params, prefill_cache)
+from elephas_tpu.models.transformer import (TransformerConfig, generate,
+                                            init_params)
+
+from ._transformer_util import DECODE_BLOCK, DECODE_STEP, PREFILL
 
 
 def _config(**overrides):
@@ -50,14 +51,14 @@ def test_decode_block_matches_stepwise(variant):
     params = init_params(config, jax.random.PRNGKey(0))
     prompt = jax.random.randint(jax.random.PRNGKey(1), (3, 6), 0,
                                 config.vocab_size)
-    _, cache = prefill_cache(params, prompt, config, 32)
+    _, cache = PREFILL(params, prompt, config, 32)
     toks = jax.random.randint(jax.random.PRNGKey(2), (3, 4), 0,
                               config.vocab_size)
 
-    block_logits, block_cache = decode_block(params, cache, toks, 6, config)
+    block_logits, block_cache = DECODE_BLOCK(params, cache, toks, 6, config)
     step_cache, step_logits = cache, []
     for j in range(4):
-        lg, step_cache = decode_step(params, step_cache, toks[:, j], 6 + j,
+        lg, step_cache = DECODE_STEP(params, step_cache, toks[:, j], 6 + j,
                                      config)
         step_logits.append(lg)
     np.testing.assert_allclose(np.asarray(block_logits),
@@ -75,18 +76,18 @@ def test_vector_positions_match_scalar(variant):
     params = init_params(config, jax.random.PRNGKey(0))
     prompt = jax.random.randint(jax.random.PRNGKey(1), (3, 6), 0,
                                 config.vocab_size)
-    _, cache = prefill_cache(params, prompt, config, 32)
+    _, cache = PREFILL(params, prompt, config, 32)
     toks = jax.random.randint(jax.random.PRNGKey(2), (3, 4), 0,
                               config.vocab_size)
     vec = jnp.full((3,), 6, jnp.int32)
 
-    ls, cs = decode_step(params, cache, toks[:, 0], 6, config)
-    lv, cv = decode_step(params, cache, toks[:, 0], vec, config)
+    ls, cs = DECODE_STEP(params, cache, toks[:, 0], 6, config)
+    lv, cv = DECODE_STEP(params, cache, toks[:, 0], vec, config)
     np.testing.assert_allclose(np.asarray(ls), np.asarray(lv), atol=1e-6)
     assert _cache_diff(cs, cv) == 0.0
 
-    bs, cbs = decode_block(params, cache, toks, 6, config)
-    bv, cbv = decode_block(params, cache, toks, vec, config)
+    bs, cbs = DECODE_BLOCK(params, cache, toks, 6, config)
+    bv, cbv = DECODE_BLOCK(params, cache, toks, vec, config)
     np.testing.assert_allclose(np.asarray(bs), np.asarray(bv), atol=1e-6)
     assert _cache_diff(cbs, cbv) == 0.0
 
@@ -102,15 +103,15 @@ def test_vector_positions_genuinely_ragged():
     # per-row caches built independently at each row's own length
     row_logits = []
     for b, ln in enumerate(lens):
-        _, c1 = prefill_cache(params, full[b:b + 1, :ln], config, 32)
-        lg, _ = decode_step(params, c1, full[b:b + 1, ln], ln, config)
+        _, c1 = PREFILL(params, full[b:b + 1, :ln], config, 32)
+        lg, _ = DECODE_STEP(params, c1, full[b:b + 1, ln], ln, config)
         row_logits.append(lg)
     # one batched cache: prefill the longer row, then a vector-pos step
-    _, cache = prefill_cache(params, full[:, :7], config, 32)
+    _, cache = PREFILL(params, full[:, :7], config, 32)
     # row 1's cache holds garbage past position 3, which the per-row
     # length mask must hide
     toks = jnp.stack([full[0, 7], full[1, 4]])
-    lg, _ = decode_step(params, cache, toks,
+    lg, _ = DECODE_STEP(params, cache, toks,
                         jnp.asarray(lens, jnp.int32), config)
     np.testing.assert_allclose(np.asarray(lg[0]), np.asarray(row_logits[0][0]),
                                atol=2e-5)
@@ -253,11 +254,18 @@ def test_decode_block_paged_matches_decode_block(variant):
     lens = [3, 6, 9]                               # ragged rows
     nb = max_len // bs
     pool = init_paged_pool(config, 1 + len(lens) * nb, bs)
+    # the rows' prompts, right-padded, in one prefill compiled once: the
+    # model is causal, so a row's cache below position n is its own
+    # n-token prefill's, and the block at n writes n on and reads no
+    # position after its own
+    prompts = np.zeros((len(lens), max(lens)), np.int32)
+    for r, n in enumerate(lens):
+        prompts[r, :n] = jax.random.randint(jax.random.PRNGKey(10 + r),
+                                            (1, n), 0, config.vocab_size)
+    _, caches = PREFILL(params, jnp.asarray(prompts), config, max_len)
     tables, row_caches = [], []
     for r, n in enumerate(lens):
-        prompt = jax.random.randint(jax.random.PRNGKey(10 + r), (1, n),
-                                    0, config.vocab_size)
-        _, row = prefill_cache(params, prompt, config, max_len)
+        row = jax.tree_util.tree_map(lambda a: a[r:r + 1], caches)
         row_caches.append(row)
         ids = [1 + r * nb + j for j in range(nb)]
         pool = install_row_paged(pool, row, ids, nb)
@@ -265,12 +273,12 @@ def test_decode_block_paged_matches_decode_block(variant):
     toks = jax.random.randint(jax.random.PRNGKey(2), (len(lens), s), 0,
                               config.vocab_size)
 
-    paged_logits, pool = decode_block_paged(
+    paged_logits, pool = jax.jit(decode_block_paged, static_argnums=5)(
         params, pool, jnp.asarray(tables), toks,
         jnp.asarray(lens, jnp.int32), config)
 
     for r, n in enumerate(lens):
-        ref_logits, ref_cache = decode_block(params, row_caches[r],
+        ref_logits, ref_cache = DECODE_BLOCK(params, row_caches[r],
                                              toks[r:r + 1], n, config)
         np.testing.assert_allclose(np.asarray(paged_logits[r]),
                                    np.asarray(ref_logits[0]), atol=2e-5)

@@ -7,12 +7,16 @@ import optax
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from elephas_tpu.models.transformer import (TransformerConfig, forward,
-                                            init_params, lm_loss,
+from elephas_tpu.models.transformer import (TransformerConfig, _moe_block,
+                                            forward, init_params, lm_loss,
                                             make_train_step, param_specs,
                                             shard_params)
 
-from ._transformer_util import _config, _moe_config
+from ._transformer_util import (DECODE_STEP, FORWARD, LM_LOSS,
+                                LOSS_AND_GRAD, _config, _moe_config)
+
+MOE_BLOCK = jax.jit(_moe_block, static_argnums=2,
+                    static_argnames="dispatch")
 
 
 def test_forward_shapes_and_loss():
@@ -20,9 +24,9 @@ def test_forward_shapes_and_loss():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                 config.vocab_size)
-    logits = forward(params, tokens, config)
+    logits = FORWARD(params, tokens, config)
     assert logits.shape == (2, 16, 64)
-    loss = float(lm_loss(params, tokens, config))
+    loss = float(LM_LOSS(params, tokens, config))
     assert np.isfinite(loss)
     # untrained LM loss should be near log(vocab)
     assert abs(loss - np.log(config.vocab_size)) < 1.0
@@ -55,12 +59,12 @@ def test_flash_attention_impl_matches_xla():
     # force the XLA reference: on a TPU backend 'auto' would also resolve
     # to flash, making the comparison vacuous
     xla_config = dataclasses.replace(config, attention_impl="xla")
-    ref = forward(params, tokens, xla_config)
-    got = forward(params, tokens, flash_config)
+    ref = FORWARD(params, tokens, xla_config)
+    got = FORWARD(params, tokens, flash_config)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4,
                                rtol=1e-4)
-    g_ref = jax.grad(lm_loss)(params, tokens, xla_config)
-    g_flash = jax.grad(lm_loss)(params, tokens, flash_config)
+    _, g_ref = LOSS_AND_GRAD(params, tokens, xla_config)
+    _, g_flash = LOSS_AND_GRAD(params, tokens, flash_config)
     flat_ref, _ = jax.tree_util.tree_flatten(g_ref)
     flat_flash, _ = jax.tree_util.tree_flatten(g_flash)
     for a, b in zip(flat_flash, flat_ref):
@@ -74,7 +78,7 @@ def test_moe_forward_and_training():
     assert "moe" in params["layer_0"] and "mlp" not in params["layer_0"]
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    logits = forward(params, tokens, config)
+    logits = FORWARD(params, tokens, config)
     assert logits.shape == (4, 16, config.vocab_size)
     tx = optax.adam(1e-2)
     opt_state = tx.init(params)
@@ -90,15 +94,13 @@ def test_moe_forward_and_training():
 def test_moe_top1_routes_to_single_expert():
     """With top_k=1 the block output must equal the argmax expert's MLP
     scaled by its raw softmax probability (Switch-style gating)."""
-    from elephas_tpu.models.transformer import _moe_block
-
     config = _moe_config(num_experts=3, expert_top_k=1,
                          num_layers=1)
     params = init_params(config, jax.random.PRNGKey(0))
     moe = params["layer_0"]["moe"]
     h = jax.random.normal(jax.random.PRNGKey(2), (2, 5, config.d_model),
                           jnp.float32)
-    out, aux = _moe_block(h, moe, config)
+    out, aux = MOE_BLOCK(h, moe, config)
     out = np.asarray(out)
     assert np.isfinite(float(aux)) and float(aux) >= 1.0  # >= uniform bound
     probs = np.asarray(jax.nn.softmax(h @ moe["gate"], axis=-1))
@@ -121,7 +123,7 @@ def test_moe_router_receives_gradient():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    grads = jax.grad(lm_loss)(params, tokens, config)
+    _, grads = LOSS_AND_GRAD(params, tokens, config)
     gate_grad = np.asarray(grads["layer_0"]["moe"]["gate"])
     assert np.abs(gate_grad).max() > 0.0
 
@@ -133,7 +135,7 @@ def test_moe_sharded_matches_unsharded():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    expected = np.asarray(forward(params, tokens, config))
+    expected = np.asarray(FORWARD(params, tokens, config))
 
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
     params_sharded = shard_params(params, config, mesh)
@@ -148,16 +150,14 @@ def test_moe_routed_matches_dense_when_nothing_drops():
     """With capacity_factor = E/k the capacity equals the token count, so
     no assignment can drop and routed dispatch must agree with dense
     dispatch exactly (same router, same experts, different data path)."""
-    from elephas_tpu.models.transformer import _moe_block
-
     config = _moe_config(num_experts=4, expert_top_k=2, num_layers=1,
                          moe_capacity_factor=2.0)  # C = N: lossless
     params = init_params(config, jax.random.PRNGKey(0))
     moe = params["layer_0"]["moe"]
     h = jax.random.normal(jax.random.PRNGKey(2), (2, 8, config.d_model),
                           jnp.float32)
-    dense, aux_d = _moe_block(h, moe, config, dispatch="dense")
-    routed, aux_r = _moe_block(h, moe, config, dispatch="routed")
+    dense, aux_d = MOE_BLOCK(h, moe, config, dispatch="dense")
+    routed, aux_r = MOE_BLOCK(h, moe, config, dispatch="routed")
     np.testing.assert_allclose(np.asarray(routed), np.asarray(dense),
                                atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(float(aux_r), float(aux_d), rtol=1e-6)
@@ -166,8 +166,6 @@ def test_moe_routed_matches_dense_when_nothing_drops():
 def test_moe_routed_flops_scale_with_top_k_not_experts():
     """The point of routed dispatch: expert-MLP FLOPs stay ~constant as
     num_experts grows (dense doubles when E doubles)."""
-    from elephas_tpu.models.transformer import _moe_block
-
     def flops(num_experts, dispatch):
         config = _moe_config(num_experts=num_experts, expert_top_k=2,
                              num_layers=1, moe_capacity_factor=1.0)
@@ -190,8 +188,6 @@ def test_moe_routed_drops_over_capacity_tokens():
     """Assignments beyond an expert's capacity contribute nothing: with a
     gate forced to a single expert and capacity < N, exactly the first
     `capacity` tokens (token-major priority) produce output."""
-    from elephas_tpu.models.transformer import _moe_block
-
     config = _moe_config(num_experts=4, expert_top_k=1, num_layers=1,
                          moe_capacity_factor=1.0)  # C = N/E = 2
     params = init_params(config, jax.random.PRNGKey(0))
@@ -201,7 +197,7 @@ def test_moe_routed_drops_over_capacity_tokens():
     moe["gate"] = jnp.zeros_like(moe["gate"])
     h = jax.random.normal(jax.random.PRNGKey(2), (1, 8, config.d_model),
                           jnp.float32)
-    out, _ = _moe_block(h, moe, config, dispatch="routed")
+    out, _ = MOE_BLOCK(h, moe, config, dispatch="routed")
     out = np.asarray(out)
     capacity = 2  # ceil(1.0 * 1 * 8 / 4)
     assert np.abs(out[0, :capacity]).max() > 0
@@ -214,7 +210,7 @@ def test_moe_routed_trains_and_router_gets_gradient():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    grads = jax.grad(lm_loss)(params, tokens, config)
+    _, grads = LOSS_AND_GRAD(params, tokens, config)
     assert np.abs(np.asarray(grads["layer_0"]["moe"]["gate"])).max() > 0
     tx = optax.adam(1e-2)
     opt_state = tx.init(params)
@@ -256,7 +252,7 @@ def test_moe_routed_ep_matches_unsharded_routed():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    expected = np.asarray(forward(params, tokens, config))
+    expected = np.asarray(FORWARD(params, tokens, config))
 
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
     params_d = shard_params(params, config, mesh)
@@ -316,7 +312,7 @@ def test_forced_routed_with_non_divisible_model_axis_stays_routed():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    expected = np.asarray(forward(params, tokens, config))
+    expected = np.asarray(FORWARD(params, tokens, config))
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "model"))
     # E=2 can't shard over a 4-way axis: params stay replicated
     got = np.asarray(jax.jit(
@@ -334,10 +330,10 @@ def test_remat_matches_baseline_values_and_grads():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                 config.vocab_size)
     np.testing.assert_allclose(
-        np.asarray(forward(params, tokens, remat_config)),
-        np.asarray(forward(params, tokens, config)), atol=1e-6)
-    g = jax.grad(lm_loss)(params, tokens, config)
-    g_r = jax.grad(lm_loss)(params, tokens, remat_config)
+        np.asarray(FORWARD(params, tokens, remat_config)),
+        np.asarray(FORWARD(params, tokens, config)), atol=1e-6)
+    _, g = LOSS_AND_GRAD(params, tokens, config)
+    _, g_r = LOSS_AND_GRAD(params, tokens, remat_config)
     for a, b in zip(jax.tree_util.tree_leaves(g_r),
                     jax.tree_util.tree_leaves(g)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
@@ -362,13 +358,12 @@ def test_remat_dots_policy_matches_values_and_grads():
     base = _config()
     params = init_params(base, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, 64)
-    ref = float(lm_loss(params, tokens, base))
-    g_ref = jax.grad(lm_loss)(params, tokens, base)
+    ref, g_ref = LOSS_AND_GRAD(params, tokens, base)
     for policy in ("full", "dots"):
         cfg = dataclasses.replace(base, remat=True, remat_policy=policy)
-        np.testing.assert_allclose(float(lm_loss(params, tokens, cfg)),
-                                   ref, atol=1e-5, rtol=1e-5)
-        g = jax.grad(lm_loss)(params, tokens, cfg)
+        loss, g = LOSS_AND_GRAD(params, tokens, cfg)
+        np.testing.assert_allclose(float(loss), float(ref), atol=1e-5,
+                                   rtol=1e-5)
         for a, b in zip(jax.tree_util.tree_leaves(g),
                         jax.tree_util.tree_leaves(g_ref)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -383,7 +378,7 @@ def test_moe_shared_expert():
     MoE combine, consistent across dense/routed dispatch and decode."""
     import dataclasses
 
-    from elephas_tpu.models.transformer import decode_step, init_kv_cache
+    from elephas_tpu.models.transformer import init_kv_cache
 
     config = _moe_config(num_experts=4, expert_top_k=2)
     shared_cfg = dataclasses.replace(config, moe_shared_expert=True)
@@ -393,21 +388,21 @@ def test_moe_shared_expert():
                                            0, shared_cfg.vocab_size))
 
     # the shared path participates: zeroing it changes the output
-    full = np.asarray(forward(params, jnp.asarray(tokens), shared_cfg))
+    full = np.asarray(FORWARD(params, jnp.asarray(tokens), shared_cfg))
     import copy
 
     zeroed = copy.deepcopy(jax.device_get(params))
     for i in range(shared_cfg.num_layers):
         sh = zeroed[f"layer_{i}"]["moe"]["shared"]
         sh["w2"] = np.zeros_like(sh["w2"])
-    out_z = np.asarray(forward(jax.tree_util.tree_map(jnp.asarray, zeroed),
+    out_z = np.asarray(FORWARD(jax.tree_util.tree_map(jnp.asarray, zeroed),
                                jnp.asarray(tokens), shared_cfg))
     assert np.abs(full - out_z).max() > 1e-6
 
     # decode parity with forward
     cache = init_kv_cache(shared_cfg, 2, max_len=8)
     for t in range(8):
-        logits, cache = decode_step(params, cache,
+        logits, cache = DECODE_STEP(params, cache,
                                     jnp.asarray(tokens[:, t]), t,
                                     shared_cfg)
         np.testing.assert_allclose(np.asarray(logits), full[:, t],
@@ -423,7 +418,7 @@ def test_moe_shared_expert():
         params, opt, loss = step(params, opt, jt)
         first = first if first is not None else float(loss)
     assert float(loss) < first
-    g = jax.grad(lm_loss)(params, jt, shared_cfg)
+    _, g = LOSS_AND_GRAD(params, jt, shared_cfg)
     assert np.abs(np.asarray(
         g["layer_0"]["moe"]["shared"]["w1"])).sum() > 0
 

@@ -9,10 +9,12 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from elephas_tpu.models.transformer import (TransformerConfig, forward,
-                                            init_params, lm_loss,
-                                            make_train_step, shard_params)
+                                            init_params, make_train_step,
+                                            shard_params)
 
-from ._transformer_util import _config, _moe_config, _rope_config, _gqa_config
+from ._transformer_util import (DECODE_STEP, FORWARD, LM_LOSS, _config,
+                                _gqa_config, _moe_config, _rope_config,
+                                assert_greedy_continuation)
 
 
 def test_decode_step_matches_forward_teacher_forced():
@@ -24,7 +26,7 @@ def test_decode_step_matches_forward_teacher_forced():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
                                            config.vocab_size))
-    full = np.asarray(forward(params, jnp.asarray(tokens), config))
+    full = np.asarray(FORWARD(params, jnp.asarray(tokens), config))
 
     cache = init_kv_cache(config, 2, max_len=12)
     step = jax.jit(lambda cache, tok, pos: decode_step(params, cache, tok,
@@ -42,7 +44,7 @@ def test_decode_step_matches_forward_moe():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
                                            config.vocab_size))
-    full = np.asarray(forward(params, jnp.asarray(tokens), config))
+    full = np.asarray(FORWARD(params, jnp.asarray(tokens), config))
     cache = init_kv_cache(config, 2, max_len=8)
     step = jax.jit(lambda cache, tok, pos: decode_step(params, cache, tok,
                                                        pos, config))
@@ -65,12 +67,7 @@ def test_generate_greedy_is_deterministic_and_shaped():
     np.testing.assert_array_equal(out1, out2)
     assert (out1 >= 0).all() and (out1 < config.vocab_size).all()
     # greedy continuation must equal step-by-step argmax over forward
-    seq = np.asarray(prompt)
-    for _ in range(6):
-        logits = np.asarray(forward(params, jnp.asarray(seq), config))
-        seq = np.concatenate([seq, logits[:, -1].argmax(-1)[:, None]],
-                             axis=1)
-    np.testing.assert_array_equal(out1, seq[:, 5:])
+    assert_greedy_continuation(params, prompt, out1, config)
 
 
 def test_generate_sampling_and_length_validation():
@@ -103,7 +100,7 @@ def test_decode_step_routed_config_uses_dense_gating():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0,
                                            config.vocab_size))
-    full = np.asarray(forward(params, jnp.asarray(tokens), dense_config))
+    full = np.asarray(FORWARD(params, jnp.asarray(tokens), dense_config))
     cache = init_kv_cache(config, 2, max_len=8)
     step = jax.jit(lambda cache, tok, pos: decode_step(params, cache, tok,
                                                        pos, config))
@@ -120,7 +117,7 @@ def test_rope_decode_matches_forward():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 10),
                                            0, config.vocab_size))
-    full = np.asarray(forward(params, jnp.asarray(tokens), config))
+    full = np.asarray(FORWARD(params, jnp.asarray(tokens), config))
     cache = init_kv_cache(config, 2, max_len=10)
     step = jax.jit(lambda cache, tok, pos: decode_step(params, cache, tok,
                                                        pos, config))
@@ -138,12 +135,7 @@ def test_rope_generate_greedy_matches_forward_loop():
     prompt = jax.random.randint(jax.random.PRNGKey(1), (2, 4), 0,
                                 config.vocab_size)
     out = np.asarray(generate(params, prompt, 5, config))
-    seq = np.asarray(prompt)
-    for _ in range(5):
-        logits = np.asarray(forward(params, jnp.asarray(seq), config))
-        seq = np.concatenate([seq, logits[:, -1].argmax(-1)[:, None]],
-                             axis=1)
-    np.testing.assert_array_equal(out, seq[:, 4:])
+    assert_greedy_continuation(params, prompt, out, config)
 
 
 def test_gqa_decode_matches_forward_and_cache_is_smaller():
@@ -156,7 +148,7 @@ def test_gqa_decode_matches_forward_and_cache_is_smaller():
         params = init_params(config, jax.random.PRNGKey(0))
         tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1),
                                                (2, 10), 0, config.vocab_size))
-        full = np.asarray(forward(params, jnp.asarray(tokens), config))
+        full = np.asarray(FORWARD(params, jnp.asarray(tokens), config))
         cache = init_kv_cache(config, 2, max_len=10)
         assert cache["layer_0"]["k"].shape == (2, kv, 10, config.head_dim)
         step = jax.jit(lambda cache, tok, pos: decode_step(
@@ -179,12 +171,7 @@ def test_gqa_rope_generate_runs():
     out = np.asarray(generate(params, prompt, 5, config))
     assert out.shape == (2, 5)
     # greedy continuation equals argmax over the full forward
-    seq = np.asarray(prompt)
-    for _ in range(5):
-        logits = np.asarray(forward(params, jnp.asarray(seq), config))
-        seq = np.concatenate([seq, logits[:, -1].argmax(-1)[:, None]],
-                             axis=1)
-    np.testing.assert_array_equal(out, seq[:, 4:])
+    assert_greedy_continuation(params, prompt, out, config)
 
 
 def test_generate_top_k_and_top_p_sampling():
@@ -249,7 +236,7 @@ def test_beam_search_beats_greedy_and_beam1_equals_greedy():
 
     def joint_logp(seq_tokens):
         full = np.concatenate([np.asarray(prompt), seq_tokens], axis=1)
-        logits = np.asarray(forward(params, jnp.asarray(full), config))
+        logits = np.asarray(FORWARD(params, jnp.asarray(full), config))
         logp = jax.nn.log_softmax(jnp.asarray(logits), axis=-1)
         total = np.zeros(full.shape[0])
         for t in range(6):
@@ -308,7 +295,7 @@ def test_llama_style_config_trains_and_decodes():
     + untied head + chunked loss — trains, and decode matches forward."""
     import dataclasses
 
-    from elephas_tpu.models.transformer import decode_step, init_kv_cache
+    from elephas_tpu.models.transformer import init_kv_cache
 
     config = TransformerConfig(vocab_size=64, num_layers=2, num_heads=4,
                                num_kv_heads=2, d_model=32, d_ff=64,
@@ -320,10 +307,10 @@ def test_llama_style_config_trains_and_decodes():
     assert "w3" in params["layer_0"]["mlp"]
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (4, 12),
                                            0, 64))
-    full = np.asarray(forward(params, jnp.asarray(tokens), config))
+    full = np.asarray(FORWARD(params, jnp.asarray(tokens), config))
     cache = init_kv_cache(config, 4, max_len=12)
     for t in range(12):
-        logits, cache = decode_step(params, cache,
+        logits, cache = DECODE_STEP(params, cache,
                                     jnp.asarray(tokens[:, t]), t, config)
         np.testing.assert_allclose(np.asarray(logits), full[:, t],
                                    atol=2e-4, rtol=2e-4)
@@ -331,8 +318,8 @@ def test_llama_style_config_trains_and_decodes():
     # chunked == dense loss for this config too
     dense_cfg = dataclasses.replace(config, loss_vocab_chunk=None)
     np.testing.assert_allclose(
-        float(lm_loss(params, jnp.asarray(tokens), config)),
-        float(lm_loss(params, jnp.asarray(tokens), dense_cfg)),
+        float(LM_LOSS(params, jnp.asarray(tokens), config)),
+        float(LM_LOSS(params, jnp.asarray(tokens), dense_cfg)),
         atol=1e-5, rtol=1e-5)
 
     tx = optax.adam(1e-2)
@@ -352,14 +339,14 @@ def test_llama_style_config_trains_and_decodes():
     sharded = np.asarray(jax.jit(
         lambda p, t: forward(p, t, config, mesh=mesh, batch_axis="data",
                              model_axis="model"))(sp, td))
-    expected = np.asarray(forward(params, jnp.asarray(tokens), config))
+    expected = np.asarray(FORWARD(params, jnp.asarray(tokens), config))
     np.testing.assert_allclose(expected, sharded, atol=2e-3)
 
 
 def test_sliding_window_attention_semantics_and_decode_parity():
     import dataclasses
 
-    from elephas_tpu.models.transformer import decode_step, init_kv_cache
+    from elephas_tpu.models.transformer import init_kv_cache
 
     base = _config()
     params = init_params(base, jax.random.PRNGKey(0))
@@ -369,14 +356,14 @@ def test_sliding_window_attention_semantics_and_decode_parity():
     # a window covering the whole sequence equals full causal attention
     wide = dataclasses.replace(base, attention_window=64)
     np.testing.assert_allclose(
-        np.asarray(forward(params, jnp.asarray(tokens), wide)),
-        np.asarray(forward(params, jnp.asarray(tokens), base)),
+        np.asarray(FORWARD(params, jnp.asarray(tokens), wide)),
+        np.asarray(FORWARD(params, jnp.asarray(tokens), base)),
         atol=1e-5, rtol=1e-5)
 
     # a tight window changes late positions but NOT the first `w`
     tight = dataclasses.replace(base, attention_window=3)
-    out_t = np.asarray(forward(params, jnp.asarray(tokens), tight))
-    out_f = np.asarray(forward(params, jnp.asarray(tokens), base))
+    out_t = np.asarray(FORWARD(params, jnp.asarray(tokens), tight))
+    out_f = np.asarray(FORWARD(params, jnp.asarray(tokens), base))
     np.testing.assert_allclose(out_t[:, :3], out_f[:, :3], atol=1e-5,
                                rtol=1e-5)
     assert np.abs(out_t[:, 6:] - out_f[:, 6:]).max() > 1e-5
@@ -384,7 +371,7 @@ def test_sliding_window_attention_semantics_and_decode_parity():
     # teacher-forced decode must match the windowed forward
     cache = init_kv_cache(tight, 2, max_len=12)
     for t in range(12):
-        logits, cache = decode_step(params, cache,
+        logits, cache = DECODE_STEP(params, cache,
                                     jnp.asarray(tokens[:, t]), t, tight)
         np.testing.assert_allclose(np.asarray(logits), out_t[:, t],
                                    atol=2e-4, rtol=2e-4)
@@ -414,12 +401,7 @@ def test_sliding_window_trains_and_generates():
     out = np.asarray(generate(params, tokens[:2, :4], 6, config))
     assert out.shape == (2, 6)
     # greedy continuation equals argmax over the windowed forward
-    seq = np.asarray(tokens[:2, :4])
-    for _ in range(6):
-        logits = np.asarray(forward(params, jnp.asarray(seq), config))
-        seq = np.concatenate([seq, logits[:, -1].argmax(-1)[:, None]],
-                             axis=1)
-    np.testing.assert_array_equal(out, seq[:, 4:])
+    assert_greedy_continuation(params, tokens[:2, :4], out, config)
 
 
 def test_repetition_penalty_suppresses_repeats():
@@ -452,21 +434,21 @@ def test_repetition_penalty_suppresses_repeats():
 def test_sinusoidal_positions_train_and_decode():
     import dataclasses
 
-    from elephas_tpu.models.transformer import decode_step, init_kv_cache
+    from elephas_tpu.models.transformer import init_kv_cache
 
     config = dataclasses.replace(_config(), positional="sinusoidal")
     params = init_params(config, jax.random.PRNGKey(0))
     assert "pos" not in params["embed"]  # parameter-free
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 10),
                                            0, 64))
-    full = np.asarray(forward(params, jnp.asarray(tokens), config))
+    full = np.asarray(FORWARD(params, jnp.asarray(tokens), config))
     # position-sensitive: permuting the sequence changes logits
     perm = np.asarray(tokens)[:, ::-1].copy()
-    assert np.abs(np.asarray(forward(params, jnp.asarray(perm), config))
+    assert np.abs(np.asarray(FORWARD(params, jnp.asarray(perm), config))
                   [:, -1] - full[:, -1]).max() > 1e-6
     cache = init_kv_cache(config, 2, max_len=10)
     for t in range(10):
-        logits, cache = decode_step(params, cache,
+        logits, cache = DECODE_STEP(params, cache,
                                     jnp.asarray(tokens[:, t]), t, config)
         np.testing.assert_allclose(np.asarray(logits), full[:, t],
                                    atol=2e-4, rtol=2e-4)
@@ -520,8 +502,7 @@ def test_ragged_prompt_generation_matches_per_row():
 def test_alibi_positions_decode_parity_and_extrapolation():
     import dataclasses
 
-    from elephas_tpu.models.transformer import (_alibi_slopes, decode_step,
-                                                init_kv_cache)
+    from elephas_tpu.models.transformer import _alibi_slopes, init_kv_cache
 
     slopes = np.asarray(_alibi_slopes(8))
     np.testing.assert_allclose(slopes[0], 2 ** -1.0, rtol=1e-6)
@@ -532,12 +513,12 @@ def test_alibi_positions_decode_parity_and_extrapolation():
     assert "pos" not in params["embed"]
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 10),
                                            0, 64))
-    full = np.asarray(forward(params, jnp.asarray(tokens), config))
+    full = np.asarray(FORWARD(params, jnp.asarray(tokens), config))
     # position-sensitive
     base = dataclasses.replace(_config(), positional="sinusoidal")
     cache = init_kv_cache(config, 2, max_len=10)
     for t in range(10):
-        logits, cache = decode_step(params, cache,
+        logits, cache = DECODE_STEP(params, cache,
                                     jnp.asarray(tokens[:, t]), t, config)
         np.testing.assert_allclose(np.asarray(logits), full[:, t],
                                    atol=2e-4, rtol=2e-4)
@@ -551,7 +532,7 @@ def test_alibi_positions_decode_parity_and_extrapolation():
         first = first if first is not None else float(loss)
     assert float(loss) < first
     long_tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 48), 0, 64)
-    out = forward(params, long_tokens, config)  # 48 > max_seq_len=32
+    out = FORWARD(params, long_tokens, config)  # 48 > max_seq_len=32
     assert np.isfinite(np.asarray(out)).all()
 
 

@@ -14,7 +14,9 @@ from elephas_tpu.utils.checkpoint import CheckpointManager
 
 
 def _config(**kw):
-    base = dict(vocab_size=64, num_layers=2, num_heads=4, d_model=32,
+    # one layer: these tests exist for the model surface (fit, callbacks,
+    # checkpoints, meshes), which a second layer only compiles again
+    base = dict(vocab_size=64, num_layers=1, num_heads=4, d_model=32,
                 d_ff=64, max_seq_len=16, dtype=jnp.float32)
     base.update(kw)
     return TransformerConfig(**base)

@@ -11,7 +11,8 @@ from elephas_tpu.models.transformer import (forward, init_params, lm_loss,
                                             make_train_step, param_specs,
                                             shard_params)
 
-from ._transformer_util import _config, _rope_config, _gqa_config
+from ._transformer_util import (FORWARD, LM_LOSS, LOSS_AND_GRAD, _config,
+                                _gqa_config, _rope_config)
 
 
 def test_sharded_forward_matches_unsharded():
@@ -19,7 +20,7 @@ def test_sharded_forward_matches_unsharded():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    expected = np.asarray(forward(params, tokens, config))
+    expected = np.asarray(FORWARD(params, tokens, config))
 
     mesh = Mesh(np.array(jax.devices()).reshape(2, 2, 2),
                 ("data", "model", "seq"))
@@ -69,7 +70,7 @@ def test_flash_under_dp_tp_mesh_matches_unsharded():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    expected = np.asarray(forward(params, tokens, xla_config))
+    expected = np.asarray(FORWARD(params, tokens, xla_config))
 
     mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
     params_d = shard_params(params, config, mesh)
@@ -80,7 +81,7 @@ def test_flash_under_dp_tp_mesh_matches_unsharded():
                              model_axis="model"))(params_d, tokens_d))
     np.testing.assert_allclose(got, expected, atol=1e-4, rtol=1e-4)
 
-    g_ref = jax.grad(lm_loss)(params, tokens, xla_config)
+    _, g_ref = LOSS_AND_GRAD(params, tokens, xla_config)
     g_mesh = jax.jit(jax.grad(
         lambda p, t: lm_loss(p, t, config, mesh=mesh, batch_axis="data",
                              model_axis="model")))(params_d, tokens_d)
@@ -228,7 +229,7 @@ def test_rope_forward_trains_and_has_no_pos_table():
     assert "pos" not in params["embed"]
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    logits = forward(params, tokens, config)
+    logits = FORWARD(params, tokens, config)
     assert logits.shape == (4, 16, config.vocab_size)
     tx = optax.adam(1e-2)
     opt_state = tx.init(params)
@@ -249,8 +250,8 @@ def test_rope_is_position_sensitive_and_relative():
     tok = np.full((1, 8), 7, dtype=np.int64)
     tok[0, 3] = 11
     shifted = np.roll(tok, 2, axis=1)
-    a = np.asarray(forward(params, jnp.asarray(tok), config))
-    b = np.asarray(forward(params, jnp.asarray(shifted), config))
+    a = np.asarray(FORWARD(params, jnp.asarray(tok), config))
+    b = np.asarray(FORWARD(params, jnp.asarray(shifted), config))
     assert not np.allclose(a, b, atol=1e-4)
 
 
@@ -262,7 +263,7 @@ def test_rope_sharded_forward_matches_unsharded():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    expected = np.asarray(forward(params, tokens, config))
+    expected = np.asarray(FORWARD(params, tokens, config))
     mesh = Mesh(np.array(jax.devices()).reshape(2, 2, 2),
                 ("data", "model", "seq"))
     params_d = shard_params(params, config, mesh)
@@ -305,7 +306,7 @@ def test_gqa_forward_trains():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    logits = forward(params, tokens, config)
+    logits = FORWARD(params, tokens, config)
     assert logits.shape == (4, 16, config.vocab_size)
     tx = optax.adam(1e-2)
     opt_state = tx.init(params)
@@ -325,7 +326,7 @@ def test_gqa_sharded_matches_unsharded():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    expected = np.asarray(forward(params, tokens, config))
+    expected = np.asarray(FORWARD(params, tokens, config))
 
     mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
     params_sharded = shard_params(params, config, mesh)
@@ -439,7 +440,7 @@ def test_mqa_under_tensor_parallel_mesh_replicates_kv_and_matches():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    expected = np.asarray(forward(params, tokens, config))
+    expected = np.asarray(FORWARD(params, tokens, config))
 
     mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
     specs = param_specs(config, mesh=mesh)
@@ -462,7 +463,7 @@ def test_gqa_ring_sharded_forward_matches_unsharded():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
                                 config.vocab_size)
-    expected = np.asarray(forward(params, tokens, config))
+    expected = np.asarray(FORWARD(params, tokens, config))
     mesh = Mesh(np.array(jax.devices()).reshape(2, 2, 2),
                 ("data", "model", "seq"))
     sp = shard_params(params, config, mesh)
@@ -482,12 +483,12 @@ def test_gqa_flash_impl_matches_xla_forward_and_grads():
     xla_cfg = dataclasses.replace(_gqa_config(2), attention_impl="xla")
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 64)
-    ref = forward(params, tokens, xla_cfg)
-    got = forward(params, tokens, config)
+    ref = FORWARD(params, tokens, xla_cfg)
+    got = FORWARD(params, tokens, config)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
-    g_ref = jax.grad(lm_loss)(params, tokens, xla_cfg)
-    g_fl = jax.grad(lm_loss)(params, tokens, config)
+    _, g_ref = LOSS_AND_GRAD(params, tokens, xla_cfg)
+    _, g_fl = LOSS_AND_GRAD(params, tokens, config)
     for a, b in zip(jax.tree_util.tree_leaves(g_fl),
                     jax.tree_util.tree_leaves(g_ref)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -500,7 +501,7 @@ def test_gqa_flash_under_dp_tp_mesh_matches_unsharded():
     config = dataclasses.replace(_gqa_config(2), attention_impl="flash")
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-    expected = np.asarray(forward(params, tokens,
+    expected = np.asarray(FORWARD(params, tokens,
                                   dataclasses.replace(config,
                                                       attention_impl="xla")))
     mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
@@ -523,7 +524,7 @@ def test_param_specs_replicate_on_non_divisible_model_axis():
     params = shard_params(init_params(config, jax.random.PRNGKey(0)),
                           config, mesh)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, 64)
-    expected = float(lm_loss(init_params(config, jax.random.PRNGKey(0)),
+    expected = float(LM_LOSS(init_params(config, jax.random.PRNGKey(0)),
                              tokens, config))
     got = float(jax.jit(lambda p, t: lm_loss(p, t, config))(params, tokens))
     np.testing.assert_allclose(got, expected, atol=2e-4, rtol=2e-4)
@@ -538,7 +539,7 @@ def test_window_under_seq_mesh_runs_windowed_ring_and_matches():
     assert select_attention_impl_for_test(config) == "ring_flash"
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 64)
-    expected = np.asarray(forward(params, tokens, config))
+    expected = np.asarray(FORWARD(params, tokens, config))
     mesh = Mesh(np.array(jax.devices()).reshape(2, 2, 2),
                 ("data", "model", "seq"))
     sp = shard_params(params, config, mesh)

@@ -7,12 +7,12 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from elephas_tpu.models.transformer import (TransformerConfig, forward,
-                                            init_params, lm_loss,
-                                            make_train_step, param_specs,
-                                            shard_params)
+from elephas_tpu.models.transformer import (TransformerConfig, init_params,
+                                            lm_loss, make_train_step,
+                                            param_specs, shard_params)
 
-from ._transformer_util import _config
+from ._transformer_util import (DECODE_STEP, FORWARD, LM_LOSS,
+                                LOSS_AND_GRAD, _config)
 
 
 def test_grad_accumulation_matches_full_batch():
@@ -49,10 +49,9 @@ def test_z_loss_added_and_finite():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                 config.vocab_size)
-    plain = float(lm_loss(params, tokens, config))
-    with_z = float(lm_loss(params, tokens, z_config))
-    assert with_z > plain  # the z penalty is strictly positive
-    g = jax.grad(lm_loss)(params, tokens, z_config)
+    plain = float(LM_LOSS(params, tokens, config))
+    with_z, g = LOSS_AND_GRAD(params, tokens, z_config)
+    assert float(with_z) > plain  # the z penalty is strictly positive
     assert all(np.isfinite(np.asarray(l)).all()
                for l in jax.tree_util.tree_leaves(g))
 
@@ -101,11 +100,10 @@ def test_chunked_vocab_loss_matches_dense_values_and_grads():
         params = init_params(dense_cfg, jax.random.PRNGKey(0))
         tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
                                     dense_cfg.vocab_size)
-        ref = float(lm_loss(params, tokens, dense_cfg))
-        got = float(lm_loss(params, tokens, chunk_cfg))
-        np.testing.assert_allclose(got, ref, atol=1e-5, rtol=1e-5)
-        g_ref = jax.grad(lm_loss)(params, tokens, dense_cfg)
-        g_got = jax.grad(lm_loss)(params, tokens, chunk_cfg)
+        ref, g_ref = LOSS_AND_GRAD(params, tokens, dense_cfg)
+        got, g_got = LOSS_AND_GRAD(params, tokens, chunk_cfg)
+        np.testing.assert_allclose(float(got), float(ref), atol=1e-5,
+                                   rtol=1e-5)
         for a, b in zip(jax.tree_util.tree_leaves(g_got),
                         jax.tree_util.tree_leaves(g_ref)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -137,7 +135,7 @@ def test_chunked_vocab_loss_trains_and_tp_mesh_falls_back():
     sharded = float(jax.jit(lambda p, t: lm_loss(
         p, t, config, mesh=mesh, batch_axis="data",
         model_axis="model"))(sp, ts))
-    unsharded = float(lm_loss(init_params(config, jax.random.PRNGKey(0)),
+    unsharded = float(LM_LOSS(init_params(config, jax.random.PRNGKey(0)),
                               tokens, config))
     np.testing.assert_allclose(sharded, unsharded, atol=2e-3)
 
@@ -151,14 +149,14 @@ def test_dropout_zero_matches_baseline_and_inference_deterministic():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, 64)
     # no key -> no dropout, regardless of rate
-    a = np.asarray(forward(params, tokens, drop_cfg))
-    b = np.asarray(forward(params, tokens, config))
+    a = np.asarray(FORWARD(params, tokens, drop_cfg))
+    b = np.asarray(FORWARD(params, tokens, config))
     np.testing.assert_allclose(a, b, atol=1e-6)
     # same key deterministic, different keys differ
     k = jax.random.PRNGKey(7)
-    d1 = np.asarray(forward(params, tokens, drop_cfg, dropout_key=k))
-    d2 = np.asarray(forward(params, tokens, drop_cfg, dropout_key=k))
-    d3 = np.asarray(forward(params, tokens, drop_cfg,
+    d1 = np.asarray(FORWARD(params, tokens, drop_cfg, dropout_key=k))
+    d2 = np.asarray(FORWARD(params, tokens, drop_cfg, dropout_key=k))
+    d3 = np.asarray(FORWARD(params, tokens, drop_cfg,
                             dropout_key=jax.random.PRNGKey(8)))
     np.testing.assert_array_equal(d1, d2)
     assert np.abs(d1 - d3).max() > 1e-6
@@ -199,20 +197,19 @@ def test_label_smoothing_dense_and_chunked_agree():
     chunked = dataclasses.replace(base, loss_vocab_chunk=24)
     params = init_params(base, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, 64)
-    dense_val = float(lm_loss(params, tokens, base))
-    chunk_val = float(lm_loss(params, tokens, chunked))
+    dense_val, g_dense = LOSS_AND_GRAD(params, tokens, base)
+    chunk_val, g_chunk = LOSS_AND_GRAD(params, tokens, chunked)
+    dense_val, chunk_val = float(dense_val), float(chunk_val)
     np.testing.assert_allclose(chunk_val, dense_val, atol=1e-5, rtol=1e-5)
     # smoothing raises the loss on a confident model and grads match
-    plain = float(lm_loss(params, tokens, _config()))
+    plain = float(LM_LOSS(params, tokens, _config()))
     assert dense_val != plain
-    g_dense = jax.grad(lm_loss)(params, tokens, base)
-    g_chunk = jax.grad(lm_loss)(params, tokens, chunked)
     for a, b in zip(jax.tree_util.tree_leaves(g_chunk),
                     jax.tree_util.tree_leaves(g_dense)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-4)
     # exact semantics: smoothed ce == (1-eps)*ce + eps*uniform_ce
-    logits = forward(params, tokens, base)
+    logits = FORWARD(params, tokens, base)
     from elephas_tpu.models.transformer import next_token_loss
     ce = float(next_token_loss(logits, tokens))
     logp = jax.nn.log_softmax(np.asarray(logits[:, :-1], np.float64), -1)
@@ -226,19 +223,19 @@ def test_untied_head_trains_and_all_paths_agree():
     dense loss, the chunked loss, decode, and the pipelined trainer."""
     import dataclasses
 
-    from elephas_tpu.models.transformer import decode_step, init_kv_cache
+    from elephas_tpu.models.transformer import init_kv_cache
 
     config = dataclasses.replace(_config(), tied_embedding=False)
     params = init_params(config, jax.random.PRNGKey(0))
     assert params["head"].shape == (32, 64)
     tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 10),
                                            0, 64))
-    full = np.asarray(forward(params, jnp.asarray(tokens), config))
+    full = np.asarray(FORWARD(params, jnp.asarray(tokens), config))
 
     # decode parity
     cache = init_kv_cache(config, 2, max_len=10)
     for t in range(10):
-        logits, cache = decode_step(params, cache,
+        logits, cache = DECODE_STEP(params, cache,
                                     jnp.asarray(tokens[:, t]), t, config)
         np.testing.assert_allclose(np.asarray(logits), full[:, t],
                                    atol=2e-4, rtol=2e-4)
@@ -246,12 +243,12 @@ def test_untied_head_trains_and_all_paths_agree():
     # chunked loss parity
     chunk_cfg = dataclasses.replace(config, loss_vocab_chunk=24)
     np.testing.assert_allclose(
-        float(lm_loss(params, jnp.asarray(tokens), chunk_cfg)),
-        float(lm_loss(params, jnp.asarray(tokens), config)),
+        float(LM_LOSS(params, jnp.asarray(tokens), chunk_cfg)),
+        float(LM_LOSS(params, jnp.asarray(tokens), config)),
         atol=1e-5, rtol=1e-5)
 
     # head receives gradient independent of the embedding
-    g = jax.grad(lm_loss)(params, jnp.asarray(tokens), config)
+    _, g = LOSS_AND_GRAD(params, jnp.asarray(tokens), config)
     assert np.abs(np.asarray(g["head"])).sum() > 0
 
     # training decreases loss; specs cover the head
@@ -315,15 +312,15 @@ def test_segment_isolation_and_weighted_loss():
     row_b[0, :6] = rng.integers(4, 64, size=6)  # different doc 1
     segs = np.asarray([[1] * 6 + [2] * 6], dtype="int32")
 
-    la = np.asarray(forward(params, jnp.asarray(row_a), config,
+    la = np.asarray(FORWARD(params, jnp.asarray(row_a), config,
                             segment_ids=jnp.asarray(segs)))
-    lb = np.asarray(forward(params, jnp.asarray(row_b), config,
+    lb = np.asarray(FORWARD(params, jnp.asarray(row_b), config,
                             segment_ids=jnp.asarray(segs)))
     # doc 2's logits identical although doc 1 changed
     np.testing.assert_allclose(la[0, 6:], lb[0, 6:], atol=1e-5, rtol=1e-5)
     # without segments they WOULD differ (sanity that the test can fail)
-    fa = np.asarray(forward(params, jnp.asarray(row_a), config))
-    fb = np.asarray(forward(params, jnp.asarray(row_b), config))
+    fa = np.asarray(FORWARD(params, jnp.asarray(row_a), config))
+    fb = np.asarray(FORWARD(params, jnp.asarray(row_b), config))
     assert np.abs(fa[0, 6:] - fb[0, 6:]).max() > 1e-6
 
     # loss weights: the doc1->doc2 boundary target and pads are excluded
@@ -334,15 +331,15 @@ def test_segment_isolation_and_weighted_loss():
     # lm_loss == manual weighted CE over the segment-masked logits, for
     # the dense AND chunked paths
     import dataclasses
-    logits = forward(params, jnp.asarray(row_a), config,
+    logits = FORWARD(params, jnp.asarray(row_a), config,
                      segment_ids=jnp.asarray(segs))
     manual = float(next_token_loss(logits, jnp.asarray(row_a),
                                    weights=jnp.asarray(w)))
-    got = float(lm_loss(params, jnp.asarray(row_a), config,
+    got = float(LM_LOSS(params, jnp.asarray(row_a), config,
                         segment_ids=jnp.asarray(segs)))
     np.testing.assert_allclose(got, manual, atol=1e-6)
     chunk_cfg = dataclasses.replace(config, loss_vocab_chunk=24)
-    got_c = float(lm_loss(params, jnp.asarray(row_a), chunk_cfg,
+    got_c = float(LM_LOSS(params, jnp.asarray(row_a), chunk_cfg,
                           segment_ids=jnp.asarray(segs)))
     np.testing.assert_allclose(got_c, manual, atol=1e-5, rtol=1e-5)
 
@@ -440,11 +437,11 @@ def test_sliding_window_flash_matches_xla_model_level():
     params = init_params(xla_cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 64)
     np.testing.assert_allclose(
-        np.asarray(forward(params, tokens, flash_cfg)),
-        np.asarray(forward(params, tokens, xla_cfg)),
+        np.asarray(FORWARD(params, tokens, flash_cfg)),
+        np.asarray(FORWARD(params, tokens, xla_cfg)),
         atol=1e-4, rtol=1e-4)
-    g_ref = jax.grad(lm_loss)(params, tokens, xla_cfg)
-    g_fl = jax.grad(lm_loss)(params, tokens, flash_cfg)
+    _, g_ref = LOSS_AND_GRAD(params, tokens, xla_cfg)
+    _, g_fl = LOSS_AND_GRAD(params, tokens, flash_cfg)
     for a, b in zip(jax.tree_util.tree_leaves(g_fl),
                     jax.tree_util.tree_leaves(g_ref)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -459,12 +456,12 @@ def test_chunked_loss_composes_with_dropout():
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, 64)
     k = jax.random.PRNGKey(3)
-    l1 = float(lm_loss(params, tokens, config, dropout_key=k))
-    l2 = float(lm_loss(params, tokens, config, dropout_key=k))
+    l1 = float(LM_LOSS(params, tokens, config, dropout_key=k))
+    l2 = float(LM_LOSS(params, tokens, config, dropout_key=k))
     np.testing.assert_allclose(l1, l2)
-    l3 = float(lm_loss(params, tokens, config))
+    l3 = float(LM_LOSS(params, tokens, config))
     assert abs(l1 - l3) > 1e-7  # dropout actually engaged in chunked path
     # and the dense path with the same key agrees (same hidden states)
     dense_cfg = dataclasses.replace(config, loss_vocab_chunk=None)
-    l4 = float(lm_loss(params, tokens, dense_cfg, dropout_key=k))
+    l4 = float(LM_LOSS(params, tokens, dense_cfg, dropout_key=k))
     np.testing.assert_allclose(l1, l4, atol=1e-5, rtol=1e-5)

@@ -12,6 +12,11 @@ from elephas_tpu.models.vit import (ViTConfig, forward, init_params,
                                     make_train_step, param_specs,
                                     shard_params, vit_loss)
 
+# compiled whole, once a shape and config: op by op, a pass is a
+# hundred small compiles
+FORWARD = jax.jit(forward, static_argnums=2)
+LOSS = jax.jit(vit_loss, static_argnums=3)
+
 
 def _config(**kw):
     base = dict(image_size=16, patch_size=4, channels=3, num_classes=10,
@@ -37,9 +42,9 @@ def test_vit_forward_shapes_and_loss():
     config = _config()
     params = init_params(config, jax.random.PRNGKey(0))
     x, y = _images(8, config)
-    logits = forward(params, jnp.asarray(x), config)
+    logits = FORWARD(params, jnp.asarray(x), config)
     assert logits.shape == (8, 10)
-    loss = float(vit_loss(params, jnp.asarray(x), jnp.asarray(y), config))
+    loss = float(LOSS(params, jnp.asarray(x), jnp.asarray(y), config))
     assert np.isfinite(loss)
     assert abs(loss - np.log(10)) < 0.5  # untrained ~ uniform
 
@@ -58,7 +63,7 @@ def test_vit_trains(pool):
         if first is None:
             first = float(loss)
     assert float(loss) < first
-    preds = np.asarray(forward(params, jnp.asarray(x), config)).argmax(1)
+    preds = np.asarray(FORWARD(params, jnp.asarray(x), config)).argmax(1)
     assert (preds == y).mean() > 0.5
 
 
@@ -66,7 +71,7 @@ def test_vit_sharded_matches_unsharded():
     config = _config()
     params = init_params(config, jax.random.PRNGKey(0))
     x, y = _images(8, config)
-    expected = np.asarray(forward(params, jnp.asarray(x), config))
+    expected = np.asarray(FORWARD(params, jnp.asarray(x), config))
 
     mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
     sharded_params = shard_params(params, config, mesh)
@@ -105,7 +110,7 @@ def test_vit_config_validation_and_gqa():
     params = init_params(config, jax.random.PRNGKey(0))
     assert params["layer_0"]["attn"]["wk"].shape == (32, 2, 8)
     x, _ = _images(4, config)
-    assert forward(params, jnp.asarray(x), config).shape == (4, 10)
+    assert FORWARD(params, jnp.asarray(x), config).shape == (4, 10)
     # specs structure matches params
     jax.tree_util.tree_map(lambda p, s: None, params, param_specs(config))
 
@@ -114,10 +119,10 @@ def test_vit_dropout_active_in_training_only():
     config = _config(dropout_rate=0.2)
     params = init_params(config, jax.random.PRNGKey(0))
     x, y = _images(8, config)
-    a = np.asarray(forward(params, jnp.asarray(x), config))
-    b = np.asarray(forward(params, jnp.asarray(x), config))
+    a = np.asarray(FORWARD(params, jnp.asarray(x), config))
+    b = np.asarray(FORWARD(params, jnp.asarray(x), config))
     np.testing.assert_array_equal(a, b)  # inference deterministic
-    d = np.asarray(forward(params, jnp.asarray(x), config,
+    d = np.asarray(FORWARD(params, jnp.asarray(x), config,
                            dropout_key=jax.random.PRNGKey(1)))
     assert np.abs(d - a).max() > 1e-6
     tx = optax.adam(1e-3)
@@ -136,11 +141,11 @@ def test_vit_stochastic_depth():
     params = init_params(base, jax.random.PRNGKey(0))
     x, y = _images(16, base)
     np.testing.assert_array_equal(
-        np.asarray(forward(params, jnp.asarray(x), config)),
-        np.asarray(forward(params, jnp.asarray(x), base)))
-    d = np.asarray(forward(params, jnp.asarray(x), config,
+        np.asarray(FORWARD(params, jnp.asarray(x), config)),
+        np.asarray(FORWARD(params, jnp.asarray(x), base)))
+    d = np.asarray(FORWARD(params, jnp.asarray(x), config,
                            dropout_key=jax.random.PRNGKey(1)))
-    assert np.abs(d - np.asarray(forward(params, jnp.asarray(x),
+    assert np.abs(d - np.asarray(FORWARD(params, jnp.asarray(x),
                                          base))).max() > 1e-6
     tx = optax.adam(1e-3)
     opt = tx.init(params)
@@ -160,8 +165,8 @@ def test_vit_remat_with_drop_path_and_dropout():
     # remat must not change inference values
     base = _config(num_layers=3)
     np.testing.assert_allclose(
-        np.asarray(forward(params, jnp.asarray(x), config)),
-        np.asarray(forward(params, jnp.asarray(x), base)), atol=1e-6)
+        np.asarray(FORWARD(params, jnp.asarray(x), config)),
+        np.asarray(FORWARD(params, jnp.asarray(x), base)), atol=1e-6)
     tx = optax.adam(1e-3)
     opt = tx.init(params)
     step = make_train_step(config, tx)

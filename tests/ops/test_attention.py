@@ -9,6 +9,15 @@ from jax.sharding import Mesh, PartitionSpec
 from elephas_tpu.ops import (attention, blockwise_attention, ring_attention,
                              ring_attention_sharded)
 
+from ._grads import out_and_grads
+
+
+# each call compiled whole once a shape: eager, a ring (and the
+# interpreted flash kernel in each hop) is a hundred small compiles
+RING = jax.jit(ring_attention_sharded,
+               static_argnames=("mesh", "seq_axis", "causal", "batch_axis",
+                                "window", "impl", "interpret"))
+
 
 def _qkv(b=2, h=4, s=32, d=16, seed=0):
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -38,8 +47,7 @@ def test_ring_matches_full(causal, ring_size):
     q, k, v = _qkv()
     mesh = Mesh(np.array(jax.devices()[:ring_size]), ("seq",))
     full = attention(q, k, v, causal=causal)
-    ring = ring_attention_sharded(q, k, v, mesh=mesh, seq_axis="seq",
-                                  causal=causal)
+    ring = RING(q, k, v, mesh=mesh, seq_axis="seq", causal=causal)
     np.testing.assert_allclose(np.asarray(full), np.asarray(ring), atol=1e-4)
 
 
@@ -47,8 +55,8 @@ def test_ring_with_batch_axis():
     q, k, v = _qkv(b=4)
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("data", "seq"))
     full = attention(q, k, v, causal=True)
-    ring = ring_attention_sharded(q, k, v, mesh=mesh, seq_axis="seq",
-                                  causal=True, batch_axis="data")
+    ring = RING(q, k, v, mesh=mesh, seq_axis="seq", causal=True,
+                batch_axis="data")
     np.testing.assert_allclose(np.asarray(full), np.asarray(ring), atol=1e-4)
 
 
@@ -66,8 +74,8 @@ def test_windowed_ring_matches_band_reference(window, ring_size):
     q, k, v = _qkv()
     mesh = Mesh(np.array(jax.devices()[:ring_size]), ("seq",))
     expected = attention(q, k, v, mask=_band_mask(q.shape[2], window))
-    ring = ring_attention_sharded(q, k, v, mesh=mesh, seq_axis="seq",
-                                  causal=True, window=window)
+    ring = RING(q, k, v, mesh=mesh, seq_axis="seq", causal=True,
+                window=window)
     np.testing.assert_allclose(np.asarray(expected), np.asarray(ring),
                                atol=1e-4)
 
@@ -116,8 +124,7 @@ def test_windowed_ring_gqa():
     v_full = jnp.repeat(v, h // kvh, axis=1)
     expected = attention(q, k_full, v_full, mask=_band_mask(t, 5))
     mesh = Mesh(np.array(jax.devices()[:4]), ("seq",))
-    got = ring_attention_sharded(q, k, v, mesh=mesh, seq_axis="seq",
-                                 causal=True, window=5)
+    got = RING(q, k, v, mesh=mesh, seq_axis="seq", causal=True, window=5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
                                atol=2e-5, rtol=2e-5)
 
@@ -129,10 +136,10 @@ def test_ring_flash_matches_einsum_ring(window, ring_size):
     and the full-attention reference, with and without a window."""
     q, k, v = _qkv()
     mesh = Mesh(np.array(jax.devices()[:ring_size]), ("seq",))
-    ref = ring_attention_sharded(q, k, v, mesh=mesh, seq_axis="seq",
-                                 causal=True, window=window)
-    got = ring_attention_sharded(q, k, v, mesh=mesh, seq_axis="seq",
-                                 causal=True, window=window, impl="flash")
+    ref = RING(q, k, v, mesh=mesh, seq_axis="seq", causal=True,
+               window=window)
+    got = RING(q, k, v, mesh=mesh, seq_axis="seq", causal=True,
+               window=window, impl="flash")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-4)
 
 
@@ -143,16 +150,12 @@ def test_ring_flash_gradients_match_einsum_ring():
     mesh = Mesh(np.array(jax.devices()[:4]), ("seq",))
     cot = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
 
-    def loss(impl):
-        def f(q, k, v):
-            out = ring_attention_sharded(q, k, v, mesh=mesh,
-                                         seq_axis="seq", causal=True,
-                                         window=7, impl=impl)
-            return jnp.sum(out * cot)
-        return f
+    def ring(impl):
+        return lambda q, k, v: RING(q, k, v, mesh=mesh, seq_axis="seq",
+                                    causal=True, window=7, impl=impl)
 
-    ref_grads = jax.grad(loss("einsum"), argnums=(0, 1, 2))(q, k, v)
-    got_grads = jax.grad(loss("flash"), argnums=(0, 1, 2))(q, k, v)
+    _, ref_grads = out_and_grads(ring("einsum"), q, k, v, cot)
+    _, got_grads = out_and_grads(ring("flash"), q, k, v, cot)
     for rg, gg in zip(ref_grads, got_grads):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(rg),
                                    atol=3e-4, rtol=3e-4)
@@ -165,25 +168,15 @@ def test_ring_flash_gqa_forward_and_grad():
     k = jax.random.normal(kk, (b, kvh, t, d))
     v = jax.random.normal(kv_, (b, kvh, t, d))
     mesh = Mesh(np.array(jax.devices()[:4]), ("seq",))
-
-    ref = ring_attention_sharded(q, k, v, mesh=mesh, seq_axis="seq",
-                                 causal=True)
-    got = ring_attention_sharded(q, k, v, mesh=mesh, seq_axis="seq",
-                                 causal=True, impl="flash")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-4)
-
     cot = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
 
-    def loss(impl):
-        def f(q, k, v):
-            out = ring_attention_sharded(q, k, v, mesh=mesh,
-                                         seq_axis="seq", causal=True,
-                                         impl=impl)
-            return jnp.sum(out * cot)
-        return f
+    def ring(impl):
+        return lambda q, k, v: RING(q, k, v, mesh=mesh, seq_axis="seq",
+                                    causal=True, impl=impl)
 
-    ref_grads = jax.grad(loss("einsum"), argnums=(0, 1, 2))(q, k, v)
-    got_grads = jax.grad(loss("flash"), argnums=(0, 1, 2))(q, k, v)
+    ref, ref_grads = out_and_grads(ring("einsum"), q, k, v, cot)
+    got, got_grads = out_and_grads(ring("flash"), q, k, v, cot)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-4)
     for rg, gg in zip(ref_grads, got_grads):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(rg),
                                    atol=3e-4, rtol=3e-4)
@@ -207,7 +200,7 @@ def test_zigzag_ring_flash_matches_full(ring_size):
                     zigzag=zigzag),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)
-        got = fn(q, k, v)
+        got = jax.jit(fn)(q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-4)
 
@@ -222,16 +215,15 @@ def test_zigzag_ring_flash_gradients_match_plain():
     spec = PartitionSpec(None, None, "seq", None)
     cot = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
 
-    def loss(zigzag):
-        fn = jax.shard_map(
+    def ring(zigzag):
+        return jax.shard_map(
             partial(ring_flash_attention, axis_name="seq", causal=True,
                     zigzag=zigzag),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * cot)
 
-    ref_grads = jax.grad(loss(False), argnums=(0, 1, 2))(q, k, v)
-    got_grads = jax.grad(loss(True), argnums=(0, 1, 2))(q, k, v)
+    _, ref_grads = out_and_grads(ring(False), q, k, v, cot)
+    _, got_grads = out_and_grads(ring(True), q, k, v, cot)
     for rg, gg in zip(ref_grads, got_grads):
         np.testing.assert_allclose(np.asarray(gg), np.asarray(rg),
                                    atol=3e-4, rtol=3e-4)
@@ -257,7 +249,7 @@ def test_zigzag_ring_flash_gqa():
                 zigzag=True),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)
-    np.testing.assert_allclose(np.asarray(fn(q, k, v)),
+    np.testing.assert_allclose(np.asarray(jax.jit(fn)(q, k, v)),
                                np.asarray(expected), atol=2e-5, rtol=2e-5)
 
 
@@ -267,10 +259,9 @@ def test_ring_flash_bf16():
     q, k, v = _qkv(s=32, d=16)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
     mesh = Mesh(np.array(jax.devices()[:4]), ("seq",))
-    ref = ring_attention_sharded(q, k, v, mesh=mesh, seq_axis="seq",
-                                 causal=True)
-    got = ring_attention_sharded(qb, kb, vb, mesh=mesh, seq_axis="seq",
-                                 causal=True, impl="flash")
+    ref = RING(q, k, v, mesh=mesh, seq_axis="seq", causal=True)
+    got = RING(qb, kb, vb, mesh=mesh, seq_axis="seq", causal=True,
+               impl="flash")
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref), atol=0.03, rtol=0.03)
@@ -285,7 +276,6 @@ def test_ring_attention_gqa_matches_full_attention():
     from jax.sharding import Mesh
 
     from elephas_tpu.ops.attention import attention
-    from elephas_tpu.ops.ring_attention import ring_attention_sharded
 
     b, h, kvh, t, d = 2, 4, 2, 16, 8
     kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -298,6 +288,5 @@ def test_ring_attention_gqa_matches_full_attention():
     expected = np.asarray(attention(q, k_full, v_full, causal=True))
 
     mesh = Mesh(np.array(jax.devices()[:4]), ("seq",))
-    got = np.asarray(ring_attention_sharded(q, k, v, mesh=mesh,
-                                            seq_axis="seq", causal=True))
+    got = np.asarray(RING(q, k, v, mesh=mesh, seq_axis="seq", causal=True))
     np.testing.assert_allclose(got, expected, atol=2e-5, rtol=2e-5)

@@ -12,6 +12,8 @@ import pytest
 from elephas_tpu.ops.attention import attention
 from elephas_tpu.ops.pallas_attention import flash_attention
 
+from ._grads import out_and_grads
+
 
 def _qkv(key, b=2, h=2, sq=32, sk=32, d=16, dtype=jnp.float32):
     kq, kk, kv = jax.random.split(key, 3)
@@ -28,8 +30,9 @@ def test_forward_matches_reference(causal, sq, sk, block):
     if causal and sq != sk:
         pytest.skip("causal requires square attention")
     q, k, v = _qkv(jax.random.PRNGKey(0), sq=sq, sk=sk)
-    got = flash_attention(q, k, v, causal=causal, block_q=block,
-                          block_k=block, interpret=True)
+    got = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=block, block_k=block,
+        interpret=True))(q, k, v)
     want = attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -48,8 +51,8 @@ def test_gradients_match_reference(causal, sq, block):
     def loss_ref(q, k, v):
         return jnp.sum(jnp.sin(attention(q, k, v, causal=causal)))
 
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
                                    rtol=5e-4, err_msg=f"d{name}")
@@ -97,7 +100,7 @@ def test_sharded_flash_matches_reference_on_dp_tp_mesh():
         return jnp.sum(jnp.sin(attention(q, k, v, causal=True)))
 
     g_got = jax.jit(jax.grad(loss_sharded, argnums=(0, 1, 2)))(q_d, k_d, v_d)
-    g_want = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_want = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(g_got, g_want, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
                                    rtol=5e-4, err_msg=f"d{name}")
@@ -136,15 +139,10 @@ def test_flash_gqa_matches_expanded_reference(kvh, causal):
         return flash_attention(q, k, v, causal=causal, block_q=16,
                                block_k=16, interpret=True)
 
-    np.testing.assert_allclose(np.asarray(flash_fn(q, k, v)),
-                               np.asarray(ref_fn(q, k, v)),
+    ref, g_ref = out_and_grads(ref_fn, q, k, v, gout)
+    got, g_fl = out_and_grads(flash_fn, q, k, v, gout)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
-
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * gout)
-
-    g_ref = jax.grad(loss(ref_fn), argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss(flash_fn), argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g_fl, g_ref):
         assert a.shape == b_.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
@@ -183,15 +181,10 @@ def test_flash_sliding_window_matches_band_reference(window, kvh):
         return flash_attention(q, k, v, causal=True, window=window,
                                block_q=16, block_k=16, interpret=True)
 
-    np.testing.assert_allclose(np.asarray(flash_fn(q, k, v)),
-                               np.asarray(ref_fn(q, k, v)),
+    ref, g_ref = out_and_grads(ref_fn, q, k, v, gout)
+    got, g_fl = out_and_grads(flash_fn, q, k, v, gout)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
-
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * gout)
-
-    g_ref = jax.grad(loss(ref_fn), argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss(flash_fn), argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g_fl, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=3e-4, rtol=3e-4)

@@ -24,9 +24,15 @@ def main():
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_num_cpu_devices", 2 if nprocs > 1 else 4)
     if nprocs > 1:
+        # a crash run's survivor learns of its dead peer from the
+        # coordination service's heartbeat timeout before it can exit
+        # (JAX's shutdown barrier waits for every task): 10 s, not the
+        # default 100, keeps that wait short
+        kw = ({"heartbeat_timeout_seconds": 10} if mode == "async_crash"
+              else {})
         jax.distributed.initialize(
             coordinator_address=f"127.0.0.1:{jax_port}",
-            num_processes=nprocs, process_id=pid)
+            num_processes=nprocs, process_id=pid, **kw)
 
     import numpy as np
 

@@ -9,6 +9,7 @@ hard-killed mid-fit (simulated host death / preemption). Contract:
 - a restarted run restores the latest checkpoint and finishes training.
 """
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -43,31 +44,39 @@ def _launch(mode, nprocs, outdir, jax_port, ps_port, timeout=240):
     return procs, outputs
 
 
-#: environment-bound, same root cause as test_multihost_spawn.py's
-#: marker (verified failing identically on the untouched seed on this
-#: box before PR 10's changes): this jaxlib's CPU runtime raises
+#: Environment-bound, the same root cause as test_multihost_spawn.py's
+#: marker: the jaxlib these tests were first run on raised
 #: 'Multiprocess computations aren't implemented on the CPU backend.'
-#: at the first cross-process collective, so the "crash run" here
-#: fails during TRAINING rather than at the injected kill — no epoch
-#: ever completes, no checkpoint is written, and both tests' premises
-#: (a surviving peer mid-fit; a checkpoint to resume from) never
-#: materialize. Passes on jaxlib builds whose CPU client implements
-#: multi-process collectives, hence non-strict.
+#: at the first cross-process collective, so the crash run failed
+#: during training rather than at the injected kill and neither test's
+#: premise (a surviving peer mid-fit; a checkpoint to resume from) came
+#: about. The installed jaxlib runs cross-process collectives on the CPU
+#: over Gloo, and both tests XPASS here, as test_multihost_spawn.py's
+#: do. The marker stays non-strict for jaxlib builds without them.
 _cpu_multiprocess_xfail = pytest.mark.xfail(
     strict=False,
-    reason="environment-bound: this jaxlib's CPU backend raises "
-           "'Multiprocess computations aren't implemented' before the "
-           "fault-injection premise can establish (see in-file note)")
+    reason="environment-bound: a jaxlib whose CPU backend raises "
+           "'Multiprocess computations aren't implemented' fails before "
+           "the fault-injection premise can establish (see in-file note)")
+
+
+@pytest.fixture(scope="module")
+def crash_run(tmp_path_factory):
+    """One 2-process ``async_crash`` run that both tests read: process 1
+    hard-exits mid-fit and process 0 survives it. Tests copy what they
+    change out of ``outdir`` and leave it as the run left it."""
+    outdir = tmp_path_factory.mktemp("crash_run")
+    jax_port, ps_port = _ports()
+    start = time.monotonic()
+    procs, outputs = _launch("async_crash", 2, outdir, jax_port, ps_port)
+    return outdir, procs, outputs, time.monotonic() - start
 
 
 @_cpu_multiprocess_xfail
-def test_peer_death_surfaces_clear_error_not_hang(tmp_path):
+def test_peer_death_surfaces_clear_error_not_hang(crash_run):
     """Hard-kill process 1 mid-fit: process 0 must exit within the
     barrier deadline with an error naming the barrier."""
-    jax_port, ps_port = _ports()
-    start = time.monotonic()
-    procs, outputs = _launch("async_crash", 2, tmp_path, jax_port, ps_port)
-    elapsed = time.monotonic() - start
+    outdir, procs, outputs, elapsed = crash_run
 
     assert procs[1].returncode == 43, \
         f"crash process should hard-exit 43:\n{outputs[1]}"
@@ -87,16 +96,15 @@ def test_peer_death_surfaces_clear_error_not_hang(tmp_path):
     # the coordinator checkpointed at least one epoch before the failure
     from elephas_tpu.utils.checkpoint import CheckpointManager
 
-    assert CheckpointManager(tmp_path / "ckpt").latest_step() is not None
+    assert CheckpointManager(outdir / "ckpt").latest_step() is not None
 
 
 @_cpu_multiprocess_xfail
-def test_restart_resumes_from_checkpoint(tmp_path):
+def test_restart_resumes_from_checkpoint(crash_run, tmp_path):
     """The full recovery story: crash run leaves checkpoints; a fresh
     2-process run restores the latest step, finishes, and both hosts
     agree on finite weights."""
-    jax_port, ps_port = _ports()
-    _launch("async_crash", 2, tmp_path, jax_port, ps_port)
+    shutil.copytree(crash_run[0] / "ckpt", tmp_path / "ckpt")
     from elephas_tpu.utils.checkpoint import CheckpointManager
 
     latest = CheckpointManager(tmp_path / "ckpt").latest_step()

@@ -110,9 +110,13 @@ def test_pipeline_with_transformer_blocks():
     pipe_fn = make_pipeline_fn(stage_fn, mesh, num_microbatches=4)
     got = np.asarray(jax.jit(pipe_fn)(stacked, x))
 
-    want = x
-    for i in range(8):
-        want = block(params[f"layer_{i}"], want)
+    @jax.jit        # one program: op by op, the blocks are many compiles
+    def sequential(params, x):
+        for i in range(8):
+            x = block(params[f"layer_{i}"], x)
+        return x
+
+    want = sequential(params, x)
     np.testing.assert_allclose(got, np.asarray(want), atol=1e-4, rtol=1e-4)
 
 

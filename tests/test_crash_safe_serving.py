@@ -16,19 +16,19 @@ import urllib.request
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from elephas_tpu.fleet import (FleetRouter, ReplicaPool,
                                ReplicaSupervisor, RestartPolicy)
-from elephas_tpu.models.transformer import (TransformerConfig, generate,
-                                            init_params)
+from elephas_tpu.models.transformer import TransformerConfig, init_params
 from elephas_tpu.obs import EngineWatchdog, MetricsRegistry
 from elephas_tpu.obs.events import clear_events, recent_events
 from elephas_tpu.fleet.membership import ReplicaMembership
 from elephas_tpu.serving_engine import DecodeEngine
 from elephas_tpu.serving_http import ServingServer
 from elephas_tpu.utils.faults import FaultPlan, clear_plan, install_plan
+
+from ._greedy import greedy_reference as _ref
 
 
 @pytest.fixture(scope="module")
@@ -46,11 +46,6 @@ def _clean_slate():
     clear_events()
     yield
     clear_plan()
-
-
-def _ref(params, config, prompt, n):
-    return list(np.asarray(
-        generate(params, jnp.asarray(prompt)[None], n, config))[0])
 
 
 def _post(port, path, payload):

@@ -18,10 +18,11 @@ from elephas_tpu.kvtier import (HostTier, SessionStore, SpilledBlock,
                                 StorageTier, TieredSpill, decode_payload,
                                 encode_payload)
 from elephas_tpu.models.block_cache import chain_keys
-from elephas_tpu.models.transformer import (TransformerConfig, generate,
-                                            init_params)
+from elephas_tpu.models.transformer import TransformerConfig, init_params
 from elephas_tpu.serving_engine import DecodeEngine
 from elephas_tpu.utils.storage import LocalMirrorStore, register_store
+
+from ._greedy import greedy_reference as _ref
 
 
 @pytest.fixture(scope="module")
@@ -39,11 +40,6 @@ def mirror(tmp_path):
     register_store("mirror", store)
     yield store
     register_store("mirror", None)
-
-
-def _ref(params, config, prompt, n):
-    return list(np.asarray(
-        generate(params, jnp.asarray(prompt)[None], n, config))[0])
 
 
 def _drain(eng):

@@ -10,9 +10,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from elephas_tpu.models.transformer import (TransformerConfig, generate,
-                                            init_params)
+from elephas_tpu.models.transformer import TransformerConfig, init_params
 from elephas_tpu.serving_engine import DecodeEngine
+
+from ._greedy import greedy_reference as _ref
 
 
 def _config(**overrides):
@@ -20,11 +21,6 @@ def _config(**overrides):
                 d_ff=64, max_seq_len=48, dtype=jnp.float32)
     base.update(overrides)
     return TransformerConfig(**base)
-
-
-def _ref(params, config, prompt, n):
-    return list(np.asarray(
-        generate(params, jnp.asarray(prompt)[None], n, config))[0])
 
 
 @pytest.fixture(scope="module")

@@ -11,11 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from elephas_tpu.models.transformer import (TransformerConfig, generate,
-                                            init_params)
+from elephas_tpu.models.transformer import TransformerConfig, init_params
 from elephas_tpu.serving_engine import DecodeEngine
 from elephas_tpu.serving_http import ServingServer
 from elephas_tpu.utils.text import ByteTokenizer
+
+from ._greedy import greedy_reference as _ref
 
 
 @pytest.fixture(scope="module")
@@ -25,11 +26,6 @@ def model():
                                dtype=jnp.float32)
     params = init_params(config, jax.random.PRNGKey(0))
     return params, config
-
-
-def _ref(params, config, prompt, n):
-    return list(np.asarray(
-        generate(params, jnp.asarray(prompt)[None], n, config))[0])
 
 
 def _post(port, path, payload):

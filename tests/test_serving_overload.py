@@ -18,11 +18,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from elephas_tpu.models.transformer import (TransformerConfig, generate,
-                                            init_params)
+from elephas_tpu.models.transformer import TransformerConfig, init_params
 from elephas_tpu.serving_engine import DecodeEngine, QueueFullError
 from elephas_tpu.serving_http import ServingServer
 from elephas_tpu.utils.faults import FaultPlan, clear_plan, install_plan
+
+from ._greedy import greedy_reference as _ref
 
 
 @pytest.fixture(autouse=True)
@@ -41,11 +42,6 @@ def model():
                                dtype=jnp.float32)
     params = init_params(config, jax.random.PRNGKey(0))
     return params, config
-
-
-def _ref(params, config, prompt, n):
-    return list(np.asarray(
-        generate(params, jnp.asarray(prompt)[None], n, config))[0])
 
 
 def _post(port, path, payload):

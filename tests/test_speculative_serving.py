@@ -17,9 +17,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from elephas_tpu.models.transformer import (TransformerConfig, generate,
-                                            init_params)
+from elephas_tpu.models.transformer import TransformerConfig, init_params
 from elephas_tpu.serving_engine import DecodeEngine
+
+from ._greedy import greedy_reference as _ref
 
 
 def _config(**overrides):
@@ -47,11 +48,6 @@ def model():
     dcfg = _draft_config()
     draft = init_params(dcfg, jax.random.PRNGKey(9))
     return params, config, draft, dcfg
-
-
-def _ref(params, config, prompt, n):
-    return list(np.asarray(
-        generate(params, jnp.asarray(prompt)[None], n, config))[0])
 
 
 def _drain(eng, rids):

@@ -17,13 +17,14 @@ import numpy as np
 import pytest
 
 from elephas_tpu.fleet import FleetRouter, ReplicaPool
-from elephas_tpu.models.transformer import (TransformerConfig, generate,
-                                            init_params)
+from elephas_tpu.models.transformer import TransformerConfig, init_params
 from elephas_tpu.serving_engine import DecodeEngine, QueueFullError
 from elephas_tpu.serving_http import ServingServer
 from elephas_tpu.serving_qos import (DEFAULT_TENANT, FairQueue,
                                      QueuedRequest, TenantQoS)
 from elephas_tpu.utils.faults import FaultPlan, clear_plan, install_plan
+
+from ._greedy import greedy_reference as _ref
 
 
 @pytest.fixture(scope="module")
@@ -48,11 +49,6 @@ def _no_fault_plan():
 def _prompt(seed, n=8):
     return np.asarray(
         np.random.default_rng(seed).integers(0, 300, n), np.int32)
-
-
-def _ref(params, config, prompt, n):
-    return list(np.asarray(
-        generate(params, jnp.asarray(prompt)[None], n, config))[0])
 
 
 def _post(port, path, payload, headers=None):
